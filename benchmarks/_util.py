@@ -12,9 +12,20 @@ import textwrap
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
+def cpu_devices_env(devices: int) -> dict:
+    """Environment for a child process that runs on `devices` virtual CPU
+    devices. ``JAX_PLATFORMS=cpu`` keeps it off the accelerator: a chip
+    belongs to one process, and the parent may hold it."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["PYTHONPATH"] = os.path.join(_REPO, "src") + (
+        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
+    )
+    return env
+
+
 PREAMBLE = """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={devices}"
 import json, time
 import jax, jax.numpy as jnp
 import numpy as np
@@ -24,14 +35,11 @@ from repro import core as drjax
 
 
 def run_point(body: str, devices: int = 1, timeout: int = 540, **fmt) -> dict:
-    script = PREAMBLE.format(devices=devices) + textwrap.dedent(body).format(
-        devices=devices, **fmt
-    )
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(_REPO, "src")
+    script = PREAMBLE + textwrap.dedent(body).format(devices=devices, **fmt)
     out = subprocess.run(
         [sys.executable, "-c", script],
-        capture_output=True, text=True, env=env, timeout=timeout,
+        capture_output=True, text=True, env=cpu_devices_env(devices),
+        timeout=timeout,
     )
     if out.returncode != 0:
         raise RuntimeError(

@@ -24,8 +24,8 @@ production metrics as a per-PR trajectory in ``BENCH_chaos.json``:
 ``--smoke`` is the CI shape: ~20 rounds with 1 device failure, 1 elastic
 event, straggler deadlines every round and a checkpoint fault (no BENCH
 write). ``--physical`` runs the physical-mesh soak; it needs 8 host
-devices and re-execs itself under ``XLA_FLAGS`` when the current process
-has fewer. Invoked via ``benchmarks.run`` (key ``chaos``) or directly:
+CPU devices and re-execs itself on them (``JAX_PLATFORMS=cpu``, ``XLA_FLAGS``)
+unless its environment already provides them. Invoked via ``benchmarks.run`` (key ``chaos``) or directly:
 
     PYTHONPATH=src python -m benchmarks.chaos [--smoke] [--physical]
 """
@@ -35,6 +35,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import re
 import subprocess
 import sys
 import time
@@ -42,8 +43,9 @@ import time
 from repro.launch import bench_log
 from repro.runtime.chaos import ChaosConfig, run_chaos_soak
 
+from ._util import _REPO, cpu_devices_env
+
 OUT_PATH = bench_log.bench_path("chaos")
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 #: devices the physical soak needs (4 pods x 2 clients)
 PHYSICAL_DEVICES = 8
@@ -105,23 +107,26 @@ def bench(smoke: bool = False, seed: int | None = None,
     return point
 
 
+def _on_cpu_pool() -> bool:
+    """Does this process's environment already give it the CPU pool the
+    physical soak needs (as :func:`cpu_devices_env` sets it)?"""
+    m = re.search(r"--xla_force_host_platform_device_count=(\d+)",
+                  os.environ.get("XLA_FLAGS", ""))
+    return (os.environ.get("JAX_PLATFORMS") == "cpu" and m is not None
+            and int(m.group(1)) >= PHYSICAL_DEVICES)
+
+
 def _physical_point_subprocess() -> dict:
     """Run the physical soak in a fresh 8-device process, return its point.
 
     The host device count is locked at JAX's first init, so the aggregator
     (whose process typically has 1 device) gets the physical point from a
     subprocess — the same pattern as benchmarks/hier_sharded.py."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={PHYSICAL_DEVICES}"
-    )
-    env["PYTHONPATH"] = os.path.join(_REPO, "src") + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
-    )
     proc = subprocess.run(
         [sys.executable, "-m", "benchmarks.chaos",
          "--smoke", "--physical", "--json"],
-        capture_output=True, text=True, cwd=_REPO, timeout=900, env=env,
+        capture_output=True, text=True, cwd=_REPO, timeout=900,
+        env=cpu_devices_env(PHYSICAL_DEVICES),
     )
     if proc.returncode != 0:
         raise RuntimeError(
@@ -175,29 +180,19 @@ def main():
                          "stragglers, 1 ckpt fault); no BENCH write")
     ap.add_argument("--physical", action="store_true",
                     help="physical-mesh soak (real (pod, data) mesh, live "
-                         "resharding); re-execs under XLA_FLAGS if this "
-                         f"process has < {PHYSICAL_DEVICES} devices")
+                         f"resharding); re-execs on {PHYSICAL_DEVICES} CPU "
+                         "devices unless the environment provides them")
     ap.add_argument("--json", action="store_true",
                     help="print the result as one machine-readable JSON line")
     ap.add_argument("--seed", type=int, default=None)
     args = ap.parse_args()
-    if args.physical:
-        import jax
-
-        if jax.device_count() < PHYSICAL_DEVICES:
-            env = dict(os.environ)
-            env["XLA_FLAGS"] = (
-                "--xla_force_host_platform_device_count="
-                f"{PHYSICAL_DEVICES}"
-            )
-            env["PYTHONPATH"] = os.path.join(_REPO, "src") + (
-                os.pathsep + env["PYTHONPATH"]
-                if env.get("PYTHONPATH") else ""
-            )
-            sys.exit(subprocess.run(
-                [sys.executable, "-m", "benchmarks.chaos"] + sys.argv[1:],
-                cwd=_REPO, env=env,
-            ).returncode)
+    if args.physical and not _on_cpu_pool():
+        # Decided from the environment alone: touching JAX here would take
+        # the accelerator, and the child could then not start.
+        sys.exit(subprocess.run(
+            [sys.executable, "-m", "benchmarks.chaos"] + sys.argv[1:],
+            cwd=_REPO, env=cpu_devices_env(PHYSICAL_DEVICES),
+        ).returncode)
     t0 = time.time()
     point = bench(smoke=args.smoke, seed=args.seed, physical=args.physical)
     point["bench_wall_s"] = round(time.time() - t0, 1)

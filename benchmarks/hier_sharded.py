@@ -11,24 +11,17 @@ sharded point to this commit's ``BENCH_hier.json`` trajectory entry.
 from __future__ import annotations
 
 import json
-import os
 import subprocess
 import sys
 
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from ._util import _REPO, cpu_devices_env
 
 
 def run(num_devices: int = 8):
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={num_devices}"
-    )
-    env["PYTHONPATH"] = os.path.join(_REPO, "src") + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
-    )
     proc = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--hier-sweep"],
-        capture_output=True, text=True, cwd=_REPO, timeout=600, env=env,
+        capture_output=True, text=True, cwd=_REPO, timeout=600,
+        env=cpu_devices_env(num_devices),
     )
     if proc.returncode != 0:
         raise RuntimeError(

@@ -15,6 +15,9 @@ cd "$(dirname "$0")/.."
 
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 export REPRO_HOST_DEVICES="${REPRO_HOST_DEVICES:-8}"
+# The suite runs on the CPU, also on a machine with an accelerator: the chip
+# is checked by chip_smoke.py, and it belongs to one process at a time.
+export JAX_PLATFORMS=cpu
 
 # Repo lints (ROADMAP "Static analysis & lints"): the unified rule registry
 # in repro.analysis.lints — compat surface, donation discipline, version
@@ -28,9 +31,11 @@ python scripts/lint.py --json
 # hierarchical reduce, compiled plan executor) end to end.
 python examples/quickstart.py > /dev/null
 
-# Compiled-vs-interpreted smoke check: plan.compile() must be BITWISE equal
-# to the run_plan oracle for a loop-carrying round program (full coverage in
-# tests/test_executor.py).
+# Compiled-vs-interpreted smoke check: plan.compile() must match the
+# run_plan oracle to 1 ulp for a loop-carrying round program (full coverage
+# in tests/test_executor.py). Not bitwise: compiled, XLA fuses the reduce
+# and the update that run_plan executes op by op, and may accumulate in
+# another order (tests/_ulp.py).
 python - <<'PY'
 import jax, jax.numpy as jnp, numpy as np
 from repro import core as drjax
@@ -49,9 +54,8 @@ plan = drjax.build_plan(jax.make_jaxpr(two_rounds)(*args), 3)
 compiled = plan.compile()
 ref = drjax.run_plan(plan, *args)
 out = compiled(*args)
-assert all(np.array_equal(np.asarray(a), np.asarray(b))
-           for a, b in zip(out, ref)), \
-    "compiled plan executor diverged from run_plan (bitwise)"
+for a, b in zip(out, ref):
+    np.testing.assert_array_max_ulp(np.asarray(a), np.asarray(b), maxulp=1)
 compiled(*args)
 assert compiled.trace_count == 1, "compiled plan retraced on a repeat call"
 print("compiled-vs-interpreted smoke check: OK")

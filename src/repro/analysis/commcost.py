@@ -291,9 +291,9 @@ def cross_validate(
     """Check the modeled geometry against the compiled program's costs.
 
     Every plain (uncompressed) Reduce eqn is jitted standalone; the XLA
-    cost model attributes ``operand bytes + output bytes`` to parameter 0
-    of a lone reduce, which must match the modeled ``endpoints * payload +
-    output nbytes`` within ``tol``. Compressed reduces are excluded — their
+    cost model's operand bytes of parameter 0 plus its output bytes must
+    match the modeled ``endpoints * payload + output nbytes`` within
+    ``tol``. Compressed reduces are excluded — their
     lowering contains quantization machinery whose memory accounting is not
     a wire model (they are pinned against the packed wire format math in
     tests instead). ``model_scale`` multiplies the modeled side; anything
@@ -328,9 +328,10 @@ def cross_validate(
         compiled = jax.jit(fn).lower(x).compile()
         cost = compat.cost_analysis(compiled)
         candidates += 1
-        measured = cost.get("bytes accessed0{}")
-        if measured is None:
+        operand = cost.get("bytes accessed0{}")
+        if operand is None:
             continue
+        measured = operand + cost.get("bytes accessedout{}", 0.0)
         saw_cost_model = True
         _, in_bytes = _nbytes(aval)
         _, out_bytes = _nbytes(out_aval)

@@ -3,9 +3,9 @@
 One registry for every repo-convention check that used to live as ad-hoc
 shell in ``scripts/run_tests.sh``:
 
-* ``compat-surface`` — the ROADMAP compat rule: no version-sensitive JAX
-  surface outside ``repro/compat``. Byte-for-byte the same match/filter as
-  the historical inline grep, so absorbing it changes no behavior.
+* ``compat-surface`` — the ROADMAP compat rule: no JAX internals (the
+  private ``_src`` package) and no version-sensitive JAX surface outside
+  ``src/repro/compat/``.
 * ``donate-jit`` — the donation rule (``scripts/check_donation.py`` is now
   a thin shim over this rule): every ``jax.jit`` in the hot layers donates
   its carried state or carries a ``# no-donate: <reason>`` marker.
@@ -40,7 +40,8 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 # The compat patterns are assembled (not written literally) so this file
 # does not flag itself: the rule matches raw substrings anywhere in a line.
-_COMPAT_PATTERNS = ("Axis" + "Type", "cost_" + "analysis()")
+_COMPAT_PATTERNS = ("Axis" + "Type", "cost_" + "analysis()", "jax." + "_src")
+_COMPAT_DIR = "src/repro/compat/"
 
 _SUPPRESS_RE = re.compile(r"#\s*lint:\s*disable=([\w\-, ]+)")
 
@@ -141,26 +142,26 @@ def run_lints(
 
 @rule(
     "compat-surface",
-    "no version-sensitive JAX API outside repro/compat (ROADMAP compat rule)",
+    "no JAX internals or version-sensitive JAX API outside repro/compat "
+    "(ROADMAP compat rule)",
 )
 def _compat_surface(root: str) -> List[LintViolation]:
-    # Reproduces the historical run_tests.sh grep exactly: match the raw
-    # substrings in any src/**/*.py line; drop a match when the grep-style
-    # "path:line:content" haystack contains "compat" anywhere.
+    # Raw substrings in any src/**/*.py line (comments and strings included,
+    # like a grep); only files under src/repro/compat/ may hold them.
     out: List[LintViolation] = []
     for path in _py_files(os.path.join(root, "src")):
         rel = _rel(path, root)
+        if rel.startswith(_COMPAT_DIR):
+            continue
         with open(path) as fh:
             for lineno, line in enumerate(fh.read().splitlines(), 1):
                 if not any(p in line for p in _COMPAT_PATTERNS):
                     continue
-                if "compat" in f"{rel}:{lineno}:{line}":
-                    continue
                 out.append(LintViolation(
                     rule="compat-surface", path=rel, line=lineno,
                     message=(
-                        "version-sensitive JAX API used outside "
-                        f"repro/compat: {line.strip()}"
+                        "JAX internals or version-sensitive JAX API used "
+                        f"outside repro/compat: {line.strip()}"
                     ),
                 ))
     return out

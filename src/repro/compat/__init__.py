@@ -1,17 +1,16 @@
-"""JAX version-portability layer.
+"""The one owner of the JAX surfaces that move between releases.
 
-One subsystem owns every JAX-version-sensitive surface the repo touches:
-mesh construction, axis-type handling, ambient-mesh contexts, compiled-cost
-analysis, and sharding-object helpers. The repo rule (see ROADMAP.md):
+Mesh construction, axis types, the ambient-mesh context, shard_map,
+compiled-cost analysis, sharding helpers and the jaxpr internals
+(``compat.jaxpr``). The repo rule (see
+ROADMAP.md, enforced by the ``compat-surface`` lint):
 
-    No direct ``jax.sharding.AxisType`` / ``jax.make_mesh`` keyword probing /
-    ``Compiled.cost_analysis`` shape handling outside ``repro/compat``.
+    No ``jax._src``, direct ``jax.sharding.AxisType`` or raw
+    ``Compiled.cost_analysis()`` outside ``repro/compat``.
 
-Callers branch on capabilities (``compat.has("mesh_axis_types")``), never on
-``jax.__version__``. Supported range: JAX 0.4.3x (list-shaped cost analysis,
-no AxisType, ``with mesh:`` ambient contexts) through current releases
-(dict cost analysis, AxisType, ``jax.set_mesh``); on older versions
-new-API-only features degrade to their implicit equivalents.
+Written for the installed JAX release only (0.9.0); there is no branch for
+another version. ``compat.capabilities()`` reports the surfaces this layer
+relies on, so a different JAX fails the compat tests where it differs.
 """
 
 from .cost import (
@@ -22,12 +21,7 @@ from .cost import (
 )
 from .meshes import axis_type, make_mesh, set_mesh, shard_map
 from .probes import capabilities, has, jax_version, reset_cache
-from .shardings import (
-    named_sharding,
-    partition_spec,
-    positional_sharding,
-    replicated_sharding,
-)
+from .shardings import named_sharding, partition_spec, replicated_sharding
 
 __all__ = [
     "axis_type",
@@ -41,7 +35,6 @@ __all__ = [
     "named_sharding",
     "normalize_cost_analysis",
     "partition_spec",
-    "positional_sharding",
     "replicated_sharding",
     "reset_cache",
     "set_mesh",

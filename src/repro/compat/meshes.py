@@ -1,4 +1,4 @@
-"""Version-portable mesh construction and ambient-mesh contexts.
+"""Mesh construction, shard_map and the ambient-mesh context.
 
 All mesh construction in this repo goes through :func:`make_mesh`; nothing
 outside ``repro.compat`` may reference ``jax.sharding.AxisType`` or probe
@@ -12,8 +12,6 @@ from typing import Optional, Sequence, Union
 
 import jax
 
-from .probes import has
-
 # Axis-type names accepted by make_mesh (lowercase) -> enum member name.
 _AXIS_TYPE_MEMBERS = {"auto": "Auto", "explicit": "Explicit", "manual": "Manual"}
 
@@ -21,15 +19,12 @@ AxisTypeLike = Union[str, object, None]
 
 
 def axis_type(kind: str = "auto"):
-    """Resolve ``jax.sharding.AxisType.<Kind>``; None when the enum is absent
-    (pre-AxisType JAX, where every mesh axis behaves as Auto)."""
+    """Resolve ``jax.sharding.AxisType.<Kind>`` from its lowercase name."""
     member = _AXIS_TYPE_MEMBERS.get(str(kind).lower())
     if member is None:
         raise ValueError(
             f"unknown axis type {kind!r}; expected one of {sorted(_AXIS_TYPE_MEMBERS)}"
         )
-    if not has("axis_type_enum"):
-        return None
     return getattr(jax.sharding.AxisType, member)
 
 
@@ -40,87 +35,40 @@ def make_mesh(
     axis_types: Union[AxisTypeLike, Sequence[AxisTypeLike]] = "auto",
     devices=None,
 ) -> jax.sharding.Mesh:
-    """Build a Mesh on any supported JAX version.
+    """Build a Mesh through ``jax.make_mesh``.
 
     ``axis_types`` accepts lowercase names ("auto" / "explicit" / "manual"),
     already-resolved enum members, a single value applied to every axis, or
-    ``None``. On JAX versions without axis types the request is dropped:
-    those versions have Auto-only semantics, which is what every current
-    caller asks for. Falls back to ``Mesh(mesh_utils.create_device_mesh(...))``
-    when ``jax.make_mesh`` itself is missing.
+    ``None`` (JAX's default).
     """
-    shape = tuple(shape)
     axes = tuple(axes)
-    if has("make_mesh"):
-        kwargs = {}
-        if devices is not None:
-            kwargs["devices"] = devices
-        if axis_types is not None and has("mesh_axis_types"):
-            if isinstance(axis_types, str) or not isinstance(
-                axis_types, (tuple, list)
-            ):
-                axis_types = (axis_types,) * len(axes)
-            kwargs["axis_types"] = tuple(
-                axis_type(t) if isinstance(t, str) else t for t in axis_types
-            )
-        return jax.make_mesh(shape, axes, **kwargs)
-    from jax.experimental import mesh_utils
-
-    devs = mesh_utils.create_device_mesh(shape, devices=devices)
-    return jax.sharding.Mesh(devs, axes)
+    kwargs = {}
+    if devices is not None:
+        kwargs["devices"] = devices
+    if axis_types is not None:
+        if isinstance(axis_types, str) or not isinstance(axis_types, (tuple, list)):
+            axis_types = (axis_types,) * len(axes)
+        kwargs["axis_types"] = tuple(
+            axis_type(t) if isinstance(t, str) else t for t in axis_types
+        )
+    return jax.make_mesh(tuple(shape), axes, **kwargs)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = True):
-    """Version-portable shard_map.
-
-    Newer JAX exposes ``jax.shard_map`` with a ``check_vma`` flag; older
-    releases have ``jax.experimental.shard_map.shard_map`` with the same flag
-    named ``check_rep``. ``check`` maps onto whichever the installed version
-    understands.
-    """
-    import inspect
-
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn  # type: ignore
-    kwargs = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
-    try:
-        params = inspect.signature(fn).parameters
-    except (TypeError, ValueError):
-        params = {}
-    if "check_vma" in params:
-        kwargs["check_vma"] = check
-    elif "check_rep" in params:
-        kwargs["check_rep"] = check
-    elif not check:
-        # Callers pass check=False when their body violates replication
-        # checking (e.g. the int8 partial-sum collectives); silently running
-        # with checking on would fail later with an opaque trace-time error.
-        raise NotImplementedError(
-            "this JAX's shard_map exposes neither check_vma nor check_rep; "
-            "cannot honor check=False — teach repro.compat.shard_map its "
-            "new flag name"
-        )
-    return fn(f, **kwargs)
+    """``jax.shard_map``; ``check`` is its ``check_vma`` flag (callers pass
+    False when their body's collectives defeat replication checking, e.g.
+    the int8 partial-sum collectives)."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
+    )
 
 
 @contextlib.contextmanager
 def set_mesh(mesh: Optional[jax.sharding.Mesh]):
-    """Ambient-mesh context across JAX versions.
-
-    Prefers ``jax.set_mesh`` (0.6+), then ``jax.sharding.use_mesh`` (0.5.x),
-    then the ``Mesh`` object's own context manager (0.4.x). ``mesh=None`` is
-    a no-op so callers can write ``with compat.set_mesh(maybe_mesh): ...``.
-    """
+    """``jax.set_mesh`` as an ambient-mesh context. ``mesh=None`` is a no-op
+    so callers can write ``with compat.set_mesh(maybe_mesh): ...``."""
     if mesh is None:
         yield None
         return
-    if has("set_mesh"):
-        with jax.set_mesh(mesh):
-            yield mesh
-    elif has("use_mesh"):
-        with jax.sharding.use_mesh(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh
+    with jax.set_mesh(mesh):
+        yield mesh

@@ -1,10 +1,10 @@
-"""Capability probes for version-sensitive JAX surfaces.
+"""Probes of the JAX surfaces that ``repro.compat`` relies on.
 
-Callers branch on *features* (``compat.has("mesh_axis_types")``), never on
-``jax.__version__`` strings. A probe inspects the installed ``jax`` module
-lazily the first time a feature is asked for and the verdict is cached;
-``reset_cache()`` clears the cache so tests can monkeypatch ``jax`` to
-simulate a newer/older API surface (see tests/test_compat.py).
+The repo supports the installed JAX release only; compat calls each surface
+directly and branches on nothing. Each probe names one surface compat
+assumes, so a different JAX fails ``tests/test_compat.py`` on the surface
+that moved instead of deep inside a trace. Verdicts are cached;
+``reset_cache()`` clears them so tests can monkeypatch ``jax``.
 """
 
 from __future__ import annotations
@@ -15,56 +15,49 @@ from typing import Callable, Dict
 import jax
 
 
-def _probe_make_mesh() -> bool:
-    """``jax.make_mesh`` (added 0.4.35; before that: mesh_utils + Mesh)."""
-    return callable(getattr(jax, "make_mesh", None))
-
-
-def _probe_axis_type_enum() -> bool:
-    """``jax.sharding.AxisType`` (the Auto/Explicit/Manual enum, 0.5+)."""
-    return hasattr(jax.sharding, "AxisType")
+def _params(fn) -> tuple:
+    try:
+        return tuple(inspect.signature(fn).parameters)
+    except (TypeError, ValueError):
+        return ()
 
 
 def _probe_mesh_axis_types() -> bool:
-    """``jax.make_mesh(..., axis_types=...)`` keyword support."""
-    if not (_probe_make_mesh() and _probe_axis_type_enum()):
-        return False
-    try:
-        sig = inspect.signature(jax.make_mesh)
-    except (TypeError, ValueError):
-        return False
-    return "axis_types" in sig.parameters
+    """``jax.make_mesh(..., axis_types=...)`` with ``jax.sharding.AxisType``."""
+    return hasattr(jax.sharding, "AxisType") and "axis_types" in _params(
+        getattr(jax, "make_mesh", None)
+    )
 
 
 def _probe_set_mesh() -> bool:
-    """``jax.set_mesh`` ambient-mesh context (0.6+)."""
+    """``jax.set_mesh`` as the ambient-mesh context."""
     return callable(getattr(jax, "set_mesh", None))
 
 
-def _probe_use_mesh() -> bool:
-    """``jax.sharding.use_mesh`` ambient-mesh context (0.5.x)."""
-    return callable(getattr(jax.sharding, "use_mesh", None))
+def _probe_shard_map_check_vma() -> bool:
+    """``jax.shard_map(..., check_vma=...)``."""
+    return "check_vma" in _params(getattr(jax, "shard_map", None))
 
 
-def _probe_positional_sharding() -> bool:
-    """``jax.sharding.PositionalSharding`` (removed in newer JAX)."""
-    return hasattr(jax.sharding, "PositionalSharding")
+def _probe_core_var_aval() -> bool:
+    """``Var(aval)``: the jaxpr variable takes its aval first (compat.jaxpr)."""
+    from jax._src import core as src_core
+
+    return _params(src_core.Var.__init__)[1:2] == ("aval",)
 
 
 _PROBES: Dict[str, Callable[[], bool]] = {
-    "make_mesh": _probe_make_mesh,
-    "axis_type_enum": _probe_axis_type_enum,
     "mesh_axis_types": _probe_mesh_axis_types,
     "set_mesh": _probe_set_mesh,
-    "use_mesh": _probe_use_mesh,
-    "positional_sharding": _probe_positional_sharding,
+    "shard_map_check_vma": _probe_shard_map_check_vma,
+    "core_var_aval": _probe_core_var_aval,
 }
 
 _CACHE: Dict[str, bool] = {}
 
 
 def has(feature: str) -> bool:
-    """True iff the installed JAX supports `feature` (see _PROBES keys)."""
+    """True iff the installed JAX has `feature` (see _PROBES keys)."""
     if feature not in _PROBES:
         raise KeyError(
             f"unknown compat feature {feature!r}; known: {sorted(_PROBES)}"

@@ -1,6 +1,5 @@
-"""Sharding-object helpers, kept in one place so call sites survive JAX's
-ongoing sharding-API churn (``PositionalSharding`` removal, ``NamedSharding``
-constructor moves)."""
+"""Sharding-object helpers, kept in one place so call sites name shardings
+one way."""
 
 from __future__ import annotations
 
@@ -8,8 +7,6 @@ from typing import Sequence, Union
 
 import jax
 from jax.sharding import NamedSharding, PartitionSpec
-
-from .probes import has
 
 SpecLike = Union[PartitionSpec, Sequence, None]
 
@@ -29,15 +26,3 @@ def named_sharding(mesh: jax.sharding.Mesh, spec: SpecLike = None) -> NamedShard
 
 def replicated_sharding(mesh: jax.sharding.Mesh) -> NamedSharding:
     return NamedSharding(mesh, PartitionSpec())
-
-
-def positional_sharding(devices):
-    """``jax.sharding.PositionalSharding`` where it still exists; newer JAX
-    removed it in favor of NamedSharding, so callers must gate on
-    ``compat.has("positional_sharding")`` and provide a mesh-based path."""
-    if not has("positional_sharding"):
-        raise NotImplementedError(
-            "this JAX has no PositionalSharding; build a mesh and use "
-            "compat.named_sharding instead"
-        )
-    return jax.sharding.PositionalSharding(devices)

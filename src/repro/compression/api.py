@@ -113,12 +113,15 @@ def flat_pack(tree, lead_ndim: int = 0, cols: Optional[int] = PACK_COLS):
                     widths = [(0, 0)] * (part.ndim - 1) + [(0, pad)]
                     part = jnp.pad(part, widths)
                 stride = size + pad
+                # Concatenate (rows, cols) blocks, not one long vector: a
+                # concat along a minor dim of ~10^8 entries sends the TPU
+                # compiler into minutes and tens of GB of host memory.
+                part = part.reshape(lead + (-1, cols))
             parts.append(part)
             segs.append((i, size, stride))
-        buf = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=-1)
+        axis = -2 if cols else -1
+        buf = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis)
         segments[key] = tuple(segs)
-        if cols:
-            buf = buf.reshape(lead + (-1, cols))
         buffers[key] = buf
     spec = PackSpec(treedef, tuple(trail_shapes), tuple(dtypes), segments,
                     cols)
@@ -132,12 +135,18 @@ def flat_unpack(buffers, spec: PackSpec, lead_ndim: int = 0):
     for key, segs in spec.segments.items():
         buf = buffers[key]
         lead = buf.shape[:lead_ndim]
-        flat = buf.reshape(lead + (-1,))
+        # Row blocks are sliced along the rows axis (every stride is a whole
+        # number of rows), for the same reason flat_pack concatenates them.
+        width = spec.cols or 1
+        if not spec.cols:
+            buf = buf.reshape(lead + (-1, 1))
         offset = 0
         for i, size, stride in segs:
-            piece = jax.lax.slice_in_dim(
-                flat, offset, offset + size, axis=flat.ndim - 1
+            rows = jax.lax.slice_in_dim(
+                buf, offset // width, (offset + stride) // width,
+                axis=buf.ndim - 2,
             )
+            piece = rows.reshape(lead + (-1,))[..., :size]
             leaves[i] = piece.reshape(lead + spec.trail_shapes[i]).astype(
                 spec.dtypes[i]
             )

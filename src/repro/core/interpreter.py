@@ -59,7 +59,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.extend import core as jex_core
-from jax._src import core as _src_core
+
+from repro.compat import jaxpr as jaxpr_compat
 
 _COMM = {
     "drjax_broadcast": "broadcast",
@@ -120,8 +121,7 @@ def _is_literal(a) -> bool:
     return isinstance(a, jex_core.Literal)
 
 
-def _is_dropvar(v) -> bool:
-    return isinstance(v, _src_core.DropVar)
+_is_dropvar = jaxpr_compat.is_dropvar
 
 
 def _eqn_subjaxprs(eqn):
@@ -166,15 +166,7 @@ def _call_subjaxpr(eqn) -> Optional[Any]:
     return None
 
 
-def _fresh_var(aval):
-    """A new Var with the given aval, across JAX Var-constructor vintages."""
-    try:
-        return _src_core.Var("", aval)  # 0.4.3x: Var(suffix, aval)
-    except TypeError:
-        try:
-            return _src_core.Var(aval)  # newer: Var(aval)
-        except TypeError:
-            return _src_core.Var(0, "", aval)  # oldest: Var(count, suffix, aval)
+_fresh_var = jaxpr_compat.fresh_var
 
 
 def _rewrite_eqn(eqn, resolve):
@@ -182,13 +174,7 @@ def _rewrite_eqn(eqn, resolve):
     new_invars = [resolve(a) for a in eqn.invars]
     if all(a is b for a, b in zip(new_invars, eqn.invars)):
         return eqn
-    try:
-        return eqn.replace(invars=new_invars)
-    except AttributeError:  # very old JaxprEqn without .replace
-        return _src_core.new_jaxpr_eqn(
-            new_invars, eqn.outvars, eqn.primitive, eqn.params, eqn.effects,
-            eqn.source_info,
-        )
+    return eqn.replace(invars=new_invars)
 
 
 # ---------------------------------------------------------------------------
@@ -1174,7 +1160,7 @@ def _run_loop_stage(stage: LoopStage, read, write):
                 )
             cond_jaxpr = params["cond_jaxpr"]
             return bool(
-                _src_core.eval_jaxpr(
+                jaxpr_compat.eval_jaxpr(
                     cond_jaxpr.jaxpr, cond_jaxpr.consts, *cond_consts, *carry
                 )[0]
             )

@@ -44,6 +44,9 @@ import numpy as np
 from jax import core
 from jax.extend.core import Primitive
 from jax.interpreters import ad, batching, mlir
+from jax.sharding import PartitionSpec as P
+
+from repro import compat
 
 from . import placement as placement_lib
 from . import sharding as sharding_lib
@@ -190,10 +193,16 @@ batching.primitive_batchers[broadcast_p] = _broadcast_batch
 # ---------------------------------------------------------------------------
 
 
-def _fused_compress_reduce(x, i, name: str, compress: str, qaxis: int):
+def _fused_compress_reduce(x, i, name: str, compress: str, qaxis: int,
+                           pctx: placement_lib.PlacementContext):
     """Execute a ``compress``-tagged reduction: the fused single-pass
     reduce+roundtrip (Pallas kernel on TPU, fused jnp oracle elsewhere —
-    see ``repro.kernels.ops.reduce_compress_roundtrip``)."""
+    see ``repro.kernels.ops.reduce_compress_roundtrip``).
+
+    On a mesh the kernel runs per shard, inside a shard_map: GSPMD cannot
+    partition a Mosaic kernel. Where the reduced groups are themselves
+    sharded, each shard sums its groups, an all-reduce completes the mean,
+    and the kernel quantizes that one partial (a group count of 1)."""
     if name != "reduce_mean" or compress != "int8":
         raise NotImplementedError(
             f"drjax.{name}: fused compress={compress!r} is only implemented "
@@ -201,7 +210,32 @@ def _fused_compress_reduce(x, i, name: str, compress: str, qaxis: int):
         )
     from repro.kernels import ops as kernel_ops  # lazy: keep core import-light
 
-    return kernel_ops.reduce_compress_roundtrip(x, axis=i, qaxis=qaxis)
+    if pctx.mesh is None or not pctx.use_sharding_annotations:
+        return kernel_ops.reduce_compress_roundtrip(x, axis=i, qaxis=qaxis)
+
+    def entry(pl):
+        axes = pl.axes_tuple()
+        return (axes if len(axes) > 1 else axes[0]) if axes else None
+
+    lead = [entry(pl) for pl in pctx.placements[: i + 1]]
+    reduced = pctx.placements[i].axes_tuple()
+    groups = x.shape[i]
+
+    def per_shard(xs):
+        if not reduced:
+            return kernel_ops.reduce_compress_roundtrip(xs, axis=i, qaxis=qaxis)
+        part = jax.lax.psum(jnp.sum(xs.astype(jnp.float32), axis=i), reduced)
+        part = (part * (1.0 / groups)).astype(xs.dtype)
+        return kernel_ops.reduce_compress_roundtrip(
+            jnp.expand_dims(part, i), axis=i, qaxis=qaxis
+        )
+
+    rest = [None] * (x.ndim - i - 1)
+    return compat.shard_map(
+        per_shard, mesh=pctx.mesh,
+        in_specs=(P(*lead, *rest),), out_specs=P(*lead[:i], *rest),
+        check=False,
+    )(x)
 
 
 def _make_reduction(name: str, reduce_fn):
@@ -212,7 +246,7 @@ def _make_reduction(name: str, reduce_fn):
         pl, i = _resolve(pctx, placement)
         _check_kind(pl, name, "replicas")  # eager binds skip abstract
         if compress is not None:
-            out = _fused_compress_reduce(x, i, name, compress, qaxis)
+            out = _fused_compress_reduce(x, i, name, compress, qaxis, pctx)
         else:
             out = reduce_fn(x, pl, i)
         if i == 0:

@@ -82,14 +82,14 @@ def dequantize(q, scales, *, dtype=None, row_block=256, interpret=None):
 
 
 @functools.partial(jax.jit, static_argnames=("row_block", "interpret"))
-def reduce_compress(x, *, row_block=256, interpret=None):
+def reduce_compress(x, *, row_block=None, interpret=None):
     """(G, R, C) -> ((R, C) int8, (R, 1) f32): fused partial mean + quantize."""
     interpret = (not _on_tpu()) if interpret is None else interpret
     return _rc.reduce_compress(x, row_block=row_block, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("row_block", "interpret"))
-def dequant_accumulate(q, scales, *, row_block=256, interpret=None):
+def dequant_accumulate(q, scales, *, row_block=None, interpret=None):
     """((P, R, C) int8, (P, R, 1)) -> (R, C): fused dequantize + pod mean."""
     interpret = (not _on_tpu()) if interpret is None else interpret
     return _rc.dequant_accumulate(
@@ -115,17 +115,20 @@ def _roundtrip_rows(part, qaxis):
 def _reduce_compress_roundtrip_jnp(x, axis, qaxis):
     """Fused jnp oracle: one pass over ``x`` produces the roundtrip partial.
 
-    The partial mean is a block-diagonal matmul (one gemm reads the operand
-    once and emits every pod's partial), which XLA:CPU executes far faster
-    than a chain of axis reductions; the quantize/dequantize then runs on the
-    small partial only.
+    Off TPU the partial mean is a block-diagonal matmul (one gemm reads the
+    operand once and emits every pod's partial), which XLA:CPU executes far
+    faster than a chain of axis reductions; the quantize/dequantize then runs
+    on the small partial only. On TPU it is the plain axis mean: the gemm
+    flattens each group to one vector, and a minor dimension of ~10^8
+    entries sends the TPU compiler into minutes and tens of GB of memory.
     """
     lead = x.shape[:axis]
     g = x.shape[axis]
     trail = x.shape[axis + 1:]
     l = int(np.prod(lead, dtype=np.int64)) if lead else 1
     d = int(np.prod(trail, dtype=np.int64)) if trail else 1
-    if x.dtype == jnp.float32 and l * l * g <= _GEMM_WEIGHT_LIMIT:
+    if (x.dtype == jnp.float32 and l * l * g <= _GEMM_WEIGHT_LIMIT
+            and not _on_tpu()):
         w = jnp.repeat(jnp.eye(l, dtype=jnp.float32), g, axis=1) * (1.0 / g)
         part = (w @ x.reshape(l * g, d)).reshape(lead + trail)
     else:
@@ -169,7 +172,7 @@ def _reduce_compress_roundtrip_pallas(x, axis, qaxis, row_block, interpret):
     jax.jit, static_argnames=("axis", "qaxis", "row_block", "backend",
                               "interpret")
 )
-def reduce_compress_roundtrip(x, *, axis=0, qaxis=-1, row_block=256,
+def reduce_compress_roundtrip(x, *, axis=0, qaxis=-1, row_block=None,
                               backend=None, interpret=False):
     """Straight-through fused reduce+compress: mean over ``axis`` followed by
     an int8 roundtrip with per-row-block scales over ``qaxis`` (an axis of

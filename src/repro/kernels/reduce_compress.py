@@ -19,6 +19,12 @@ produce it in a single pass over the deltas:
   per-pod payloads and accumulates their mean in one pass, so the f32
   partials are never materialized on the receiving side either.
 
+Each grid step holds one row block of every operand in VMEM, double
+buffered. :func:`_row_block` sizes the block from a VMEM budget, so the
+group count G (clients per pod, or pods) does not decide whether the kernel
+compiles: a fixed 256-row block of f32 deltas overruns v5e's scoped VMEM
+from G=32 on.
+
 Scale granularity is per row block: rows map to the sublane dimension and a
 row is one lane-contiguous block of ``C`` values (the flat-packing utility in
 ``repro.compression`` lays trees out as ``(..., R, 256)`` buffers, so a
@@ -33,6 +39,8 @@ in via ``jax.vmap``):
 """
 
 from __future__ import annotations
+
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -72,40 +80,54 @@ def _dequant_accumulate_kernel(q_ref, s_ref, out_ref):
     out_ref[...] = jnp.sum(back, axis=0) * (1.0 / q.shape[0])
 
 
-def _pad_rows(x, row_block, axis):
-    pad = (-x.shape[axis]) % row_block
-    if pad:
-        widths = [(0, 0)] * x.ndim
-        widths[axis] = (0, pad)
-        x = jnp.pad(x, widths)
-    return x
+# Bytes of VMEM that the double-buffered blocks of one grid step may take.
+# Mosaic's default scoped VMEM limit on v5e is 16 MiB; the rest is left to
+# the kernel's temporaries.
+_VMEM_BUDGET = 12 * 2**20
+# A (rows, 1) f32 block is laid out in (8, 128) tiles: 128 lanes per row.
+_SCALE_ROW_BYTES = 128 * 4
+# int8 blocks tile rows by 32, so a partial row block is a multiple of 32.
+_INT8_ROWS = 32
 
 
-def reduce_compress(x, *, row_block: int = 256, interpret: bool = False):
+def _row_block(rows: int, row_bytes: int, row_block: Optional[int]) -> int:
+    """Rows per grid step. ``row_bytes`` is what one row of every operand's
+    block takes in VMEM. Without an explicit ``row_block``: all rows if their
+    double-buffered blocks fit ``_VMEM_BUDGET``, else the largest multiple of
+    32 that fits. The grid rounds up; the last block may be partial (its
+    rows past the end are never written, and every row is independent)."""
+    if row_block is None:
+        row_block = _VMEM_BUDGET // (2 * row_bytes)
+        if row_block < rows:
+            row_block = max(_INT8_ROWS, row_block - row_block % _INT8_ROWS)
+    return min(row_block, rows)
+
+
+def reduce_compress(x, *, row_block: Optional[int] = None,
+                    interpret: bool = False):
     """Fused partial mean + int8 quantize: (G, R, C) -> ((R, C) q, (R, 1) s)."""
     g, r, c = x.shape
-    row_block = min(row_block, r)
-    x = _pad_rows(x, row_block, axis=1)
-    rp = x.shape[1]
-    nb = rp // row_block
+    row_block = _row_block(
+        r, g * c * x.dtype.itemsize + c + _SCALE_ROW_BYTES, row_block
+    )
     q, s = pl.pallas_call(
         _reduce_compress_kernel,
-        grid=(nb,),
+        grid=(pl.cdiv(r, row_block),),
         in_specs=[pl.BlockSpec((g, row_block, c), lambda i: (0, i, 0))],
         out_specs=[
             pl.BlockSpec((row_block, c), lambda i: (i, 0)),
             pl.BlockSpec((row_block, 1), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((rp, c), jnp.int8),
-            jax.ShapeDtypeStruct((rp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((r, c), jnp.int8),
+            jax.ShapeDtypeStruct((r, 1), jnp.float32),
         ],
         interpret=interpret,
     )(x)
-    return q[:r], s[:r]
+    return q, s
 
 
-def reduce_compress_roundtrip(x, *, row_block: int = 256,
+def reduce_compress_roundtrip(x, *, row_block: Optional[int] = None,
                               interpret: bool = False):
     """Fused mean + quantize + dequantize: (G, R, C) -> (back, q, s).
 
@@ -113,13 +135,13 @@ def reduce_compress_roundtrip(x, *, row_block: int = 256,
     value the DrJAX reduction consumes; ``(q, s)`` is the wire payload.
     """
     g, r, c = x.shape
-    row_block = min(row_block, r)
-    x = _pad_rows(x, row_block, axis=1)
-    rp = x.shape[1]
-    nb = rp // row_block
+    itemsize = x.dtype.itemsize
+    row_block = _row_block(
+        r, (g + 1) * c * itemsize + c + _SCALE_ROW_BYTES, row_block
+    )
     back, q, s = pl.pallas_call(
         _reduce_compress_roundtrip_kernel,
-        grid=(nb,),
+        grid=(pl.cdiv(r, row_block),),
         in_specs=[pl.BlockSpec((g, row_block, c), lambda i: (0, i, 0))],
         out_specs=[
             pl.BlockSpec((row_block, c), lambda i: (i, 0)),
@@ -127,33 +149,29 @@ def reduce_compress_roundtrip(x, *, row_block: int = 256,
             pl.BlockSpec((row_block, 1), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((rp, c), x.dtype),
-            jax.ShapeDtypeStruct((rp, c), jnp.int8),
-            jax.ShapeDtypeStruct((rp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((r, c), x.dtype),
+            jax.ShapeDtypeStruct((r, c), jnp.int8),
+            jax.ShapeDtypeStruct((r, 1), jnp.float32),
         ],
         interpret=interpret,
     )(x)
-    return back[:r], q[:r], s[:r]
+    return back, q, s
 
 
-def dequant_accumulate(q, scales, *, row_block: int = 256,
+def dequant_accumulate(q, scales, *, row_block: Optional[int] = None,
                        interpret: bool = False):
     """Fused dequantize + mean over pods: ((P, R, C), (P, R, 1)) -> (R, C)."""
     p, r, c = q.shape
-    row_block = min(row_block, r)
-    q = _pad_rows(q, row_block, axis=1)
-    scales = _pad_rows(scales, row_block, axis=1)
-    rp = q.shape[1]
-    nb = rp // row_block
+    row_block = _row_block(r, p * (c + _SCALE_ROW_BYTES) + 4 * c, row_block)
     out = pl.pallas_call(
         _dequant_accumulate_kernel,
-        grid=(nb,),
+        grid=(pl.cdiv(r, row_block),),
         in_specs=[
             pl.BlockSpec((p, row_block, c), lambda i: (0, i, 0)),
             pl.BlockSpec((p, row_block, 1), lambda i: (0, i, 0)),
         ],
         out_specs=pl.BlockSpec((row_block, c), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rp, c), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((r, c), jnp.float32),
         interpret=interpret,
     )(q, scales)
-    return out[:r]
+    return out

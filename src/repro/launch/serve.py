@@ -74,6 +74,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import registry
 from repro.runtime.executor import TraceCounter
 
@@ -442,6 +443,7 @@ def main():
     ap.add_argument("--rate", type=float, default=0.0,
                     help="Poisson arrival rate (req/s); 0 = all at t=0")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = registry.get_config(args.arch)
     if args.reduced:

@@ -30,6 +30,7 @@ from repro import optim
 from repro.algorithms.rounds import LocalSGDConfig, make_local_sgd_round
 from repro.checkpoint import CheckpointManager
 from repro.data.grouped import CohortSampler, GroupedCorpus
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import registry
 from repro.runtime.failure import FailureInjector, run_with_recovery
 from repro.runtime.stragglers import StragglerSimulator, straggler_mask
@@ -61,7 +62,7 @@ def build_round_fn(cfg, args):
     return jax.jit(round_fn, donate_argnums=(0, 1)), server_opt
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="lm_350m", choices=registry.ARCH_IDS)
     ap.add_argument("--reduced", action="store_true",
@@ -90,8 +91,85 @@ def main():
                          "faults, serve traffic) with the production "
                          "invariants asserted (see repro.runtime.chaos)")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
+
+
+def train(args, *, on_round=None) -> dict:
+    """Train ``args.rounds`` rounds through :func:`run_with_recovery`.
+
+    ``on_round(round_idx, state, metrics)`` sees every completed round's
+    state. Returns the config, the per-round losses and seconds (host clock
+    around the round, ended by ``block_until_ready``), the recovery stats,
+    the final state and the checkpoint manager.
+    """
+    cfg = registry.get_config(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
+        args.seq = min(args.seq, 64)
+        args.batch = min(args.batch, 4)
+
+    params = registry.init_params(jax.random.PRNGKey(args.seed), cfg)
+    round_fn, server_opt = build_round_fn(cfg, args)
+    server_state = server_opt.init(params)
+
+    corpus = GroupedCorpus(vocab_size=cfg.vocab_size)
+    sampler = CohortSampler(corpus, cohort_size=args.cohort)
+    strag = StragglerSimulator() if args.stragglers else None
+    injector = FailureInjector(args.fail_at)
+    mgr = CheckpointManager(args.ckpt_dir, keep_last_n=3)
+    n_params = sum(
+        int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(params)
+    )
+    logger.info("arch=%s params=%.2fM cohort=%d local_steps=%d",
+                cfg.name, n_params / 1e6, args.cohort, args.local_steps)
+
+    history, round_s = [], []
+
+    def round_step(round_idx, state):
+        injector.check(round_idx)
+        params, server_state = state["params"], state["server"]
+        data = sampler.round_batch(
+            round_idx, args.local_steps, args.batch, args.seq
+        )
+        batch = {"tokens": data["tokens"], "labels": data["labels"]}
+        t0 = time.perf_counter()
+        if strag is not None:
+            durations = strag.durations(round_idx, args.cohort)
+            deadline = float(
+                np.percentile(durations, args.straggler_deadline_pct)
+            )
+            mask = straggler_mask(durations, deadline,
+                                  min_finishers=max(args.cohort // 2, 1))
+            out = round_fn(params, server_state, batch, mask)
+        else:
+            out = round_fn(params, server_state, batch)
+        params, server_state, metrics = jax.block_until_ready(out)
+        round_s.append(time.perf_counter() - t0)
+        loss = float(metrics["loss"])
+        history.append(loss)
+        if round_idx % args.log_every == 0:
+            logger.info("round %d loss %.4f (%.2fs)", round_idx, loss,
+                        round_s[-1])
+        state = {"params": params, "server": server_state}
+        if on_round is not None:
+            on_round(round_idx, state, metrics)
+        return state
+
+    init_state = {"params": params, "server": server_state}
+    final, stats = run_with_recovery(
+        round_step, init_state, args.rounds, mgr,
+        checkpoint_every=args.ckpt_every,
+    )
+    return {
+        "cfg": cfg, "n_params": n_params, "history": history,
+        "round_s": round_s, "stats": stats, "final": final, "ckpt": mgr,
+    }
+
+
+def main(argv=None):
+    args = parse_args(argv)
     logging.basicConfig(level=logging.INFO)
+    enable_compile_cache()
 
     if args.chaos:
         from repro.runtime.chaos import ChaosConfig, run_chaos_soak
@@ -111,68 +189,13 @@ def main():
         print(json.dumps(report.to_json(), indent=2))
         return
 
-    cfg = registry.get_config(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced()
-        args.seq = min(args.seq, 64)
-        args.batch = min(args.batch, 4)
-
-    params = registry.init_params(jax.random.PRNGKey(0), cfg)
-    round_fn, server_opt = build_round_fn(cfg, args)
-    server_state = server_opt.init(params)
-
-    corpus = GroupedCorpus(vocab_size=cfg.vocab_size)
-    sampler = CohortSampler(corpus, cohort_size=args.cohort)
-    strag = StragglerSimulator() if args.stragglers else None
-    injector = FailureInjector(args.fail_at)
-    mgr = CheckpointManager(args.ckpt_dir, keep_last_n=3)
-    n_params = sum(
-        int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(params)
-    )
-    logger.info("arch=%s params=%.2fM cohort=%d local_steps=%d",
-                cfg.name, n_params / 1e6, args.cohort, args.local_steps)
-
-    history = []
-
-    def round_step(round_idx, state):
-        injector.check(round_idx)
-        params, server_state = state["params"], state["server"]
-        data = sampler.round_batch(
-            round_idx, args.local_steps, args.batch, args.seq
-        )
-        batch = {"tokens": data["tokens"], "labels": data["labels"]}
-        t0 = time.time()
-        if strag is not None:
-            durations = strag.durations(round_idx, args.cohort)
-            deadline = float(
-                np.percentile(durations, args.straggler_deadline_pct)
-            )
-            mask = straggler_mask(durations, deadline,
-                                  min_finishers=max(args.cohort // 2, 1))
-            params, server_state, metrics = round_fn(
-                params, server_state, batch, mask
-            )
-        else:
-            params, server_state, metrics = round_fn(
-                params, server_state, batch
-            )
-        loss = float(metrics["loss"])
-        history.append(loss)
-        if round_idx % args.log_every == 0:
-            logger.info("round %d loss %.4f (%.2fs)", round_idx, loss,
-                        time.time() - t0)
-        return {"params": params, "server": server_state}
-
-    init_state = {"params": params, "server": server_state}
-    final, stats = run_with_recovery(
-        round_step, init_state, args.rounds, mgr,
-        checkpoint_every=args.ckpt_every,
-    )
+    result = train(args)
+    history, stats = result["history"], result["stats"]
     logger.info("done: %d rounds, %d restarts, final loss %.4f",
                 args.rounds, stats["restarts"],
                 history[-1] if history else float("nan"))
     print(json.dumps({
-        "arch": cfg.name,
+        "arch": result["cfg"].name,
         "algorithm": args.algorithm,
         "rounds": args.rounds,
         "restarts": stats["restarts"],

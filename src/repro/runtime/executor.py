@@ -61,9 +61,9 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax._src import core as _src_core
 from jax.extend import core as jex_core
 
+from repro.compat import jaxpr as jaxpr_compat
 from repro.core import interpreter as interp
 from repro.core import placement as placement_lib
 from repro.core import sharding as sharding_lib
@@ -234,8 +234,7 @@ def _is_literal(a) -> bool:
     return isinstance(a, jex_core.Literal)
 
 
-def _is_dropvar(v) -> bool:
-    return isinstance(v, _src_core.DropVar)
+_is_dropvar = jaxpr_compat.is_dropvar
 
 
 def _plan_consts(plan) -> Dict[Any, Any]:
@@ -390,7 +389,7 @@ class _PlanTracer:
                 pred = self.run(stage.cond_plan, list(cond_consts) + list(carry))[0]
             else:
                 cond_jaxpr = params["cond_jaxpr"]
-                pred = _src_core.eval_jaxpr(
+                pred = jaxpr_compat.eval_jaxpr(
                     cond_jaxpr.jaxpr, cond_jaxpr.consts, *cond_consts, *carry
                 )[0]
             return jnp.reshape(pred, ())
@@ -478,7 +477,7 @@ def executor_cache_size() -> int:
 def _aval_key(args) -> Tuple:
     out = []
     for a in args:
-        aval = _src_core.get_aval(a)
+        aval = jaxpr_compat.get_aval(a)
         out.append(
             (tuple(aval.shape), str(aval.dtype), bool(getattr(aval, "weak_type", False)))
         )

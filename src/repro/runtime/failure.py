@@ -13,6 +13,9 @@ Recovery policy:
    restore-and-replay — programming errors (``TypeError``/``ValueError``/...)
    propagate immediately instead of burning ``max_restarts`` on an error
    that every replay will hit again;
+ * so do device errors that replay cannot cure (:func:`is_fatal`): a program
+   the compiler refuses, or one that exhausts device memory, fails on the
+   first try with the compiler's own message;
  * restarts back off exponentially (``backoff_base_s * 2**(restart-1)``,
    capped) so a crash-looping fleet does not hammer the checkpoint store;
  * ``stats["completed_steps"]`` counts *forward progress* (high-water mark
@@ -26,6 +29,8 @@ from __future__ import annotations
 import logging
 import time
 from typing import Any, Callable, Optional, Tuple, Type
+
+import jax
 
 logger = logging.getLogger(__name__)
 
@@ -41,6 +46,18 @@ DEFAULT_RECOVERABLE: Tuple[Type[BaseException], ...] = (
     SimulatedDeviceFailure,
     RuntimeError,
 )
+
+# Status codes of device errors that every replay would raise again.
+_FATAL_STATUS = ("RESOURCE_EXHAUSTED", "INVALID_ARGUMENT", "UNIMPLEMENTED")
+
+
+def is_fatal(exc: BaseException) -> bool:
+    """True for a JAX runtime error that a restore cannot cure: the program
+    did not compile (XLA or Mosaic refused it) or did not fit the device."""
+    if not isinstance(exc, jax.errors.JaxRuntimeError):
+        return False
+    msg = str(exc)
+    return msg.startswith(_FATAL_STATUS) or "compil" in msg.lower()
 
 
 class FailureInjector:
@@ -79,8 +96,8 @@ def run_with_recovery(
     (the resume step is also injected into the checkpoint metadata under
     ``"step"``, so ``on_restore`` callbacks can see where they landed).
 
-    Only exceptions matching ``recoverable`` trigger a restore; everything
-    else propagates. ``backoff_base_s > 0`` sleeps
+    Only exceptions matching ``recoverable`` and not :func:`is_fatal`
+    trigger a restore; everything else propagates. ``backoff_base_s > 0`` sleeps
     ``min(backoff_cap_s, backoff_base_s * 2**(restart-1))`` before each
     restore.
 
@@ -126,6 +143,8 @@ def run_with_recovery(
                 meta = dict(meta, step=step)
                 checkpoint_mgr.save(step, state, metadata=meta, blocking=False)
         except recoverable as e:
+            if is_fatal(e):
+                raise
             restarts += 1
             stats["restarts"] = restarts
             if restarts > max_restarts:

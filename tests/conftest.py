@@ -53,6 +53,9 @@ class DevicePool:
         env["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={self.num_devices}"
         )
+        # The worker's devices are virtual CPU devices; keep it off any
+        # accelerator, which belongs to one process at a time.
+        env["JAX_PLATFORMS"] = "cpu"
         self.proc = subprocess.Popen(
             [sys.executable, "-u", _WORKER],
             stdin=subprocess.PIPE,

@@ -530,6 +530,21 @@ class TestLints:
         vs = run_lints(root=root, rules=["compat-surface"])
         assert [v.path for v in vs] == ["src/repro/models/bad.py"]
 
+    def test_compat_surface_rejects_jax_internals(self, tmp_path):
+        root = str(tmp_path)
+        internal = "jax." + "_src"  # assembled: this file must not match
+        _write(root, "src/repro/core/bad.py",
+               f"from {internal} import core\n"
+               f"x = 1  # {internal} mentioned beside compat\n")
+        _write(root, "src/repro/compat/jaxpr.py", f"from {internal} import core\n")
+        _write(root, "src/repro/compat_notes.py", f"import {internal}\n")
+        vs = run_lints(root=root, rules=["compat-surface"])
+        assert [(v.path, v.line) for v in vs] == [
+            ("src/repro/compat_notes.py", 1),
+            ("src/repro/core/bad.py", 1),
+            ("src/repro/core/bad.py", 2),
+        ]
+
     def test_donate_jit_rule_and_marker(self, tmp_path):
         root = str(tmp_path)
         _write(root, "src/repro/algorithms/bad.py", """\

@@ -1,9 +1,8 @@
 """Compat-layer tests.
 
-Two halves: (a) the layer works against the *installed* JAX (whatever
-version the environment has), and (b) a monkeypatched new-API present /
-absent matrix pins the branch each probe selects, so a JAX upgrade or
-downgrade can't silently flip behavior without a test noticing.
+Two halves: (a) the layer works against the installed JAX, which has every
+surface the probes name, and (b) monkeypatched ``jax`` surfaces pin what
+compat forwards to them, so a JAX upgrade that moves one fails here.
 """
 
 import contextlib
@@ -30,6 +29,9 @@ class TestOnInstalledJax:
         caps = compat.capabilities()
         assert caps, "no probes registered"
         assert all(isinstance(v, bool) for v in caps.values())
+        # compat has no fallback: the installed JAX must have every surface
+        missing = sorted(k for k, v in caps.items() if not v)
+        assert not missing, f"installed JAX lacks compat surfaces {missing}"
 
     def test_unknown_feature_raises(self):
         with pytest.raises(KeyError, match="unknown compat feature"):
@@ -46,10 +48,9 @@ class TestOnInstalledJax:
         assert mesh.devices.shape == (1,)
 
     def test_make_mesh_axis_type_request_is_portable(self):
-        # "auto" must build everywhere: applied where AxisType exists,
-        # dropped (with identical semantics) where it doesn't.
         mesh = compat.make_mesh((1,), ("data",), axis_types="auto")
         assert mesh.shape["data"] == 1
+        assert mesh.axis_types == (compat.axis_type("auto"),)
 
     def test_axis_type_rejects_unknown_kind(self):
         with pytest.raises(ValueError, match="unknown axis type"):
@@ -151,7 +152,7 @@ class _FakeAxisType:
 
 
 class TestProbeMatrix:
-    """Simulate newer/older JAX API surfaces by monkeypatching ``jax``."""
+    """Pin what compat forwards to ``jax`` by monkeypatching it."""
 
     def test_new_api_axis_types_forwarded(self, monkeypatch):
         recorded = {}
@@ -171,30 +172,6 @@ class TestProbeMatrix:
         assert recorded["axis_types"] == ("AUTO", "AUTO")
         assert compat.axis_type("explicit") == "EXPLICIT"
 
-    def test_old_api_axis_types_dropped(self, monkeypatch):
-        recorded = {}
-
-        def fake_make_mesh(shape, axes, *, devices=None, **kw):
-            recorded.update(kw)
-            return "OLD-MESH"
-
-        monkeypatch.setattr(jax, "make_mesh", fake_make_mesh)
-        monkeypatch.delattr(jax.sharding, "AxisType", raising=False)
-        compat.reset_cache()
-        assert not compat.has("axis_type_enum")
-        assert not compat.has("mesh_axis_types")
-        assert compat.make_mesh((8,), ("data",)) == "OLD-MESH"
-        assert "axis_types" not in recorded
-        assert compat.axis_type("auto") is None
-
-    def test_no_make_mesh_falls_back_to_mesh_utils(self, monkeypatch):
-        monkeypatch.delattr(jax, "make_mesh", raising=False)
-        compat.reset_cache()
-        assert not compat.has("make_mesh")
-        mesh = compat.make_mesh((1,), ("data",))
-        assert isinstance(mesh, jax.sharding.Mesh)
-        assert mesh.axis_names == ("data",)
-
     def test_set_mesh_prefers_jax_set_mesh(self, monkeypatch):
         seen = []
 
@@ -209,53 +186,6 @@ class TestProbeMatrix:
         with compat.set_mesh("the-mesh"):
             pass
         assert seen == ["the-mesh"]
-
-    def test_set_mesh_use_mesh_fallback(self, monkeypatch):
-        seen = []
-
-        @contextlib.contextmanager
-        def fake_use_mesh(mesh):
-            seen.append(mesh)
-            yield
-
-        monkeypatch.delattr(jax, "set_mesh", raising=False)
-        monkeypatch.setattr(
-            jax.sharding, "use_mesh", fake_use_mesh, raising=False
-        )
-        compat.reset_cache()
-        assert not compat.has("set_mesh")
-        assert compat.has("use_mesh")
-        with compat.set_mesh("the-mesh"):
-            pass
-        assert seen == ["the-mesh"]
-
-    def test_set_mesh_mesh_context_fallback(self, monkeypatch):
-        monkeypatch.delattr(jax, "set_mesh", raising=False)
-        monkeypatch.delattr(jax.sharding, "use_mesh", raising=False)
-        compat.reset_cache()
-
-        class FakeMesh:
-            entered = 0
-
-            def __enter__(self):
-                FakeMesh.entered += 1
-                return self
-
-            def __exit__(self, *exc):
-                return False
-
-        with compat.set_mesh(FakeMesh()):
-            pass
-        assert FakeMesh.entered == 1
-
-    def test_positional_sharding_gated(self, monkeypatch):
-        monkeypatch.delattr(
-            jax.sharding, "PositionalSharding", raising=False
-        )
-        compat.reset_cache()
-        assert not compat.has("positional_sharding")
-        with pytest.raises(NotImplementedError, match="PositionalSharding"):
-            compat.positional_sharding(jax.devices())
 
     def test_probe_cache_invalidation(self, monkeypatch):
         before = compat.has("set_mesh")

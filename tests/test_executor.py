@@ -25,15 +25,19 @@ from repro.core import interpreter as interp
 from repro.runtime import executor as executor_lib
 from repro.runtime.elastic import make_elastic_hierarchical_round
 from repro.runtime.executor import TraceCounter, compile_plan, fuse_stages
+from _ulp import assert_within_ulp
 
 
-def assert_compiled_bitwise(plan, args, **compile_kwargs):
+def assert_compiled_bitwise(plan, args, maxulp=0, **compile_kwargs):
     compiled = plan.compile(**compile_kwargs)
     outs = compiled(*args)
     ref = drjax.run_plan(plan, *args)
     assert len(outs) == len(ref)
     for a, b in zip(outs, ref):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        if maxulp:
+            assert_within_ulp(a, b, maxulp=maxulp)
+        else:
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     return compiled
 
 
@@ -236,7 +240,9 @@ class TestCompiledBitwise:
             jax.make_jaxpr(jax.jit(round_fn))(params, sstate, data), 4
         )
         flat = jax.tree_util.tree_leaves((params, sstate, data))
-        assert_compiled_bitwise(plan, flat)
+        # 1 ulp, not bitwise: the round's loss is a fused reduction whose
+        # accumulation order differs between op-by-op and compiled (_ulp.py).
+        assert_compiled_bitwise(plan, flat, maxulp=1)
 
     def test_async_round(self):
         loss_fn, params, data = quadratic_setup()
@@ -252,7 +258,8 @@ class TestCompiledBitwise:
             4,
         )
         flat = jax.tree_util.tree_leaves((params, pending, sstate, data))
-        assert_compiled_bitwise(plan, flat)
+        # 1 ulp: fused loss reduction, as in test_local_sgd_round.
+        assert_compiled_bitwise(plan, flat, maxulp=1)
 
     def test_multi_round_trainer(self):
         loss_fn, params, data = quadratic_setup()
@@ -269,7 +276,8 @@ class TestCompiledBitwise:
         )
         flat = jax.tree_util.tree_leaves((params, sstate, all_data))
         for loops in ("native", "unroll"):
-            assert_compiled_bitwise(plan, flat, loops=loops)
+            # 1 ulp: fused loss reduction, as in test_local_sgd_round.
+            assert_compiled_bitwise(plan, flat, maxulp=1, loops=loops)
 
     def test_hierarchical_two_level_reduce(self):
         @drjax.program(placements={"pods": 2, "clients": 4})
@@ -425,7 +433,9 @@ class TestExecutableCache:
         for a, b in zip(
             jax.tree_util.tree_leaves(ref), jax.tree_util.tree_leaves(out)
         ):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            # 1 ulp: the eager round and the jitted one reduce the loss in
+            # different orders (_ulp.py).
+            assert_within_ulp(b, a)
         # the donated form consumed its inputs
         assert all(
             l.is_deleted() for l in jax.tree_util.tree_leaves(params)

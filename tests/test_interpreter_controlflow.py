@@ -23,6 +23,7 @@ from repro.algorithms.rounds import (
     make_multi_round,
 )
 from repro.core import interpreter as interp
+from _ulp import assert_within_ulp
 
 
 # ---------------------------------------------------------------------------
@@ -34,14 +35,18 @@ def stage_kinds(plan):
     return [s.kind for s in plan.stages]
 
 
-def assert_bitwise(plan, fn, args):
-    """run_plan output == direct execution, bitwise, on CPU."""
+def assert_bitwise(plan, fn, args, maxulp=0):
+    """run_plan output == direct execution, bitwise on CPU (or within
+    ``maxulp`` where the caller says why)."""
     flat = jax.tree_util.tree_leaves(args)
     outs = drjax.run_plan(plan, *flat)
     direct = jax.tree_util.tree_leaves(fn(*args))
     assert len(outs) == len(direct)
     for a, b in zip(outs, direct):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        if maxulp:
+            assert_within_ulp(a, b, maxulp=maxulp)
+        else:
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 _GENERATED_NAME = re.compile(
@@ -383,7 +388,9 @@ class TestShippedAlgorithmPlans:
         assert loops[0].trip_count == num_rounds
         body_kinds = stage_kinds(loops[0].body_plan)
         assert "BROADCAST" in body_kinds and "REDUCE" in body_kinds
-        assert_bitwise(plan, trainer, (params, sstate, all_data))
+        # 1 ulp: the jitted trainer fuses the per-round loss reduction, which
+        # run_plan executes op by op (tests/_ulp.py).
+        assert_bitwise(plan, trainer, (params, sstate, all_data), maxulp=1)
 
 
 # ---------------------------------------------------------------------------

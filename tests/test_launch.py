@@ -105,3 +105,29 @@ def test_int8_prefill_variant_compiles(device_pool):
     """)
     assert res["ok"]
     assert res["s8"] >= 1
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_location(monkeypatch, tmp_path, env_dir):
+    """The cache goes where JAX_COMPILATION_CACHE_DIR says, and the helper
+    then sets nothing; unset, it is the fixed <repo>/.jax_cache."""
+    import os
+
+    import jax
+
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv(compile_cache.CACHE_ENV, raising=False)
+        want = os.path.join(compile_cache.REPO_ROOT, ".jax_cache")
+        assert os.path.isfile(os.path.join(compile_cache.REPO_ROOT, "ROADMAP.md"))
+    else:
+        want = str(tmp_path / env_dir)
+        monkeypatch.setenv(compile_cache.CACHE_ENV, want)
+    try:
+        assert compile_cache.enable_compile_cache() == want
+        after = jax.config.jax_compilation_cache_dir
+        assert after == (before if env_dir else want)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

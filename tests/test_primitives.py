@@ -205,8 +205,10 @@ class TestTransforms:
         np.testing.assert_array_equal(out, np.full((3,), 2.0, np.float32))
 
         xs = jnp.arange(3, dtype=jnp.float32)
-        reducer = batching.primitive_batchers[prims.reduce_sum_p]
-        out, d = reducer((xs,), (batching.not_mapped,), pctx=ctx)
+        # The registry holds the rule wrapped; the wrapper takes axis data
+        # first and binds the primitive itself when no operand is batched.
+        reducer = batching.fancy_primitive_batchers[prims.reduce_sum_p]
+        out, d = reducer(None, (xs,), (batching.not_mapped,), pctx=ctx)
         assert d is batching.not_mapped
         np.testing.assert_allclose(out, 3.0)
 

@@ -77,6 +77,55 @@ class TestRecoveryHardening:
             )
         assert calls == [0]
 
+    @pytest.mark.parametrize("message", [
+        "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
+        "memory in memory space hbm. Used 18.22G of 15.75G hbm.",
+        "INTERNAL: Mosaic failed to compile TPU kernel: ran out of memory "
+        "in memory space vmem",
+    ])
+    def test_compile_and_oom_errors_fail_fast(self, tmp_path, message):
+        """A device error that every replay would hit again (the compiler
+        refused the round, or it does not fit) propagates on the first try
+        with its own message, while a simulated device failure in the same
+        run still restores."""
+        mgr = CheckpointManager(str(tmp_path))
+        injector = FailureInjector(fail_at=[1])
+        calls, recoveries = [], []
+
+        def step_fn(step, state):
+            calls.append(step)
+            injector.check(step)
+            if step == 3:
+                raise jax.errors.JaxRuntimeError(message)
+            return {"x": state["x"] + 1.0}
+
+        with pytest.raises(jax.errors.JaxRuntimeError) as info:
+            run_with_recovery(
+                step_fn, {"x": jnp.float32(0)}, 5, mgr, checkpoint_every=1,
+                on_recovery=lambda i, s: recoveries.append((i, s)),
+            )
+        assert str(info.value) == message
+        assert recoveries == [(1, 1)]  # the simulated failure restored once
+        assert calls == [0, 1, 1, 2, 3]  # the fatal step ran once, no replay
+
+    def test_transient_runtime_error_still_restores(self, tmp_path):
+        """A JAX runtime error that is neither a compile refusal nor an
+        exhausted resource (a lost device) keeps the restore-and-replay."""
+        mgr = CheckpointManager(str(tmp_path))
+        fired = []
+
+        def step_fn(step, state):
+            if step == 2 and not fired:
+                fired.append(step)
+                raise jax.errors.JaxRuntimeError("UNAVAILABLE: device lost")
+            return {"x": state["x"] + 1.0}
+
+        final, stats = run_with_recovery(
+            step_fn, {"x": jnp.float32(0)}, 4, mgr, checkpoint_every=1,
+        )
+        assert float(final["x"]) == 4.0
+        assert stats["restarts"] == 1
+
     def test_custom_recoverable_allowlist(self, tmp_path):
         class FlakyStore(Exception):
             pass
