@@ -385,7 +385,7 @@ def run_one_chip(args, clock, *, hier_layers=None, kernel_marker="tpu_custom_cal
 
 def _sharded_round_once(cfg, args, mesh):
     """One round of ``make_drjax_round_step`` on ``mesh``: (loss, params on
-    the host, per-device memory right after the round, compile s, round s)."""
+    the host, per-device memory right after the round, round s)."""
     step, param_sh, server_sh, data_sh = steps_lib.make_drjax_round_step(
         cfg, mesh, partition_size=args.cohort,
         num_local_steps=args.local_steps, client_lr=args.client_lr,
