@@ -5,13 +5,15 @@
     python chip_smoke.py --chips 4   # the paths that exist only across chips
 
 One chip runs three phases in one process, on lm_350m at its published
-widths (``configs/lm_350m.py``, random weights from ``--seed``) with a
+widths (``configs/lm_350m.py``, random weights from seed 0) with a
 cohort of 4 clients, 4 local steps, batch 4 and sequence 512:
 
 * ``train``: 3 rounds through the donated round and the recovery loop that
   ``repro.launch.train`` runs, one checkpoint saved and restored bitwise;
 * ``plan``: the same jit-wrapped round through ``build_plan`` and
-  ``plan.compile()``, compared with the train phase's first round;
+  ``plan.compile()``, compared with the train phase's first round, and a
+  control round (half the clients' data replaced by the other half's) that
+  the comparison must reject;
 * ``hier-int8``: the pod-hierarchical round (2 pods x 2 clients, int8
   cross-pod leg) whose compiled HLO must hold the Mosaic reduce+compress
   kernel, and that kernel against its jnp oracle on the round's own
@@ -23,8 +25,10 @@ mesh, each against the same round on one device, and prints every chip's
 memory use.
 
 Each phase prints its compile seconds (XLA and Mosaic backend compiles, as
-JAX's monitoring reports them), its seconds per round (host clock, ended by
-``block_until_ready``), its losses and the device's ``peak_bytes_in_use``.
+JAX's monitoring reports them: ``compile_s`` for every compile in the phase,
+``round_compile_s`` for the round's own program), its seconds per round
+(host clock, ended by ``block_until_ready``), its losses and the device's
+``peak_bytes_in_use``.
 The last line of stdout is ``{"ok": true, "device": {...}}``. Without a TPU,
 or when a phase fails, the script exits non-zero and prints no result.
 """
@@ -32,6 +36,7 @@ or when a phase fails, the script exits non-zero and prints no result.
 from __future__ import annotations
 
 import argparse
+import collections
 import dataclasses
 import functools
 import json
@@ -70,19 +75,33 @@ PODS, CLIENTS_PER_POD = 2, 2
 # Widths stay lm_350m's; depth is cut to 16 (11.6 GB).
 HIER_LAYERS = 16
 
-# Tolerances between two programs that compute the same round.
+# Tolerances between two programs that compute the same round from the same
+# parameters p0 and data (the plan executor's HLO and the jitted round, or
+# one device and a mesh, whose client matmuls batch and whose reductions
+# order differently).
+#
 # The loss is an f32 mean over clients, steps and tokens of bf16 matmul
-# outputs. Two separately compiled programs (the plan executor's HLO and the
-# jitted round, or one device and a mesh, whose client matmuls batch and
-# whose reductions order differently) may round single bf16 values
-# differently, 2**-9 relative each; averaged over ~10^4 tokens that stays
-# far below 1e-3. A program that computes something else (a dropped client,
-# a stale parameter) moves the loss by more.
+# outputs; the two programs may round single bf16 values differently, 2**-9
+# relative each, which averaged over ~10^4 tokens stays far below 1e-3. The
+# loss is taken during the clients' local steps from p0, so it checks the
+# client leg only: a fault in the reduce or the server update leaves it as
+# it was.
 LOSS_RTOL = 1e-3
-# Parameters are compared per leaf as ||a - b|| / ||b||: a bf16 rounding
-# flip moves one element by one ulp (2**-8 relative), so even one flip in
-# every hundred elements gives ~4e-4.
-PARAM_RTOL = 1e-3
+# The round's output is checked as an update: over the whole parameter tree,
+# ||a - b|| / ||b - p0||, the distance between the two rounds' parameters
+# relative to the size of the update. Against ||b|| it would say nothing: one
+# round moves a bf16 weight of ~0.02 by ~1e-5, under half its ulp (1.2e-4),
+# so the weight does not move at all. The update is the few elements whose
+# step clears half their ulp: 4e-6 to 4e-5 of ||p0|| on a TPU v5e. Two
+# programs that round single values differently move different elements
+# across that threshold, so even the same round reads far from 0: a mesh
+# against one device read 0.255 (sharded round) and 0.300 (hier-int8) on a
+# v5e. A round that skips the update reads 1. Every phase that compares
+# also runs a control, the round with the second half of the cohort's data
+# replaced by the first half's, which must read above the limit: 1.32 and
+# 1.42 on the v5e, 0.57 to 0.85 on the reduced f32 model on the CPU, where
+# the same round reads under 1e-4. The limit sits between the two regimes.
+UPDATE_RTOL = 0.4
 # The Mosaic kernel and the jnp oracle quantize the same partial mean; an
 # ulp of difference in that mean can move a value across a rounding
 # boundary, so they may differ by one int8 step (the row's scale), never
@@ -110,22 +129,29 @@ class CompileClock:
     def __init__(self):
         self.seconds = 0.0
         self.cache_hits = 0
+        self.per_fun = collections.Counter()
         jax.monitoring.register_event_duration_secs_listener(self._on_duration)
         jax.monitoring.register_event_listener(self._on_event)
 
-    def _on_duration(self, event, duration, **_):
+    def _on_duration(self, event, duration, fun_name="", **_):
         if event == _COMPILE_EVENT:
             self.seconds += duration
+            self.per_fun[fun_name] += duration
 
     def _on_event(self, event, **_):
         if event == _CACHE_HIT_EVENT:
             self.cache_hits += 1
 
     def mark(self):
-        return self.seconds, self.cache_hits
+        return self.seconds, self.cache_hits, collections.Counter(self.per_fun)
 
     def since(self, mark):
+        """(compile seconds, cache hits) since ``mark``."""
         return self.seconds - mark[0], self.cache_hits - mark[1]
+
+    def fun_since(self, mark, fun_name: str) -> float:
+        """Compile seconds of the jitted function ``fun_name`` since ``mark``."""
+        return self.per_fun[fun_name] - mark[2][fun_name]
 
 
 def memory(device=None) -> dict:
@@ -157,16 +183,34 @@ def rel_close(a: float, b: float, rtol: float) -> bool:
 
 
 @jax.jit
-def _leaf_rel_diff(a, b):
-    """Per-leaf ||a - b|| / ||b|| in f32."""
-    def one(x, y):
-        x, y = x.astype(jnp.float32), y.astype(jnp.float32)
-        return jnp.linalg.norm(x - y) / jnp.maximum(jnp.linalg.norm(y), 1e-30)
-    return jnp.stack(jax.tree_util.tree_leaves(jax.tree_util.tree_map(one, a, b)))
+def _update_norms(a, b, p0):
+    """f32 norms, over the whole tree, of a - b, b - p0 and p0."""
+    def leaves(tree):
+        return [x.astype(jnp.float32) for x in jax.tree_util.tree_leaves(tree)]
+
+    def norm(xs):
+        return jnp.sqrt(sum(jnp.sum(jnp.square(x)) for x in xs))
+
+    a, b, p0 = leaves(a), leaves(b), leaves(p0)
+    return (norm([x - y for x, y in zip(a, b)]),
+            norm([y - z for y, z in zip(b, p0)]), norm(p0))
 
 
-def max_rel_diff(a, b) -> float:
-    return float(jnp.max(_leaf_rel_diff(a, b)))
+def same_tree(a, b) -> bool:
+    """Two host trees hold the same bytes."""
+    return all(
+        x.dtype == y.dtype and x.shape == y.shape and x.tobytes() == y.tobytes()
+        for x, y in zip(jax.tree_util.tree_leaves(a),
+                        jax.tree_util.tree_leaves(b))
+    )
+
+
+def update_distance(a, b, p0) -> tuple:
+    """(||a - b|| / ||b - p0||, ||b - p0|| / ||p0||) over the whole tree, in
+    f32: how far round ``a`` is from round ``b``, both started from ``p0``,
+    relative to ``b``'s update; and that update's size."""
+    diff, update, base = (float(v) for v in _update_norms(a, b, p0))
+    return diff / max(update, 1e-30), update / max(base, 1e-30)
 
 
 def round_batch(args, cfg, round_idx: int) -> dict:
@@ -197,7 +241,9 @@ def smoke_args(ckpt_dir: str, extra=()) -> argparse.Namespace:
 
 def phase_train(args, clock):
     """``args.rounds`` rounds of ``launch.train``; returns the report, the config
-    and round 0's loss and parameters (on the host) for the plan phase."""
+    and round 0's loss and parameters (on the host) for the plan phase.
+    ``compile_s`` also holds the eager compiles of parameter and optimizer
+    init; ``round_compile_s`` is the round's own (``train.build_round_fn``)."""
     first = {}
 
     def on_round(idx, state, metrics):
@@ -207,6 +253,7 @@ def phase_train(args, clock):
     mark = clock.mark()
     result = train_lib.train(args, on_round=on_round)
     compile_s, hits = clock.since(mark)
+    round_compile_s = clock.fun_since(mark, "jit(round_fn)")
     losses = result["history"]
     check(len(losses) == args.rounds and finite(losses),
           f"train losses {losses}")
@@ -215,25 +262,35 @@ def phase_train(args, clock):
     final = jax.device_get(result["final"])
     step, restored, _ = result["ckpt"].restore_latest(final)
     check(step == args.rounds, f"restored step {step}, want {args.rounds}")
-    same = [
-        a.dtype == b.dtype and a.shape == b.shape and a.tobytes() == b.tobytes()
-        for a, b in zip(jax.tree_util.tree_leaves(final),
-                        jax.tree_util.tree_leaves(restored))
-    ]
-    check(all(same), "restored checkpoint differs from the saved state")
+    check(same_tree(final, restored),
+          "restored checkpoint differs from the saved state")
     rep = report(
         "train", params_m=round(result["n_params"] / 1e6, 1),
-        compile_s=compile_s, compile_cache_hits=hits,
-        round_s=result["round_s"], losses=losses,
+        compile_s=compile_s, round_compile_s=round_compile_s,
+        compile_cache_hits=hits, round_s=result["round_s"], losses=losses,
         checkpoint_restored_bitwise=True, **memory(),
     )
     first["loss"] = losses[0]
     return rep, result["cfg"], first
 
 
+def control_batch(batch: dict) -> dict:
+    """``batch`` with the second half of its outermost client axis (clients,
+    or pods) replaced by the first half: a round whose mean update misses
+    half the cohort, as a dropped shard or pod would."""
+    out = {}
+    for k, v in batch.items():
+        v = np.array(v)
+        half = v.shape[0] // 2
+        v[v.shape[0] - half:] = v[:half]
+        out[k] = v
+    return out
+
+
 def phase_plan(args, cfg, first, clock):
     """The same jit-wrapped round through ``build_plan`` and the compiled
-    plan executor, on round 0's inputs."""
+    plan executor, on round 0's inputs; then the control round."""
+    mark = clock.mark()
     round_fn, server_opt = train_lib.build_round_fn(cfg, args)
     params, sstate = init_state(args, cfg, server_opt)
     batch = round_batch(args, cfg, 0)
@@ -242,33 +299,42 @@ def phase_plan(args, cfg, first, clock):
     )
     plan = drjax.build_plan(closed, args.cohort)
     compiled = plan.compile()
-    flat = jax.tree_util.tree_leaves((params, sstate, batch))
+    tree = jax.tree_util.tree_structure(out_shape)
 
-    mark = clock.mark()
-    round_s = []
-    for _ in range(2):  # the first call compiles
+    def run(b):
         t0 = time.perf_counter()
-        outs = jax.block_until_ready(compiled(*flat))
-        round_s.append(time.perf_counter() - t0)
-    compile_s, hits = clock.since(mark)
-    check(compiled.trace_count == 1, f"plan traced {compiled.trace_count}x")
+        outs = jax.block_until_ready(
+            compiled(*jax.tree_util.tree_leaves((params, sstate, b)))
+        )
+        new_params, _, metrics = jax.tree_util.tree_unflatten(tree, list(outs))
+        return new_params, float(metrics["loss"]), time.perf_counter() - t0
 
-    new_params, _, metrics = jax.tree_util.tree_unflatten(
-        jax.tree_util.tree_structure(out_shape), list(outs)
+    round_mark = clock.mark()
+    new_params, loss, first_s = run(batch)  # this call compiles
+    round_compile_s, _ = clock.since(round_mark)
+    _, _, second_s = run(batch)
+    ctl_params, ctl_loss, _ = run(control_batch(batch))
+    compile_s, hits = clock.since(mark)
+
+    diff, update = update_distance(new_params, first["params"], params)
+    ctl_diff, _ = update_distance(ctl_params, first["params"], params)
+    rep = report(
+        "plan", stages=[s.kind for s in plan.stages], compile_s=compile_s,
+        round_compile_s=round_compile_s, compile_cache_hits=hits,
+        round_s=[first_s, second_s], losses=[loss],
+        train_round0_loss=first["loss"], update_rel_norm=update,
+        update_rel_diff=diff, control_loss=ctl_loss,
+        control_update_rel_diff=ctl_diff, **memory(),
     )
-    loss = float(metrics["loss"])
-    param_diff = max_rel_diff(new_params, first["params"])
+    check(compiled.trace_count == 1, f"plan traced {compiled.trace_count}x")
     check(finite([loss]), f"plan loss {loss}")
     check(rel_close(loss, first["loss"], LOSS_RTOL),
           f"plan loss {loss} vs train round 0 {first['loss']}")
-    check(param_diff <= PARAM_RTOL,
-          f"plan params differ from train round 0 by {param_diff}")
-    return report(
-        "plan", stages=[s.kind for s in plan.stages], compile_s=compile_s,
-        compile_cache_hits=hits, round_s=round_s, losses=[loss],
-        train_round0_loss=first["loss"], max_param_rel_diff=param_diff,
-        **memory(),
-    )
+    check(diff <= UPDATE_RTOL,
+          f"plan update differs from train round 0 by {diff}")
+    check(ctl_diff > UPDATE_RTOL,
+          f"the control round passes the update check ({ctl_diff})")
+    return rep
 
 
 def hier_round(cfg, args, *, mesh=None):
@@ -329,22 +395,19 @@ def phase_hier_int8(args, cfg, clock, *, kernel_marker="tpu_custom_call",
     show that the Mosaic kernel, not the jnp oracle, runs in the round."""
     check(os.environ.get("REPRO_NO_FUSED_REDUCE", "") in ("", "0"),
           "REPRO_NO_FUSED_REDUCE is set: the fused kernel would not run")
+    mark = clock.mark()
     round_fn, client_update, server_opt = hier_round(cfg, args)
     params, sstate = init_state(args, cfg, server_opt)
 
     kernel_steps = float(_kernel_vs_oracle(
         params, pod_batch(args, cfg, 0), client_update, interpret
     ))
-    check(kernel_steps <= INT8_STEPS,
-          f"kernel vs oracle differ by {kernel_steps} int8 steps")
 
-    mark = clock.mark()
+    round_mark = clock.mark()
     step = jax.jit(round_fn, donate_argnums=(0, 1))
     compiled = step.lower(params, sstate, pod_batch(args, cfg, 0)).compile()
-    compile_s, hits = clock.since(mark)
-    if kernel_marker is not None:
-        check(kernel_marker in compiled.as_text(),
-              f"{kernel_marker} missing from the hier-int8 round's HLO")
+    round_compile_s, _ = clock.since(round_mark)
+    kernel = kernel_marker is None or kernel_marker in compiled.as_text()
 
     losses, round_s = [], []
     for r in range(ROUNDS):
@@ -355,13 +418,19 @@ def phase_hier_int8(args, cfg, clock, *, kernel_marker="tpu_custom_call",
         )
         round_s.append(time.perf_counter() - t0)
         losses.append(float(metrics["loss"]))
-    check(finite(losses), f"hier-int8 losses {losses}")
-    return report(
+    compile_s, hits = clock.since(mark)
+    rep = report(
         "hier-int8", pods=PODS, clients_per_pod=CLIENTS_PER_POD,
         layers=cfg.num_layers, kernel_in_hlo=kernel_marker,
         kernel_vs_oracle_int8_steps=kernel_steps, compile_s=compile_s,
-        compile_cache_hits=hits, round_s=round_s, losses=losses, **memory(),
+        round_compile_s=round_compile_s, compile_cache_hits=hits,
+        round_s=round_s, losses=losses, **memory(),
     )
+    check(kernel_steps <= INT8_STEPS,
+          f"kernel vs oracle differ by {kernel_steps} int8 steps")
+    check(kernel, f"{kernel_marker} missing from the hier-int8 round's HLO")
+    check(finite(losses), f"hier-int8 losses {losses}")
+    return rep
 
 
 def run_one_chip(args, clock, *, hier_layers=None, kernel_marker="tpu_custom_call",
@@ -383,9 +452,61 @@ def run_one_chip(args, clock, *, hier_layers=None, kernel_marker="tpu_custom_cal
 # ---------------------------------------------------------------------------
 
 
-def _sharded_round_once(cfg, args, mesh):
-    """One round of ``make_drjax_round_step`` on ``mesh``: (loss, params on
-    the host, per-device memory right after the round, round s)."""
+def _timed_round(clock, step, params, sstate, batch):
+    """Compile ``step`` for these arguments, run one round, then the control
+    round from the same start: (loss, start and end parameters on the host,
+    round compile s, round s, control end parameters), and the executable."""
+    mark = clock.mark()
+    compiled = step.lower(params, sstate, batch).compile()
+    compile_s, _ = clock.since(mark)
+    shardings = jax.tree_util.tree_map(lambda x: x.sharding, (params, sstate))
+    start = jax.device_get((params, sstate))  # the round donates its inputs
+    t0 = time.perf_counter()
+    params, sstate, metrics = jax.block_until_ready(
+        compiled(params, sstate, batch)
+    )
+    round_s = time.perf_counter() - t0
+    end, loss = jax.device_get(params), float(metrics["loss"])
+    del params, sstate  # free the device for the control round
+    ctl = jax.tree_util.tree_map(
+        lambda c, b: jax.device_put(c, b.sharding),
+        control_batch(jax.device_get(batch)), batch,
+    )
+    ctl_params, _, _ = compiled(*jax.device_put(start, shardings), ctl)
+    return (loss, start[0], end, compile_s, round_s,
+            jax.device_get(ctl_params)), compiled
+
+
+def _compare_rounds(phase, clock, mark, mesh_run, one_run, mem4, **fields):
+    """Report a mesh round against its one-device run, then check them."""
+    compile_s, hits = clock.since(mark)
+    loss4, p0_4, params4, compile4, round_s4, _ = mesh_run
+    loss1, p0_1, params1, compile1, round_s1, ctl1 = one_run
+    diff, update = update_distance(params4, params1, p0_1)
+    ctl_diff, _ = update_distance(ctl1, params1, p0_1)
+    rep = report(
+        phase, **fields, compile_s=compile_s,
+        round_compile_s={"mesh": compile4, "one_device": compile1},
+        compile_cache_hits=hits,
+        round_s={"mesh": round_s4, "one_device": round_s1},
+        losses={"mesh": loss4, "one_device": loss1},
+        update_rel_norm=update, update_rel_diff=diff,
+        control_update_rel_diff=ctl_diff, memory_per_device=mem4,
+    )
+    check(same_tree(p0_4, p0_1), "the mesh and one-device rounds start apart")
+    check(finite([loss4, loss1]), f"losses {loss4} {loss1}")
+    check(rel_close(loss4, loss1, LOSS_RTOL),
+          f"{phase} loss {loss4} vs one device {loss1}")
+    check(diff <= UPDATE_RTOL, f"{phase} update differs by {diff}")
+    check(ctl_diff > UPDATE_RTOL,
+          f"{phase}: the control round passes the update check ({ctl_diff})")
+    check(all_devices_used(mem4), f"a chip holds nothing: {mem4}")
+    return rep
+
+
+def _sharded_round_once(cfg, args, mesh, clock):
+    """One round of ``make_drjax_round_step`` on ``mesh``, and the memory of
+    each of its devices right after it."""
     step, param_sh, server_sh, data_sh = steps_lib.make_drjax_round_step(
         cfg, mesh, partition_size=args.cohort,
         num_local_steps=args.local_steps, client_lr=args.client_lr,
@@ -399,87 +520,52 @@ def _sharded_round_once(cfg, args, mesh):
     batch = jax.device_put(
         batch, jax.tree_util.tree_map(data_sh, batch)
     )
-    compiled = step.lower(params, sstate, batch).compile()
-    t0 = time.perf_counter()
-    params, sstate, metrics = jax.block_until_ready(
-        compiled(params, sstate, batch)
-    )
-    round_s = time.perf_counter() - t0
-    mem = {d.id: memory(d) for d in mesh.devices.flat}
-    return float(metrics["loss"]), jax.device_get(params), mem, round_s
+    run, _ = _timed_round(clock, step, params, sstate, batch)
+    return run, {d.id: memory(d) for d in mesh.devices.flat}
 
 
 def phase_sharded_round(args, cfg, clock, devices):
     mark = clock.mark()
     mesh4 = compat.make_mesh((len(devices), 1), ("data", "model"),
                              devices=devices)
-    loss4, params4, mem4, round_s4 = _sharded_round_once(cfg, args, mesh4)
+    run4, mem4 = _sharded_round_once(cfg, args, mesh4, clock)
     mesh1 = compat.make_mesh((1, 1), ("data", "model"), devices=devices[:1])
-    loss1, params1, _, round_s1 = _sharded_round_once(cfg, args, mesh1)
-    compile_s, hits = clock.since(mark)
-    diff = max_rel_diff(params4, params1)
-    check(finite([loss4, loss1]), f"losses {loss4} {loss1}")
-    check(rel_close(loss4, loss1, LOSS_RTOL),
-          f"4-chip loss {loss4} vs one device {loss1}")
-    check(diff <= PARAM_RTOL, f"4-chip params differ by {diff}")
-    check(all_devices_used(mem4), f"a chip holds nothing: {mem4}")
-    return report(
-        "sharded-round", mesh={"data": len(devices), "model": 1},
-        compile_s=compile_s, compile_cache_hits=hits,
-        round_s={"mesh": round_s4, "one_device": round_s1},
-        losses={"mesh": loss4, "one_device": loss1},
-        max_param_rel_diff=diff, memory_per_device=mem4,
-    )
+    run1, _ = _sharded_round_once(cfg, args, mesh1, clock)
+    return _compare_rounds("sharded-round", clock, mark, run4, run1, mem4,
+                           mesh={"data": len(devices), "model": 1})
 
 
-def _hier_once(cfg, args, mesh, kernel_marker):
+def _hier_once(cfg, args, mesh, clock):
+    """One hier-int8 round on ``mesh`` (one device if None): the run, the
+    memory of its devices and whether the kernel is in its HLO."""
     round_fn, _, server_opt = hier_round(cfg, args, mesh=mesh)
     params, sstate = init_state(args, cfg, server_opt)
-    batch = pod_batch(args, cfg, 0)
+    batch = jax.device_put(pod_batch(args, cfg, 0))
     if mesh is not None:
         rep = compat.replicated_sharding(mesh)
         params, sstate = jax.device_put((params, sstate), rep)
         batch = jax.device_put(
             batch, compat.named_sharding(mesh, ("pod", "data"))
         )
-    compiled = jax.jit(round_fn, donate_argnums=(0, 1)).lower(
-        params, sstate, batch
-    ).compile()
-    t0 = time.perf_counter()
-    params, sstate, metrics = jax.block_until_ready(
-        compiled(params, sstate, batch)
-    )
-    round_s = time.perf_counter() - t0
+    step = jax.jit(round_fn, donate_argnums=(0, 1))
+    run, compiled = _timed_round(clock, step, params, sstate, batch)
     devs = mesh.devices.flat if mesh is not None else jax.devices()[:1]
-    mem = {d.id: memory(d) for d in devs}
-    kernel = kernel_marker is None or kernel_marker in compiled.as_text()
-    return float(metrics["loss"]), jax.device_get(params), mem, round_s, kernel
+    return run, {d.id: memory(d) for d in devs}, compiled.as_text()
 
 
 def phase_hier_sharded(args, cfg, clock, devices, kernel_marker):
     mark = clock.mark()
     mesh = mesh_for_placements({"pods": PODS, "clients": CLIENTS_PER_POD},
                                devices=devices)
-    loss4, params4, mem4, round_s4, kernel4 = _hier_once(cfg, args, mesh,
-                                                         kernel_marker)
-    loss1, params1, _, round_s1, kernel1 = _hier_once(cfg, args, None,
-                                                      kernel_marker)
-    compile_s, hits = clock.since(mark)
-    diff = max_rel_diff(params4, params1)
-    check(kernel4 and kernel1, f"{kernel_marker} missing from a hier round")
-    check(finite([loss4, loss1]), f"losses {loss4} {loss1}")
-    check(rel_close(loss4, loss1, LOSS_RTOL),
-          f"(pod, data) loss {loss4} vs one device {loss1}")
-    check(diff <= PARAM_RTOL, f"(pod, data) params differ by {diff}")
-    check(all_devices_used(mem4), f"a chip holds nothing: {mem4}")
-    return report(
-        "hier-int8-sharded", mesh=dict(mesh.shape), layers=cfg.num_layers,
-        kernel_in_hlo=kernel_marker,
-        compile_s=compile_s, compile_cache_hits=hits,
-        round_s={"mesh": round_s4, "one_device": round_s1},
-        losses={"mesh": loss4, "one_device": loss1},
-        max_param_rel_diff=diff, memory_per_device=mem4,
-    )
+    run4, mem4, hlo4 = _hier_once(cfg, args, mesh, clock)
+    run1, _, hlo1 = _hier_once(cfg, args, None, clock)
+    rep = _compare_rounds("hier-int8-sharded", clock, mark, run4, run1, mem4,
+                          mesh=dict(mesh.shape), layers=cfg.num_layers,
+                          kernel_in_hlo=kernel_marker)
+    check(kernel_marker is None
+          or (kernel_marker in hlo4 and kernel_marker in hlo1),
+          f"{kernel_marker} missing from a hier round")
+    return rep
 
 
 def run_four_chips(args, clock, devices, *, hier_layers=None,
@@ -506,7 +592,6 @@ def run_four_chips(args, clock, devices, *, hier_layers=None,
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
-    ap.add_argument("--seed", type=int, default=0)
     opts = ap.parse_args(argv)
 
     devices = jax.devices()
@@ -526,7 +611,7 @@ def main(argv=None) -> int:
     clock = CompileClock()
     t0 = time.perf_counter()
     with tempfile.TemporaryDirectory(prefix="chip_smoke_ckpt_") as ckpt:
-        args = smoke_args(ckpt, ("--seed", str(opts.seed)))
+        args = smoke_args(ckpt)
         if opts.chips == 1:
             run_one_chip(args, clock, hier_layers=HIER_LAYERS)
         else:

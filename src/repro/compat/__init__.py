@@ -9,8 +9,8 @@ ROADMAP.md, enforced by the ``compat-surface`` lint):
     ``Compiled.cost_analysis()`` outside ``repro/compat``.
 
 Written for the installed JAX release only (0.9.0); there is no branch for
-another version. ``compat.capabilities()`` reports the surfaces this layer
-relies on, so a different JAX fails the compat tests where it differs.
+another version. Each surface is called directly, so a JAX that moved one
+fails ``tests/test_compat.py`` at that call.
 """
 
 from .cost import (
@@ -20,23 +20,18 @@ from .cost import (
     normalize_cost_analysis,
 )
 from .meshes import axis_type, make_mesh, set_mesh, shard_map
-from .probes import capabilities, has, jax_version, reset_cache
 from .shardings import named_sharding, partition_spec, replicated_sharding
 
 __all__ = [
     "axis_type",
-    "capabilities",
     "cost_analysis",
     "cost_bytes_accessed",
     "cost_flops",
-    "has",
-    "jax_version",
     "make_mesh",
     "named_sharding",
     "normalize_cost_analysis",
     "partition_spec",
     "replicated_sharding",
-    "reset_cache",
     "set_mesh",
     "shard_map",
 ]

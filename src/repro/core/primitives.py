@@ -202,7 +202,9 @@ def _fused_compress_reduce(x, i, name: str, compress: str, qaxis: int,
     On a mesh the kernel runs per shard, inside a shard_map: GSPMD cannot
     partition a Mosaic kernel. Where the reduced groups are themselves
     sharded, each shard sums its groups, an all-reduce completes the mean,
-    and the kernel quantizes that one partial (a group count of 1)."""
+    and the kernel quantizes that one f32 partial (a group count of 1), as
+    the one-device kernel quantizes its f32 mean. The axes after the reduced
+    one must be unsharded: each shard receives them whole."""
     if name != "reduce_mean" or compress != "int8":
         raise NotImplementedError(
             f"drjax.{name}: fused compress={compress!r} is only implemented "
@@ -225,10 +227,10 @@ def _fused_compress_reduce(x, i, name: str, compress: str, qaxis: int,
         if not reduced:
             return kernel_ops.reduce_compress_roundtrip(xs, axis=i, qaxis=qaxis)
         part = jax.lax.psum(jnp.sum(xs.astype(jnp.float32), axis=i), reduced)
-        part = (part * (1.0 / groups)).astype(xs.dtype)
-        return kernel_ops.reduce_compress_roundtrip(
-            jnp.expand_dims(part, i), axis=i, qaxis=qaxis
+        back = kernel_ops.reduce_compress_roundtrip(
+            jnp.expand_dims(part * (1.0 / groups), i), axis=i, qaxis=qaxis
         )
+        return back.astype(xs.dtype)
 
     rest = [None] * (x.ndim - i - 1)
     return compat.shard_map(

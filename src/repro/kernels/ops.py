@@ -132,9 +132,10 @@ def _reduce_compress_roundtrip_jnp(x, axis, qaxis):
         w = jnp.repeat(jnp.eye(l, dtype=jnp.float32), g, axis=1) * (1.0 / g)
         part = (w @ x.reshape(l * g, d)).reshape(lead + trail)
     else:
+        # Quantize the f32 mean, as the kernel does, and cast only the
+        # roundtrip result.
         part = jnp.sum(x.astype(jnp.float32), axis=axis) * (1.0 / g)
-        part = part.astype(x.dtype)
-    return _roundtrip_rows(part, qaxis)
+    return _roundtrip_rows(part, qaxis).astype(x.dtype)
 
 
 def _reduce_compress_roundtrip_pallas(x, axis, qaxis, row_block, interpret):

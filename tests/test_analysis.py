@@ -568,7 +568,7 @@ class TestLints:
             import jax
             NEW = jax.__version__ >= "0.5"
         """)
-        _write(root, "src/repro/compat/probes.py", """\
+        _write(root, "src/repro/compat/meshes.py", """\
             import jax
             NEW = jax.__version__ >= "0.5"
         """)

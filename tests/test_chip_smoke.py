@@ -59,7 +59,8 @@ def test_one_chip_phases_tiny(tmp_path, interpreted_kernels):
     train, plan, hier = reports
     assert len(train["losses"]) == chip_smoke.ROUNDS
     assert train["checkpoint_restored_bitwise"]
-    assert plan["max_param_rel_diff"] <= chip_smoke.PARAM_RTOL
+    assert plan["update_rel_diff"] <= chip_smoke.UPDATE_RTOL
+    assert plan["control_update_rel_diff"] > chip_smoke.UPDATE_RTOL
     assert "BROADCAST" in plan["stages"] and "REDUCE" in plan["stages"]
     assert hier["kernel_vs_oracle_int8_steps"] <= chip_smoke.INT8_STEPS
     assert len(hier["losses"]) == chip_smoke.ROUNDS
@@ -99,10 +100,11 @@ def test_four_chip_phases_on_cpu_devices(device_pool):
         finally:
             ops._on_tpu, pl.pallas_call = real_on_tpu, real_call
         print(json.dumps([
-            {{"phase": r["phase"], "diff": r["max_param_rel_diff"],
+            {{"phase": r["phase"], "diff": r["update_rel_diff"],
+              "control": r["control_update_rel_diff"],
               "devices": len(r["memory_per_device"])}} for r in reps]))
     """)
     assert [r["phase"] for r in out] == ["sharded-round", "hier-int8-sharded"]
     for r in out:
-        assert r["diff"] <= chip_smoke.PARAM_RTOL
+        assert r["diff"] <= chip_smoke.UPDATE_RTOL < r["control"]
         assert r["devices"] == 4

@@ -1,11 +1,12 @@
 """Compat-layer tests.
 
 Two halves: (a) the layer works against the installed JAX, which has every
-surface the probes name, and (b) monkeypatched ``jax`` surfaces pin what
+surface compat calls, and (b) monkeypatched ``jax`` surfaces pin what
 compat forwards to them, so a JAX upgrade that moves one fails here.
 """
 
 import contextlib
+import inspect
 
 import jax
 import jax.numpy as jnp
@@ -13,34 +14,44 @@ import numpy as np
 import pytest
 
 from repro import compat
-from repro.compat import probes as probes_lib
+from repro.compat import jaxpr as compat_jaxpr
 
 
-@pytest.fixture(autouse=True)
-def _fresh_probe_cache():
-    """Probe verdicts are cached; tests that monkeypatch jax must re-probe."""
-    compat.reset_cache()
-    yield
-    compat.reset_cache()
+def _params(fn):
+    return tuple(inspect.signature(fn).parameters)
+
+
+# The JAX surfaces compat calls with no fallback, each checked where it is
+# declared so a release that moved one names it here.
+_SURFACES = {
+    "make_mesh_axis_types": lambda: (
+        "axis_types" in _params(jax.make_mesh)
+        and all(hasattr(jax.sharding.AxisType, m)
+                for m in ("Auto", "Explicit", "Manual"))
+    ),
+    "set_mesh": lambda: callable(jax.set_mesh),
+    "shard_map_check_vma": lambda: "check_vma" in _params(jax.shard_map),
+    "var_takes_aval": lambda: (
+        compat_jaxpr.fresh_var(jax.core.ShapedArray((2,), jnp.float32)).aval
+        == jax.core.ShapedArray((2,), jnp.float32)
+    ),
+}
 
 
 class TestOnInstalledJax:
-    def test_capabilities_are_booleans(self):
-        caps = compat.capabilities()
-        assert caps, "no probes registered"
-        assert all(isinstance(v, bool) for v in caps.values())
-        # compat has no fallback: the installed JAX must have every surface
-        missing = sorted(k for k, v in caps.items() if not v)
-        assert not missing, f"installed JAX lacks compat surfaces {missing}"
+    @pytest.mark.parametrize("surface", sorted(_SURFACES))
+    def test_installed_jax_has_surface(self, surface):
+        assert _SURFACES[surface](), f"installed JAX lacks {surface}"
 
-    def test_unknown_feature_raises(self):
-        with pytest.raises(KeyError, match="unknown compat feature"):
-            compat.has("warp_drive")
-
-    def test_jax_version_tuple(self):
-        v = compat.jax_version()
-        assert isinstance(v, tuple) and len(v) >= 2
-        assert all(isinstance(p, int) for p in v)
+    def test_jaxpr_internals(self):
+        closed = jax.make_jaxpr(lambda x: (x * 2.0, x + 1.0))(jnp.ones((3,)))
+        aval = compat_jaxpr.get_aval(jnp.ones((3,)))
+        assert aval.shape == (3,) and aval.dtype == jnp.float32
+        out = compat_jaxpr.eval_jaxpr(closed.jaxpr, closed.consts,
+                                      jnp.arange(3.0))
+        np.testing.assert_allclose(out[0], [0.0, 2.0, 4.0])
+        assert not any(compat_jaxpr.is_dropvar(v)
+                       for v in closed.jaxpr.outvars)
 
     def test_make_mesh_single_device(self):
         mesh = compat.make_mesh((1,), ("data",))
@@ -165,8 +176,6 @@ class TestProbeMatrix:
         monkeypatch.setattr(
             jax.sharding, "AxisType", _FakeAxisType, raising=False
         )
-        compat.reset_cache()
-        assert compat.has("mesh_axis_types")
         mesh = compat.make_mesh((2, 4), ("data", "model"))
         assert mesh == "NEW-MESH"
         assert recorded["axis_types"] == ("AUTO", "AUTO")
@@ -181,22 +190,6 @@ class TestProbeMatrix:
             yield
 
         monkeypatch.setattr(jax, "set_mesh", fake_set_mesh, raising=False)
-        compat.reset_cache()
-        assert compat.has("set_mesh")
         with compat.set_mesh("the-mesh"):
             pass
         assert seen == ["the-mesh"]
-
-    def test_probe_cache_invalidation(self, monkeypatch):
-        before = compat.has("set_mesh")
-        monkeypatch.setattr(
-            jax, "set_mesh", lambda m: contextlib.nullcontext(), raising=False
-        )
-        # cached verdict survives until reset
-        assert compat.has("set_mesh") == before
-        compat.reset_cache()
-        assert compat.has("set_mesh")
-
-    def test_every_probe_has_a_docstring(self):
-        for name, fn in probes_lib._PROBES.items():
-            assert fn.__doc__, f"probe {name!r} undocumented"

@@ -103,6 +103,18 @@ class TestOpsDispatch:
             np.asarray(fast), np.asarray(kern), atol=1e-5
         )
 
+    def test_bf16_jnp_path_quantizes_the_f32_mean_like_the_kernel(self):
+        x = jax.random.normal(jax.random.PRNGKey(8), (2, 4, 10, 256))
+        x = x.astype(jnp.bfloat16)
+        fast = ops.reduce_compress_roundtrip(x, axis=1, backend="jnp")
+        kern = ops.reduce_compress_roundtrip(
+            x, axis=1, backend="pallas", interpret=True
+        )
+        assert fast.dtype == kern.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(
+            np.asarray(fast, np.float32), np.asarray(kern, np.float32)
+        )
+
     def test_gemm_and_plain_mean_agree(self):
         # bf16 input takes the plain-mean branch; compare against the f32
         # gemm branch on the same values.
