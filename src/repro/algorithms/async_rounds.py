@@ -21,14 +21,20 @@ the outer optimizers used here (tested on the CPU-scale model).
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
 
 from repro import core as drjax
-from repro.algorithms.rounds import LocalSGDConfig, _hier_axes, _tree_sub
-from repro.optim.optimizers import Optimizer, apply_updates, clip_by_global_norm
+from repro.algorithms.rounds import (
+    LocalSGDConfig,
+    _hier_axes,
+    _make_client_update,
+    _server_update,
+)
+from repro.optim.optimizers import Optimizer
 
 
 def make_async_local_sgd_round(
@@ -39,22 +45,10 @@ def make_async_local_sgd_round(
     *,
     donate: bool = False,
 ):
-    def client_update(params0, client_data):
-        opt_state = client_opt.init(params0)
-
-        def one_step(carry, batch):
-            params, opt_state = carry
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-            if cfg.grad_clip:
-                grads, _ = clip_by_global_norm(grads, cfg.grad_clip)
-            updates, opt_state = client_opt.update(grads, opt_state, params)
-            params = apply_updates(params, updates)
-            return (params, opt_state), loss
-
-        (params_new, _), losses = jax.lax.scan(
-            one_step, (params0, opt_state), client_data
-        )
-        return _tree_sub(params_new, params0), jnp.mean(losses)
+    # The flat async round sends its deltas uncompressed.
+    client_update = _make_client_update(
+        loss_fn, client_opt, dataclasses.replace(cfg, compression=None)
+    )
 
     @drjax.program(
         partition_size=cfg.partition_size,
@@ -64,10 +58,9 @@ def make_async_local_sgd_round(
     )
     def async_round(params, pending_delta, server_state, round_data):
         # 1) apply the delta that finished aggregating during the last round
-        updates, server_state = server_opt.update(
-            pending_delta, server_state, params
+        params, server_state = _server_update(
+            server_opt, pending_delta, server_state, params
         )
-        params = apply_updates(params, updates)
         # 2) launch this round's local training on the just-updated params
         params_b = drjax.broadcast(params)
         deltas, losses = drjax.map_fn(client_update, (params_b, round_data))
@@ -110,8 +103,6 @@ def make_hierarchical_async_round(
         raise ValueError(
             "make_hierarchical_async_round needs cfg.num_pods >= 1"
         )
-    from repro.algorithms.rounds import _make_client_update
-
     client_update = _make_client_update(loss_fn, client_opt, cfg)
 
     @drjax.program(
@@ -121,10 +112,9 @@ def make_hierarchical_async_round(
         use_sharding_annotations=cfg.use_sharding_annotations,
     )
     def async_round(params, pending_delta, server_state, round_data):
-        updates, server_state = server_opt.update(
-            pending_delta, server_state, params
+        params, server_state = _server_update(
+            server_opt, pending_delta, server_state, params
         )
-        params = apply_updates(params, updates)
         params_b = drjax.broadcast(params)
         deltas, losses = drjax.map_fn(client_update, (params_b, round_data))
         new_pending = drjax.hierarchical_reduce_mean(deltas)

@@ -82,31 +82,49 @@ def _hier_axes(cfg: LocalSGDConfig):
 
 def _make_client_update(loss_fn: Callable, client_opt: Optimizer,
                         cfg: LocalSGDConfig):
-    """num_local_steps optimizer steps on one group's batches -> (delta, loss)."""
+    """num_local_steps optimizer steps on one group's batches -> (delta, loss).
+
+    Each leg binds under a ``jax.named_scope`` that profiles read:
+    ``client_step`` (forward and backward), ``clip``, ``client_opt`` (the
+    optimizer update and its application) and ``client_delta`` (the change
+    and its compression)."""
 
     def client_update(params0, client_data):
         opt_state = client_opt.init(params0)
 
         def one_step(carry, batch):
             params, opt_state = carry
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+            with jax.named_scope("client_step"):
+                loss, grads = jax.value_and_grad(loss_fn)(params, batch)
             if cfg.grad_clip:
-                grads, _ = clip_by_global_norm(grads, cfg.grad_clip)
-            updates, opt_state = client_opt.update(grads, opt_state, params)
-            params = apply_updates(params, updates)
+                with jax.named_scope("clip"):
+                    grads, _ = clip_by_global_norm(grads, cfg.grad_clip)
+            with jax.named_scope("client_opt"):
+                updates, opt_state = client_opt.update(grads, opt_state,
+                                                       params)
+                params = apply_updates(params, updates)
             return (params, opt_state), loss
 
         (params_new, _), losses = jax.lax.scan(
             one_step, (params0, opt_state), client_data
         )
-        delta = _tree_sub(params_new, params0)
-        if cfg.compression == "int8":
-            delta = compression.int8_roundtrip(delta)
-        elif cfg.compression == "topk":
-            delta = compression.topk_sparsify(delta, cfg.topk_fraction)
+        with jax.named_scope("client_delta"):
+            delta = _tree_sub(params_new, params0)
+            if cfg.compression == "int8":
+                delta = compression.int8_roundtrip(delta)
+            elif cfg.compression == "topk":
+                delta = compression.topk_sparsify(delta, cfg.topk_fraction)
         return delta, jnp.mean(losses)
 
     return client_update
+
+
+def _server_update(server_opt: Optimizer, delta, server_state, params):
+    """The server step on the aggregated ``delta``, under the scope
+    ``server_update``: (new params, new server state)."""
+    with jax.named_scope("server_update"):
+        updates, server_state = server_opt.update(delta, server_state, params)
+        return apply_updates(params, updates), server_state
 
 
 def _maybe_donate(round_fn: Callable, donate: bool) -> Callable:
@@ -151,10 +169,9 @@ def make_local_sgd_round(
         else:
             mean_delta = drjax.reduce_mean(deltas)
             mean_loss = drjax.reduce_mean(losses)
-        updates, new_server_state = server_opt.update(
-            mean_delta, server_state, global_params
+        new_params, new_server_state = _server_update(
+            server_opt, mean_delta, server_state, global_params
         )
-        new_params = apply_updates(global_params, updates)
         metrics = {"loss": mean_loss}
         return new_params, new_server_state, metrics
 
@@ -223,10 +240,9 @@ def make_hierarchical_local_sgd_round(
                 deltas, compress_fn=pod_compress, use_fused=cfg.fused_reduce
             )
             mean_loss = drjax.hierarchical_reduce_mean(losses)
-        updates, new_server_state = server_opt.update(
-            mean_delta, server_state, global_params
+        new_params, new_server_state = _server_update(
+            server_opt, mean_delta, server_state, global_params
         )
-        new_params = apply_updates(global_params, updates)
         metrics = {"loss": mean_loss}
         return new_params, new_server_state, metrics
 
@@ -291,7 +307,8 @@ def make_fedsgd_round(
     """
 
     def client_grad(params, batch):
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+        with jax.named_scope("client_step"):
+            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
         return grads, loss
 
     @drjax.program(
@@ -311,10 +328,9 @@ def make_fedsgd_round(
             mean_grad = drjax.reduce_mean(grads)
             mean_loss = drjax.reduce_mean(losses)
         neg = jax.tree_util.tree_map(lambda g: -g, mean_grad)
-        updates, new_server_state = server_opt.update(
-            neg, server_state, global_params
+        new_params, new_server_state = _server_update(
+            server_opt, neg, server_state, global_params
         )
-        new_params = apply_updates(global_params, updates)
         return new_params, new_server_state, {"loss": mean_loss}
 
     return round_fn

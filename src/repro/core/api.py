@@ -30,6 +30,12 @@ degenerate case.
 
 All ops are pytree-polymorphic: partitioned *structures* are pytrees whose
 every leaf carries the leading group axes (paper Fig. 2).
+
+Every building block binds under the ``jax.named_scope``
+``drjax.<op>[<placement>]`` (one per level a stack-spanning op binds; see
+:func:`scope`), so the compiled program's ``op_name`` metadata, and a
+profile read against it, name the primitive and level each instruction came
+from.
 """
 
 from __future__ import annotations
@@ -135,6 +141,13 @@ def program(
 # ---------------------------------------------------------------------------
 
 
+def scope(op: str, levels: str):
+    """The ``jax.named_scope`` a building block binds under:
+    ``drjax.<op>[<levels>]``. Metadata only: it adds no equation. (XLA cuts
+    an ``op_name`` at ``@``, so the placement goes in brackets.)"""
+    return jax.named_scope(f"drjax.{op}[{levels}]")
+
+
 def _ctx() -> placement_lib.PlacementContext:
     return placement_lib.current_context()
 
@@ -168,13 +181,14 @@ def broadcast(tree, placement: Optional[str] = None):
 
     def leaf(x):
         for name in chain:
-            x = prims.bind_broadcast(x, placement=name)
+            with scope("broadcast", name):
+                x = prims.bind_broadcast(x, placement=name)
         return x
 
     return jax.tree_util.tree_map(leaf, tree)
 
 
-def _reduce_tree(tree, binder, placement: Optional[str]):
+def _reduce_tree(tree, op: str, binder, placement: Optional[str]):
     ctx = _ctx()
     if placement is None:
         _require_replica_stack(ctx, "reduce")
@@ -184,7 +198,8 @@ def _reduce_tree(tree, binder, placement: Optional[str]):
 
     def leaf(x):
         for name in chain:
-            x = binder(x, placement=name)
+            with scope(op, name):
+                x = binder(x, placement=name)
         return x
 
     return jax.tree_util.tree_map(leaf, tree)
@@ -197,7 +212,7 @@ def reduce_sum(tree, placement: Optional[str] = None):
     default reduces the whole stack down to the server, innermost level
     first — on a nested stack this is automatically the hierarchical
     (two-stage) reduction."""
-    return _reduce_tree(tree, prims.bind_reduce_sum, placement)
+    return _reduce_tree(tree, "reduce_sum", prims.bind_reduce_sum, placement)
 
 
 def reduce_mean(tree, placement: Optional[str] = None):
@@ -205,12 +220,13 @@ def reduce_mean(tree, placement: Optional[str] = None):
 
     The stack-spanning default composes per-level means (equal group sizes
     make the mean-of-means the global mean)."""
-    return _reduce_tree(tree, prims.bind_reduce_mean, placement)
+    return _reduce_tree(tree, "reduce_mean", prims.bind_reduce_mean,
+                        placement)
 
 
 def reduce_max(tree, placement: Optional[str] = None):
     """Max over groups (extension primitive; sub-gradient AD)."""
-    return _reduce_tree(tree, prims.bind_reduce_max, placement)
+    return _reduce_tree(tree, "reduce_max", prims.bind_reduce_max, placement)
 
 
 def reduce_weighted_mean(tree, weights, placement: Optional[str] = None):
@@ -228,10 +244,16 @@ def reduce_weighted_mean(tree, weights, placement: Optional[str] = None):
     dropped round leaves the server params untouched instead of poisoning
     them.
     """
+    return _weighted_mean(tree, weights, placement, "reduce_weighted_mean")
+
+
+def _weighted_mean(tree, weights, placement: Optional[str], op: str):
+    """:func:`reduce_weighted_mean`, bound under the scope
+    ``drjax.<op>[<levels>]`` (the levels it reduces, outermost first)."""
     ctx = _ctx()
     weights = jnp.asarray(weights)
     if placement is None:
-        _require_replica_stack(ctx, "reduce_weighted_mean")
+        _require_replica_stack(ctx, op)
         chain = tuple(reversed(ctx.names))
         depth_in, depth_out = ctx.depth, 0
     else:
@@ -250,10 +272,6 @@ def reduce_weighted_mean(tree, weights, placement: Optional[str] = None):
         for name in chain:
             x = prims.bind_reduce_sum(x, placement=name)
         return x
-
-    denom = rsum(weights)
-    all_dropped = denom == 0
-    safe_denom = jnp.where(all_dropped, jnp.ones_like(denom), denom)
 
     def leaf(x):
         if x.ndim < depth_in or x.shape[:depth_in] != expected:
@@ -274,7 +292,11 @@ def reduce_weighted_mean(tree, weights, placement: Optional[str] = None):
         )
         return jnp.where(dropped, jnp.zeros_like(s), s / denom_b)
 
-    return jax.tree_util.tree_map(leaf, tree)
+    with scope(op, "+".join(reversed(chain))):
+        denom = rsum(weights)
+        all_dropped = denom == 0
+        safe_denom = jnp.where(all_dropped, jnp.ones_like(denom), denom)
+        return jax.tree_util.tree_map(leaf, tree)
 
 
 def masked_reduce_mean(tree, mask, placement: Optional[str] = None):
@@ -286,7 +308,7 @@ def masked_reduce_mean(tree, mask, placement: Optional[str] = None):
     differentiable and stays within the DrJAX primitive set. An all-zero mask
     (every straggler dropped) yields zeros, not NaN.
     """
-    return reduce_weighted_mean(tree, mask, placement)
+    return _weighted_mean(tree, mask, placement, "masked_reduce_mean")
 
 
 def _fused_spmd_names(ctx: placement_lib.PlacementContext):
@@ -329,8 +351,18 @@ def map_fn(fn: Callable, tree, placement: Optional[str] = None,
     levels' mesh-axis annotations cannot be merged into one. The mapped
     computation itself is inlined into the jaxpr, exactly as in paper
     Snippet 5.
+
+    The map binds under the scope ``drjax.map[<placement>]``, or
+    ``drjax.map[<outermost>+...+<innermost>]`` when it spans the stack.
     """
     ctx = placement_lib.current_context()
+    levels = "+".join(ctx.names) if placement is None else placement
+    with scope("map", levels):
+        return _map_fn(ctx, fn, tree, placement, fuse)
+
+
+def _map_fn(ctx, fn: Callable, tree, placement: Optional[str],
+            fuse: Optional[bool]):
     if isinstance(tree, tuple):
         f = lambda args: fn(*args)
     else:
@@ -429,12 +461,13 @@ def stage_transfer(tree, placement: Optional[str] = None, *,
     """
     ctx = _ctx()
     name = _stage_placement_name(ctx, placement)
-    return jax.tree_util.tree_map(
-        lambda x: prims.bind_stage_transfer(
-            x, placement=name, shift=shift, wrap=wrap
-        ),
-        tree,
-    )
+    with scope("stage_transfer", name):
+        return jax.tree_util.tree_map(
+            lambda x: prims.bind_stage_transfer(
+                x, placement=name, shift=shift, wrap=wrap
+            ),
+            tree,
+        )
 
 
 def stage_map(fns, tree, placement: Optional[str] = None):
@@ -476,11 +509,13 @@ def stage_map(fns, tree, placement: Optional[str] = None):
         )
         return f(sliced)
 
-    outs = [run_stage(s) for s in range(size)]
-    out = jax.tree_util.tree_map(
-        lambda *xs: jnp.stack(xs, axis=i), *outs
-    )
-    return sharding_lib.constrain_tree(out, ctx, partitioned=True, depth=i + 1)
+    with scope("stage_map", name):
+        outs = [run_stage(s) for s in range(size)]
+        out = jax.tree_util.tree_map(
+            lambda *xs: jnp.stack(xs, axis=i), *outs
+        )
+        return sharding_lib.constrain_tree(out, ctx, partitioned=True,
+                                           depth=i + 1)
 
 
 def partition_size(placement: Optional[str] = None) -> int:

@@ -112,22 +112,32 @@ def _staged_reduce(tree, ctx, compress_fn, use_fused: Optional[bool]):
     unpack. The program still stages as placement-tagged REDUCEs, so
     ``build_plan``/``to_beam`` see the same communication structure as the
     generic composition.
+
+    Scopes: the fused path's pack, inner reduce and unpack bind under
+    ``drjax.reduce_compress[<inner>]``, the generic path's ``compress_fn``
+    under ``drjax.compress[<inner>]``; the outer levels are
+    ``drjax.reduce_mean[<level>]``.
     """
     inner = ctx.names[-1]
     if _fusable(tree, ctx, compress_fn, use_fused):
-        bufs, spec = compression.flat_pack(
-            tree, lead_ndim=ctx.depth, cols=compression.PACK_COLS
-        )
+        with api.scope("reduce_compress", inner):
+            bufs, spec = compression.flat_pack(
+                tree, lead_ndim=ctx.depth, cols=compression.PACK_COLS
+            )
         outs = {}
         for key, buf in bufs.items():
-            v = prims.bind_reduce_mean(buf, placement=inner, compress="int8")
+            with api.scope("reduce_compress", inner):
+                v = prims.bind_reduce_mean(buf, placement=inner,
+                                           compress="int8")
             for name in reversed(ctx.names[:-1]):
-                v = prims.bind_reduce_mean(v, placement=name)
+                v = api.reduce_mean(v, placement=name)
             outs[key] = v
-        return compression.flat_unpack(outs, spec, lead_ndim=0)
+        with api.scope("reduce_compress", inner):
+            return compression.flat_unpack(outs, spec, lead_ndim=0)
     partials = api.reduce_mean(tree, placement=inner)
     if compress_fn is not None:
-        partials = compress_fn(partials)
+        with api.scope("compress", inner):
+            partials = compress_fn(partials)
     out = partials
     for name in reversed(ctx.names[:-1]):
         out = api.reduce_mean(out, placement=name)

@@ -50,6 +50,7 @@ def quantize(x, *, row_block: int = 256, interpret: bool = False):
             jax.ShapeDtypeStruct((rp, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="quantize",
     )(x)
     return q[:r], s[:r]
 
@@ -75,5 +76,6 @@ def dequantize(q, scales, dtype=jnp.float32, *, row_block: int = 256,
         out_specs=pl.BlockSpec((row_block, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rp, c), dtype),
         interpret=interpret,
+        name="dequantize",
     )(q, scales)
     return x[:r]

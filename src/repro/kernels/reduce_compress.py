@@ -123,6 +123,7 @@ def reduce_compress(x, *, row_block: Optional[int] = None,
             jax.ShapeDtypeStruct((r, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="reduce_compress",
     )(x)
     return q, s
 
@@ -154,6 +155,7 @@ def reduce_compress_roundtrip(x, *, row_block: Optional[int] = None,
             jax.ShapeDtypeStruct((r, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="reduce_compress_roundtrip",
     )(x)
     return back, q, s
 
@@ -173,5 +175,6 @@ def dequant_accumulate(q, scales, *, row_block: Optional[int] = None,
         out_specs=pl.BlockSpec((row_block, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, c), jnp.float32),
         interpret=interpret,
+        name="dequant_accumulate",
     )(q, scales)
     return out

@@ -12,6 +12,18 @@ CPU-scale example (reduced config, a few hundred rounds):
 
 On a real cluster, run unmodified under `jax.distributed` with
 ``--mesh single|multi`` (the production meshes from launch/mesh.py).
+
+Profiling a real run: ``--profile-dir DIR`` records rounds with the JAX
+profiler into ``DIR`` (an ``.xplane.pb`` under ``DIR/plugins/profile/``),
+``--profile-rounds START:END`` the rounds START..END-1 only (default: all).
+Each round is a step (``StepTraceAnnotation("round")``) whose host spans are
+``sample``, ``dispatch``, ``wait`` and ``readback``; ``checkpoint_save`` and
+``restore`` mark the recovery loop's checkpoint traffic. The device ops of
+each round carry the program's scopes (``client_step``, ``drjax.<op>[...]``,
+``server_update``, ...) in their ``op_name``.
+
+    PYTHONPATH=src python -m repro.launch.train --arch lm_350m --reduced \
+        --rounds 10 --profile-dir /tmp/prof --profile-rounds 2:5
 """
 
 from __future__ import annotations
@@ -62,6 +74,36 @@ def build_round_fn(cfg, args):
     return jax.jit(round_fn, donate_argnums=(0, 1)), server_opt
 
 
+def _round_range(text: str) -> range:
+    """``START:END`` -> the rounds START..END-1."""
+    start, sep, end = text.partition(":")
+    if not sep:
+        raise argparse.ArgumentTypeError(f"want START:END, got {text!r}")
+    return range(int(start), int(end))
+
+
+class RoundProfiler:
+    """Records ``rounds`` with the JAX profiler into ``log_dir``. The trace
+    starts as the first of them begins and stops as a later round begins or
+    training ends, so it holds their checkpoint saves too."""
+
+    def __init__(self, log_dir: str, rounds: range):
+        self.log_dir, self.rounds = log_dir, rounds
+        self.state = "waiting"
+
+    def round_begins(self, round_idx: int) -> None:
+        if self.state == "waiting" and round_idx == self.rounds.start:
+            jax.profiler.start_trace(self.log_dir)
+            self.state = "tracing"
+        elif round_idx >= self.rounds.stop:
+            self.stop()
+
+    def stop(self) -> None:
+        if self.state == "tracing":
+            jax.profiler.stop_trace()
+            self.state = "done"
+
+
 def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="lm_350m", choices=registry.ARCH_IDS)
@@ -91,6 +133,13 @@ def parse_args(argv=None) -> argparse.Namespace:
                          "faults, serve traffic) with the production "
                          "invariants asserted (see repro.runtime.chaos)")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--profile-dir", default=None,
+                    help="record rounds with the JAX profiler into this "
+                         "directory")
+    ap.add_argument("--profile-rounds", type=_round_range, default=None,
+                    metavar="START:END",
+                    help="with --profile-dir, record rounds START..END-1 "
+                         "only (default: every round)")
     return ap.parse_args(argv)
 
 
@@ -124,28 +173,41 @@ def train(args, *, on_round=None) -> dict:
                 cfg.name, n_params / 1e6, args.cohort, args.local_steps)
 
     history, round_s = [], []
+    profiler = (RoundProfiler(args.profile_dir,
+                              args.profile_rounds or range(args.rounds))
+                if args.profile_dir else None)
 
     def round_step(round_idx, state):
+        if profiler is not None:
+            profiler.round_begins(round_idx)
+        with jax.profiler.StepTraceAnnotation("round", step_num=round_idx):
+            return _round(round_idx, state)
+
+    def _round(round_idx, state):
         injector.check(round_idx)
         params, server_state = state["params"], state["server"]
-        data = sampler.round_batch(
-            round_idx, args.local_steps, args.batch, args.seq
-        )
-        batch = {"tokens": data["tokens"], "labels": data["labels"]}
-        t0 = time.perf_counter()
-        if strag is not None:
-            durations = strag.durations(round_idx, args.cohort)
-            deadline = float(
-                np.percentile(durations, args.straggler_deadline_pct)
+        with jax.profiler.TraceAnnotation("sample"):
+            data = sampler.round_batch(
+                round_idx, args.local_steps, args.batch, args.seq
             )
-            mask = straggler_mask(durations, deadline,
-                                  min_finishers=max(args.cohort // 2, 1))
-            out = round_fn(params, server_state, batch, mask)
-        else:
-            out = round_fn(params, server_state, batch)
-        params, server_state, metrics = jax.block_until_ready(out)
+            batch = {"tokens": data["tokens"], "labels": data["labels"]}
+        t0 = time.perf_counter()
+        with jax.profiler.TraceAnnotation("dispatch"):
+            if strag is not None:
+                durations = strag.durations(round_idx, args.cohort)
+                deadline = float(
+                    np.percentile(durations, args.straggler_deadline_pct)
+                )
+                mask = straggler_mask(durations, deadline,
+                                      min_finishers=max(args.cohort // 2, 1))
+                out = round_fn(params, server_state, batch, mask)
+            else:
+                out = round_fn(params, server_state, batch)
+        with jax.profiler.TraceAnnotation("wait"):
+            params, server_state, metrics = jax.block_until_ready(out)
         round_s.append(time.perf_counter() - t0)
-        loss = float(metrics["loss"])
+        with jax.profiler.TraceAnnotation("readback"):
+            loss = float(metrics["loss"])
         history.append(loss)
         if round_idx % args.log_every == 0:
             logger.info("round %d loss %.4f (%.2fs)", round_idx, loss,
@@ -156,10 +218,14 @@ def train(args, *, on_round=None) -> dict:
         return state
 
     init_state = {"params": params, "server": server_state}
-    final, stats = run_with_recovery(
-        round_step, init_state, args.rounds, mgr,
-        checkpoint_every=args.ckpt_every,
-    )
+    try:
+        final, stats = run_with_recovery(
+            round_step, init_state, args.rounds, mgr,
+            checkpoint_every=args.ckpt_every,
+        )
+    finally:
+        if profiler is not None:
+            profiler.stop()
     return {
         "cfg": cfg, "n_params": n_params, "history": history,
         "round_s": round_s, "stats": stats, "final": final, "ckpt": mgr,

@@ -99,8 +99,7 @@ def make_elastic_hierarchical_round(
     recompiles when the finisher set changes.
     """
     from repro import core as drjax
-    from repro.algorithms.rounds import _make_client_update
-    from repro.optim.optimizers import apply_updates
+    from repro.algorithms.rounds import _make_client_update, _server_update
     from repro.runtime.executor import ElasticHierarchicalRound
 
     client_update = _make_client_update(loss_fn, client_opt, cfg)
@@ -145,10 +144,9 @@ def make_elastic_hierarchical_round(
 
             mean_delta = jax.tree_util.tree_map(wmean, pod_deltas)
             mean_loss = wmean(pod_losses)
-            updates, new_server_state = server_opt.update(
-                mean_delta, server_state, global_params
+            new_params, new_server_state = _server_update(
+                server_opt, mean_delta, server_state, global_params
             )
-            new_params = apply_updates(global_params, updates)
             return new_params, new_server_state, {
                 "loss": mean_loss,
                 "finishers": total,
@@ -170,10 +168,9 @@ def make_elastic_hierarchical_round(
             mean_delta = jax.tree_util.tree_map(
                 lambda d: jnp.mean(d, axis=0), pod_deltas
             )
-            updates, new_server_state = server_opt.update(
-                mean_delta, server_state, global_params
+            new_params, new_server_state = _server_update(
+                server_opt, mean_delta, server_state, global_params
             )
-            new_params = apply_updates(global_params, updates)
             return new_params, new_server_state, {
                 "loss": jnp.mean(pod_losses, 0)
             }

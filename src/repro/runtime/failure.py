@@ -7,6 +7,9 @@ replay is exact). ``run_with_recovery`` is the driver used by
 ``launch/train.py`` and the chaos soak harness (``runtime/chaos.py``);
 ``FailureInjector`` simulates device loss in tests and examples.
 
+The host spans ``checkpoint_save`` and ``restore`` (``jax.profiler``
+annotations) mark checkpoint traffic in a profile of the loop.
+
 Recovery policy:
 
  * only exceptions in the ``recoverable`` allowlist trigger a
@@ -119,7 +122,8 @@ def run_with_recovery(
     }
     state = init_state
     step = 0
-    restored = checkpoint_mgr.restore_latest(state)
+    with jax.profiler.TraceAnnotation("restore"):
+        restored = checkpoint_mgr.restore_latest(state)
     if restored is not None:
         step, state, meta = restored
         if on_restore is not None:
@@ -141,7 +145,9 @@ def run_with_recovery(
             if step % checkpoint_every == 0 or step == num_steps:
                 meta = state_metadata(state) if state_metadata else {}
                 meta = dict(meta, step=step)
-                checkpoint_mgr.save(step, state, metadata=meta, blocking=False)
+                with jax.profiler.TraceAnnotation("checkpoint_save"):
+                    checkpoint_mgr.save(step, state, metadata=meta,
+                                        blocking=False)
         except recoverable as e:
             if is_fatal(e):
                 raise
@@ -156,7 +162,8 @@ def run_with_recovery(
                 stats["backoff_s"] += delay
                 time.sleep(delay)
             logger.warning("step %d failed (%s); restoring", step, e)
-            restored = checkpoint_mgr.restore_latest(state)
+            with jax.profiler.TraceAnnotation("restore"):
+                restored = checkpoint_mgr.restore_latest(state)
             if restored is None:
                 # no checkpoint yet: restart from the initial state. The
                 # step counter resets but completed_steps does not — the

@@ -9,11 +9,16 @@ more VMEM than a kernel may use (the fixed 256-row block did, from 32
 groups on). Interpret-mode tests (``test_kernels.py``,
 ``test_fused_reduce.py``) cannot see either.
 
+Each kernel's custom call carries the kernel's ``name`` in its ``op_name``
+(the scope ``<name>/pallas_call``), which is how a profile finds it: the
+custom call target ``tpu_custom_call`` is shared by every Mosaic kernel.
+
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +27,7 @@ import pytest
 from repro.kernels import ops
 
 ROWS, LANES = 4096, 256
+OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 
 @pytest.fixture(scope="module")
@@ -54,13 +60,23 @@ def _compile_hlo(fn, sharding, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _assert_named_kernel(hlo, name):
+    """The HLO holds the Mosaic kernel, and each of its custom calls is
+    named ``name`` (``vmap(name)`` under a vmap)."""
+    calls = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
+    assert calls
+    for line in calls:
+        (op_name,) = OP_NAME.findall(line)
+        assert op_name.split("/")[-2] in (name, f"vmap({name})"), op_name
+
+
 @pytest.mark.parametrize("groups", [8, 64])
 def test_reduce_compress(one_chip, groups):
     hlo = _compile_hlo(
         lambda x: ops.reduce_compress(x, interpret=False),
         one_chip, ((groups, ROWS, LANES), jnp.float32),
     )
-    assert "tpu_custom_call" in hlo
+    _assert_named_kernel(hlo, "reduce_compress")
 
 
 @pytest.mark.parametrize("groups", [8, 64])
@@ -71,7 +87,7 @@ def test_reduce_compress_roundtrip(one_chip, groups):
         ),
         one_chip, ((groups, ROWS, LANES), jnp.float32),
     )
-    assert "tpu_custom_call" in hlo
+    _assert_named_kernel(hlo, "reduce_compress_roundtrip")
 
 
 @pytest.mark.parametrize("pods", [8, 64])
@@ -82,7 +98,7 @@ def test_dequant_accumulate(one_chip, pods):
         ((pods, ROWS, LANES), jnp.int8),
         ((pods, ROWS, 1), jnp.float32),
     )
-    assert "tpu_custom_call" in hlo
+    _assert_named_kernel(hlo, "dequant_accumulate")
 
 
 def test_quantize(one_chip):
@@ -90,7 +106,7 @@ def test_quantize(one_chip):
         lambda x: ops.quantize(x, interpret=False),
         one_chip, ((4096, 1024), jnp.float32),
     )
-    assert "tpu_custom_call" in hlo
+    _assert_named_kernel(hlo, "quantize")
 
 
 def test_dequantize(one_chip):
@@ -98,4 +114,4 @@ def test_dequantize(one_chip):
         lambda q, s: ops.dequantize(q, s, interpret=False),
         one_chip, ((4096, 1024), jnp.int8), ((4096, 1), jnp.float32),
     )
-    assert "tpu_custom_call" in hlo
+    _assert_named_kernel(hlo, "dequantize")
