@@ -1,14 +1,27 @@
 """MapReduce training rounds: local SGD / FedAvg / DiLoCo / FedSGD.
 
-This is the paper's §4 workload, built verbatim from the building blocks:
+This is the paper's §4 workload, built from the building blocks:
 
     params_b = drjax.broadcast(global_params)           # server -> groups
-    deltas   = drjax.map_fn(client_update, (params_b, round_data))
-    delta    = drjax.reduce_mean(deltas)                # groups -> server
-    params   = server_opt(global_params, delta)
+    updated  = drjax.map_fn(client_update, (params_b, round_data))
+    mean     = drjax.reduce_mean(updated, dtype=f32)    # groups -> server
+    params   = server_opt(global_params, mean - global_params)
 
 ``client_update`` runs ``num_local_steps`` optimizer steps on the group's
 batches — model- and optimizer-agnostic (any ``loss_fn(params, batch)``).
+
+What the clients hand the reduction depends on the round. The flat,
+uncompressed, unmasked round hands it each client's new parameters in their
+storage dtype; the reduction accumulates them in f32, and the server forms
+the f32 mean delta ``mean - global`` once. That is the delta form
+``mean(p_k - global)`` up to f32 rounding, and on one device XLA fuses the
+mean and the server step into one pass over the parameters. A compressed
+round hands it per-client deltas, since the compressor (int8 with its
+scales, top-k) acts on each client's change; a straggler-masked round does
+too, since its weighted mean of an all-dropped cohort must be a zero
+change, not zero parameters. The pod-hierarchical round reduces deltas with
+``hierarchical_reduce_mean``, which compresses the pod partials.
+
 Distribution: the partition axis shards over (pod, data); everything inside
 ``map_fn`` additionally uses the model's logical-axis annotations, so model
 parallelism composes (paper: "shard computations over data partitions,
@@ -81,8 +94,9 @@ def _hier_axes(cfg: LocalSGDConfig):
 
 
 def _make_client_update(loss_fn: Callable, client_opt: Optimizer,
-                        cfg: LocalSGDConfig):
-    """num_local_steps optimizer steps on one group's batches -> (delta, loss).
+                        cfg: LocalSGDConfig, *, as_delta: bool = True):
+    """num_local_steps optimizer steps on one group's batches -> (delta,
+    loss), or (new params, loss) with ``as_delta=False``.
 
     Each leg binds under a ``jax.named_scope`` that profiles read:
     ``client_step`` (forward and backward), ``clip``, ``client_opt`` (the
@@ -108,6 +122,8 @@ def _make_client_update(loss_fn: Callable, client_opt: Optimizer,
         (params_new, _), losses = jax.lax.scan(
             one_step, (params0, opt_state), client_data
         )
+        if not as_delta:
+            return params_new, jnp.mean(losses)
         with jax.named_scope("client_delta"):
             delta = _tree_sub(params_new, params0)
             if cfg.compression == "int8":
@@ -151,8 +167,21 @@ def make_local_sgd_round(
     ``round_data`` leaves have shape (n, num_local_steps, ...per-step batch).
     Returns (new_params, new_server_state, metrics). ``donate=True`` returns
     the round jitted with params/server_state donated (the hot-loop form).
+
+    Without compression or a straggler mask, the clients hand
+    ``reduce_mean`` their new parameters, still in their storage dtype; it
+    accumulates them in f32, and the server step subtracts the global
+    parameters from that f32 mean. Where the clients' placement names no
+    mesh axis, XLA fuses the mean and the server step into one pass per
+    leaf that reads each client's parameters and the global once and
+    writes the new global once. With ``cfg.compression`` the clients hand
+    it their compressed deltas (the compressor acts on each client's
+    change), and with a mask their deltas (an all-dropped cohort's masked
+    mean is zero, which must mean no change).
     """
     client_update = _make_client_update(loss_fn, client_opt, cfg)
+    client_params = _make_client_update(loss_fn, client_opt, cfg,
+                                        as_delta=False)
 
     @drjax.program(
         partition_size=cfg.partition_size,
@@ -162,13 +191,23 @@ def make_local_sgd_round(
     )
     def round_fn(global_params, server_state, round_data, mask=None):
         params_b = drjax.broadcast(global_params)
-        deltas, losses = drjax.map_fn(client_update, (params_b, round_data))
-        if cfg.straggler_mask and mask is not None:
-            mean_delta = drjax.masked_reduce_mean(deltas, mask)
-            mean_loss = drjax.masked_reduce_mean(losses, mask)
+        masked = cfg.straggler_mask and mask is not None
+        if masked or cfg.compression is not None:
+            deltas, losses = drjax.map_fn(client_update,
+                                          (params_b, round_data))
+            if masked:
+                mean_delta = drjax.masked_reduce_mean(deltas, mask)
+                mean_loss = drjax.masked_reduce_mean(losses, mask)
+            else:
+                mean_delta = drjax.reduce_mean(deltas)
+                mean_loss = drjax.reduce_mean(losses)
         else:
-            mean_delta = drjax.reduce_mean(deltas)
+            updated, losses = drjax.map_fn(client_params,
+                                           (params_b, round_data))
+            mean_params = drjax.reduce_mean(updated, dtype=jnp.float32)
             mean_loss = drjax.reduce_mean(losses)
+            with jax.named_scope("server_update"):
+                mean_delta = _tree_sub(mean_params, global_params)
         new_params, new_server_state = _server_update(
             server_opt, mean_delta, server_state, global_params
         )

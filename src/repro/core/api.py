@@ -215,13 +215,21 @@ def reduce_sum(tree, placement: Optional[str] = None):
     return _reduce_tree(tree, "reduce_sum", prims.bind_reduce_sum, placement)
 
 
-def reduce_mean(tree, placement: Optional[str] = None):
+def reduce_mean(tree, placement: Optional[str] = None, *, dtype=None):
     """Average a partitioned structure over its groups (derived symbol).
 
     The stack-spanning default composes per-level means (equal group sizes
-    make the mean-of-means the global mean)."""
-    return _reduce_tree(tree, "reduce_mean", prims.bind_reduce_mean,
-                        placement)
+    make the mean-of-means the global mean).
+
+    ``dtype`` (default: each leaf's own) is the dtype the groups are
+    accumulated in and the result is given in, as for ``jnp.mean``: bf16
+    parameters reduced with ``dtype=jnp.float32`` give their f32 mean, with
+    no bf16 rounding of the sum. Where the placement names no mesh axis
+    (all its groups on one device), the sum is formed from per-group slices
+    upcast as they are read, which XLA fuses with the consumer of the mean
+    into one elementwise pass (``primitives._group_sum``)."""
+    binder = functools.partial(prims.bind_reduce_mean, dtype=dtype)
+    return _reduce_tree(tree, "reduce_mean", binder, placement)
 
 
 def reduce_max(tree, placement: Optional[str] = None):

@@ -240,44 +240,76 @@ def _fused_compress_reduce(x, i, name: str, compress: str, qaxis: int,
     )(x)
 
 
+# Up to this many groups on one device, an accumulating sum is a tree of
+# per-group slices; beyond it, the reduce's one extra write is a small share
+# of reading the groups, and the tree would bloat the program.
+_SLICE_SUM_MAX_GROUPS = 16
+
+
+def _group_sum(x, pl: placement_lib.Placement, i: int, dtype=None):
+    """Sum of ``x`` over its group axis ``i``, accumulated in ``dtype``
+    (default: ``x``'s own).
+
+    With an accumulation dtype and a placement that names no mesh axis,
+    every group lives on this device, and the sum is a pairwise tree of
+    the groups' slices, each upcast as it is read. XLA then sees
+    elementwise work that fuses with its consumers (the server step) into
+    one pass over the operand; a reduce would be a fusion root that writes
+    its upcast result out. A sharded placement keeps the reduce, which
+    GSPMD lowers to an all-reduce, as do more than
+    ``_SLICE_SUM_MAX_GROUPS`` groups."""
+    if dtype is None:
+        return jnp.sum(x, axis=i)
+    if pl.axes_tuple() or x.shape[i] > _SLICE_SUM_MAX_GROUPS:
+        return jnp.sum(x, axis=i, dtype=dtype)
+    parts = [jax.lax.index_in_dim(x, k, i, keepdims=False).astype(dtype)
+             for k in range(x.shape[i])]
+    while len(parts) > 1:
+        pairs = [a + b for a, b in zip(parts[0::2], parts[1::2])]
+        parts = pairs + parts[len(pairs) * 2:]
+    return parts[0]
+
+
 def _make_reduction(name: str, reduce_fn):
     p = Primitive(f"drjax_{name}")
 
     def impl(x, *, pctx: placement_lib.PlacementContext, placement=None,
-             compress=None, qaxis=-1):
+             compress=None, qaxis=-1, dtype=None):
         pl, i = _resolve(pctx, placement)
         _check_kind(pl, name, "replicas")  # eager binds skip abstract
         if compress is not None:
             out = _fused_compress_reduce(x, i, name, compress, qaxis, pctx)
         else:
-            out = reduce_fn(x, pl, i)
+            out = reduce_fn(x, pl, i, dtype)
         if i == 0:
             return sharding_lib.constrain_replicated(out, pctx)
         return sharding_lib.constrain_partitioned(out, pctx, depth=i)
 
-    def abstract(x, *, pctx, placement=None, compress=None, qaxis=-1):
+    def abstract(x, *, pctx, placement=None, compress=None, qaxis=-1,
+                 dtype=None):
         pl, i = _resolve(pctx, placement)
         _check_kind(pl, name, "replicas")
         _check_operand_depth(x, pctx, i + 1, name)
-        return core.ShapedArray(x.shape[:i] + x.shape[i + 1 :], x.dtype)
+        return core.ShapedArray(x.shape[:i] + x.shape[i + 1 :],
+                                x.dtype if dtype is None else dtype)
 
     p.def_impl(impl)
     p.def_abstract_eval(abstract)
     mlir.register_lowering(p, mlir.lower_fun(impl, multiple_results=False))
 
-    def batch(args, dims, *, pctx, placement=None, compress=None, qaxis=-1):
+    def batch(args, dims, *, pctx, placement=None, compress=None, qaxis=-1,
+              dtype=None):
         (x,), (d,) = args, dims
-        if d is batching.not_mapped:
-            extra = {} if compress is None else {"compress": compress,
-                                                 "qaxis": qaxis}
-            return p.bind(x, pctx=pctx, placement=placement, **extra), d
-        extra = {} if compress is None else {
+        extra = {} if dtype is None else {"dtype": dtype}
+        if compress is not None:
             # The batch axis lands at the end (below), so a from-the-end
             # quantization axis shifts one step deeper; a from-the-front one
             # is untouched.
-            "compress": compress,
-            "qaxis": qaxis - 1 if qaxis < 0 else qaxis,
-        }
+            mapped = d is not batching.not_mapped and qaxis < 0
+            extra.update(compress=compress,
+                         qaxis=qaxis - 1 if mapped else qaxis)
+        if d is batching.not_mapped:
+            return p.bind(x, pctx=pctx, placement=placement, **extra), d
         # Logical operand: (sizes-prefix, *rest); physical batch dim at d.
         # Move the batch axis to the end so the partition axes stay leading,
         # preserving the primitive (and hence jaxpr interpretability) under
@@ -290,30 +322,32 @@ def _make_reduction(name: str, reduce_fn):
     return p
 
 
-reduce_sum_p = _make_reduction(
-    "reduce_sum", lambda x, pl, i: jnp.sum(x, axis=i)
-)
+reduce_sum_p = _make_reduction("reduce_sum", _group_sum)
 reduce_mean_p = _make_reduction(
-    "reduce_mean", lambda x, pl, i: jnp.sum(x, axis=i) / pl.size
+    "reduce_mean",
+    lambda x, pl, i, dtype: _group_sum(x, pl, i, dtype) / pl.size,
 )
 reduce_max_p = _make_reduction(
-    "reduce_max", lambda x, pl, i: jnp.max(x, axis=i)
+    "reduce_max", lambda x, pl, i, dtype: jnp.max(x, axis=i)
 )
 
 
 def _linear_reduction_jvp(p):
-    def jvp(primals, tangents, *, pctx, placement=None, **fused):
+    def jvp(primals, tangents, *, pctx, placement=None, dtype=None,
+            **fused):
         # ``fused`` carries compress/qaxis on the int8 fast-path eqn. The
         # primal keeps them (fused execution); the tangent drops them: the
         # roundtrip is straight-through under MapReduce AD, so d(fused
         # reduce_mean@p) == d(reduce_mean@p) and grad matches the unfused
-        # composition exactly.
+        # composition exactly. An accumulation ``dtype`` is the output's
+        # dtype, so the tangent keeps it.
         (x,), (t,) = primals, tangents
-        out = p.bind(x, pctx=pctx, placement=placement, **fused)
+        acc = {} if dtype is None else {"dtype": dtype}
+        out = p.bind(x, pctx=pctx, placement=placement, **acc, **fused)
         if isinstance(t, ad.Zero):
             t_out = ad.Zero(core.get_aval(out).to_tangent_aval())
         else:
-            t_out = p.bind(t, pctx=pctx, placement=placement)
+            t_out = p.bind(t, pctx=pctx, placement=placement, **acc)
         return out, t_out
 
     return jvp
@@ -332,11 +366,15 @@ def _reduce_sum_transpose(ct, x, *, pctx, placement=None, **fused):
 
 def _reduce_mean_transpose(ct, x, *, pctx, placement=None, **fused):
     # d(reduce_mean@p)^T = broadcast@p / size(p). A compress-tagged eqn
-    # transposes identically: the int8 roundtrip is straight-through.
+    # transposes identically: the int8 roundtrip is straight-through. A mean
+    # that accumulated in another dtype hands back its operand's.
     if isinstance(ct, ad.Zero):
         return (ad.Zero(x.aval),)
     pl, _ = _resolve(pctx, placement)
-    return (broadcast_p.bind(ct / pl.size, pctx=pctx, placement=placement),)
+    ct = ct / pl.size
+    if ct.dtype != x.aval.dtype:
+        ct = ct.astype(x.aval.dtype)
+    return (broadcast_p.bind(ct, pctx=pctx, placement=placement),)
 
 
 ad.primitive_transposes[reduce_sum_p] = _reduce_sum_transpose
@@ -492,16 +530,17 @@ def bind_reduce_sum(x, placement: Optional[str] = None):
 
 
 def bind_reduce_mean(x, placement: Optional[str] = None, *,
-                     compress: Optional[str] = None, qaxis: int = -1):
+                     compress: Optional[str] = None, qaxis: int = -1,
+                     dtype=None):
     """``compress="int8"`` tags the eqn for the fused single-pass
     reduce+roundtrip execution (``qaxis`` = the partial's axis that carries
-    the per-row-block scales). The params are only attached when set, so
-    plain reductions keep their exact eqn signature."""
-    if compress is None:
-        return reduce_mean_p.bind(x, **_bind_params(placement))
-    return reduce_mean_p.bind(
-        x, compress=compress, qaxis=qaxis, **_bind_params(placement)
-    )
+    the per-row-block scales). ``dtype`` is the accumulation and output
+    dtype (see :func:`_group_sum`). The params are only attached when set,
+    so plain reductions keep their exact eqn signature."""
+    extra = {} if dtype is None else {"dtype": np.dtype(dtype)}
+    if compress is not None:
+        extra.update(compress=compress, qaxis=qaxis)
+    return reduce_mean_p.bind(x, **extra, **_bind_params(placement))
 
 
 def bind_reduce_max(x, placement: Optional[str] = None):

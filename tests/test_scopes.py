@@ -8,6 +8,7 @@ legs under ``client_step``, ``clip``, ``client_opt``, ``client_delta`` and
 """
 
 import dataclasses
+import functools
 import os
 import re
 
@@ -31,12 +32,15 @@ def _has(names, scope) -> bool:
     return any(scope in n for n in names)
 
 
-@pytest.fixture(scope="module")
-def local_sgd_names():
-    """The reduced local-SGD round of ``launch.train``, full remat."""
-    args = train_lib.parse_args(
-        ["--arch", "lm_350m", "--reduced", "--cohort", "2",
-         "--local-steps", "2", "--batch", "2", "--seq", "16"])
+@functools.lru_cache(maxsize=None)
+def _local_sgd_names(compression=None) -> frozenset:
+    """The op_names of the reduced local-SGD round of ``launch.train``,
+    full remat, with ``compression`` of the clients' deltas."""
+    argv = ["--arch", "lm_350m", "--reduced", "--cohort", "2",
+            "--local-steps", "2", "--batch", "2", "--seq", "16"]
+    if compression:
+        argv += ["--compression", compression]
+    args = train_lib.parse_args(argv)
     cfg = dataclasses.replace(registry.get_config("lm_350m").reduced(),
                               remat="full")
     step, server_opt = train_lib.build_round_fn(cfg, args)
@@ -46,7 +50,7 @@ def local_sgd_names():
              for k in ("tokens", "labels")}
     text = step.lower(params, jax.eval_shape(server_opt.init, params),
                       batch).compile().as_text()
-    return set(OP_NAME.findall(text))
+    return frozenset(OP_NAME.findall(text))
 
 
 @pytest.mark.parametrize("scope", [
@@ -54,8 +58,20 @@ def local_sgd_names():
     "drjax.reduce_mean[clients]", "client_step", "clip", "client_opt",
     "client_delta", "server_update", "transpose(", "rematted_computation",
 ])
-def test_local_sgd_round_names_its_legs(local_sgd_names, scope):
-    assert _has(local_sgd_names, scope), sorted(local_sgd_names)[:20]
+def test_local_sgd_round_names_its_legs(scope):
+    # Only a compressed round forms per-client deltas (``client_delta``);
+    # the uncompressed one forms its mean delta under ``server_update``.
+    names = _local_sgd_names("int8" if scope == "client_delta" else None)
+    assert _has(names, scope), sorted(names)[:20]
+
+
+def test_uncompressed_round_forms_its_delta_on_the_server():
+    """The uncompressed round's clients hand the reduction their parameters:
+    no client forms a delta, and the server subtracts the global from the
+    mean."""
+    names = _local_sgd_names()
+    assert not _has(names, "client_delta"), sorted(names)[:20]
+    assert _has(names, "server_update/sub"), sorted(names)[:20]
 
 
 def _flat(body):
