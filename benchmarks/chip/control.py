@@ -6,17 +6,19 @@
 
 In one process, with the round compiled once, for each of ``--seeds``
 seeds (2**32 + 1000 + i by default) the program's first rounds through the
-timed executable are compared with the plain reference on the same
-sampler's batches (``check.py``'s numbers): the sound readings, whose
+timed executable are compared with the round kind's plain reference on the
+same sampler's batches (``check.py``'s numbers): the sound readings, whose
 largest is a limit's lower reading. For the first ``--faulty`` seeds it
 also compares, against the same reference,
 
-* ``control``: the reference computed in float8 (``reference.py``'s
-  ``quant="fp8"``) in the program's place;
-* ``program_int8``: the program's own int8 compression of the client
-  deltas, its own lower-precision path;
-* ``half_batch`` of ``faults.py``, planted under the timed executable
-  (``unchanged`` reads 1 by construction and is not run).
+* ``control``: the reference computed in float8 (its ``quant="fp8"``) in
+  the program's place;
+* each of the round kind's ``VARIANTS``, the program's own lower-precision
+  paths (``program_int8``, the int8 compression of the client deltas, for
+  ``local_sgd``);
+* each of the round kind's faults (``faults.py``'s and its own
+  ``FAULTS``), planted under the timed executable, but ``unchanged``, which
+  reads 1 by construction and is not run.
 
 Each row also holds ``check.judge``'s verdict on each under the cell's
 limits. Prints one JSON line per seed and a summary: the largest sound and
@@ -39,32 +41,37 @@ sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 
 from benchmarks.chip import run  # noqa: E402
 
+# Faults not run: their readings are known without a run.
+NOT_RUN = ("unchanged",)
+
 
 def readings(cell, devices, seeds, faulty, log=print):
     """One row per seed: the sound readings and, on the first ``faulty``
     seeds, each variant's readings and ``check.judge``'s verdict on them
     under the cell's limits. Returns (rows, summary)."""
-    from benchmarks.chip import check, faults, weights
+    from benchmarks.chip import check, weights
 
     c, t = cell["config"], cell["traffic"]
-    module = run.load_module("rounds", t["round"])
-    rnd = module.build(c, t, devices)
-    lower = module.build(c, t, devices, compression="int8")
+    kind = run.round_kind(t)
+    builds = {"sound": kind.build(c, t, devices)}
+    for name, options in kind.variants.items():
+        builds[name] = kind.build(c, t, devices, **options)
+    faults = {k: f for k, f in kind.faults.items() if k not in NOT_RUN}
     compiled = {}
 
     def program(name, words, batches):
-        """``name``: "sound", "program_int8" or a fault of ``faults.py``."""
-        key = "program_int8" if name == "program_int8" else "sound"
-        build = lower if key == "program_int8" else rnd
+        """``name``: "sound", a variant or a fault of the round kind."""
+        key = name if name in builds else "sound"
+        build = builds[key]
         params, sstate = build.init(words)
         if key not in compiled:
             compiled[key] = build.step.lower(
                 params, sstate, build.place(batches[0])).compile()
         step = compiled[key]
-        if name in faults.FAULTS:
-            step = faults.FAULTS[name](step)
-        params, sstate, out = run.check_rounds(step, build, batches, words,
-                                               params, sstate)
+        if name in faults:
+            step = faults[name](step)
+        params, sstate, out = run.check_rounds(step, build, kind.reference,
+                                               batches, words, params, sstate)
         del params, sstate
         return out
 
@@ -76,12 +83,12 @@ def readings(cell, devices, seeds, faulty, log=print):
                    for r in range(run.CHECK_ROUNDS)]
         runs = {"sound": program("sound", words, batches)}
         if i < faulty:
-            for name in ("half_batch", "program_int8"):
+            for name in (*faults, *kind.variants):
                 runs[name] = program(name, words, batches)
-        ref = run.reference_rounds(c, t, batches, words)
+        ref = run.reference_rounds(kind.reference, c, t, batches, words)
         if i < faulty:
-            runs["control"] = run.reference_rounds(c, t, batches, words,
-                                                   quant="fp8")
+            runs["control"] = run.reference_rounds(kind.reference, c, t,
+                                                   batches, words, quant="fp8")
         row = {"seed": seed,
                "losses": {"program": runs["sound"]["losses"],
                           "reference": ref["losses"]}}

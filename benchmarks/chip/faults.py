@@ -6,7 +6,9 @@ batch)`` and returns a broken one with the same signature.
 * ``half_batch``: the second half of the cohort is left out and the mean
   taken over the rest, by giving it the first half's data.
 
-A flat round on one chip has no exchange between chips to leave out.
+Every round kind can have these. A fault that only one kind can have, such
+as an exchange between chips left out, is in that kind's ``FAULTS``
+(``run.round_kind``).
 """
 
 from __future__ import annotations

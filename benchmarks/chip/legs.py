@@ -29,7 +29,6 @@ import bisect
 import collections
 import re
 import sys
-import time
 
 from benchmarks.chip import trace
 
@@ -175,22 +174,6 @@ def leg_busy(ops, names: dict, lo, hi) -> dict:
             for k, v in by_leg.items()}
 
 
-def compiled_text(ctx) -> str:
-    """The text of the cell's round compiled as the run compiled it (the
-    persistent compilation cache holds it by then)."""
-    import jax
-
-    from benchmarks.chip import run, weights
-
-    c, t = ctx["config"], ctx["traffic"]
-    rnd = run.load_module("rounds", t["round"]).build(
-        c, t, jax.devices()[:ctx["chips"]])
-    params, sstate = jax.eval_shape(rnd.init, weights.seed_array(0))
-    batch = rnd.place(run.round_batch(run.sampler(t, c["vocab_size"], 0),
-                                      t, 0))
-    return rnd.step.lower(params, sstate, batch).compile().as_text()
-
-
 def _log(*parts) -> None:
     print(*parts, file=sys.stderr, flush=True)
 
@@ -237,7 +220,9 @@ def _report(tr, names, lo, hi, rounds, starts, legs_ns) -> None:
 def readings(ctx) -> dict:
     """Every reading of this module for ``ctx``, computed once and kept in
     ``ctx["legs"]``: each leg's ``<metric>`` in ms a round, or None where
-    the program binds none of the scopes, and the two idle readings."""
+    the program binds none of the scopes, and the two idle readings.
+    ``ctx["op_names"]`` is :func:`op_names` of the timed executable's
+    text."""
     if "legs" in ctx:
         return ctx["legs"]
     tr, lo, hi, rounds = ctx["trace"], ctx["lo"], ctx["hi"], ctx["rounds"]
@@ -249,12 +234,7 @@ def readings(ctx) -> dict:
                  for ops in tr.ops.values()]
         out["step_idle_ms"] = _ms(max(s for s, _ in split), rounds)
         out["host_idle_ms"] = _ms(max(h for _, h in split), rounds)
-    names = ctx.get("op_names")
-    if names is None:
-        t0 = time.perf_counter()
-        names = op_names(compiled_text(ctx))
-        _log(f"op names of {len(names)} instructions from the compiled round "
-             f"in {time.perf_counter() - t0:.2f} s")
+    names = ctx["op_names"]
     if tr.ops and any(m in n for n in names.values() for m in PROGRAM_SCOPES):
         legs_ns = {d: leg_busy(ops, names, lo, hi)
                    for d, ops in tr.ops.items()}

@@ -11,6 +11,8 @@ from repro.launch import train as train_lib
 
 # build_round_fn fixes these; a traffic file that asks for others is refused.
 FIXED = {"grad_clip": 1.0, "server_lr": 1.0}
+# The program's own lower-precision path: int8 compression of the deltas.
+VARIANTS = {"program_int8": {"compression": "int8"}}
 
 
 def build(c: dict, t: dict, devices: list, compression=None) -> program.Round:

@@ -9,6 +9,22 @@ and the limits of its output check (``limits/<cell>.json``); each per-layer
 metric is read by ``metrics/<metric>.py``. Adding a cell or a metric adds
 files and entries, and edits none.
 
+A round kind, ``rounds/<round>.py``, defines ``build(c, t, devices,
+**variant) -> program.Round`` and may set three attributes, which
+:func:`round_kind` resolves:
+
+* ``REFERENCE``: the file name, under this directory, of the kind's plain
+  reference (default ``"reference"``). A reference imports nothing of the
+  program under test (``src/repro``) and defines ``frozen(c)``,
+  ``init(frozen_c, words)``, ``run_round(c, t, p, batch, quant=None) ->
+  (params, loss)``, where ``quant="fp8"`` is the control, and
+  ``leaf_change_norms(p, p0)``;
+* ``VARIANTS``: the program's own lower-precision paths, a variant's name
+  to the ``build`` keyword arguments that switch it on (default none);
+  ``control.py`` runs each against the reference;
+* ``FAULTS``: faults that only this kind can have, a name to a wrapper of
+  the compiled round as in ``faults.py``, whose faults every kind has.
+
 A run: weights and server state from the seed in one jitted call; the
 round compiled ahead of time; three rounds through the timed executable,
 fed by the program's own ``CohortSampler`` as ``launch.train`` feeds it,
@@ -16,9 +32,10 @@ whose losses and parameter changes the output check reads, and one more
 (set-up ends here); then the window, one round at a time for
 ``--seconds``, each sampled, dispatched, waited for and its loss read back. Any compile in the
 window fails the run. After the window the program's state is freed and the
-plain reference (``reference.py``) runs the same three rounds on the
-batches the sampler gave the program; ``check.py`` decides ``correct``. ``--trace 1`` records the window with the profiler and
-reports the per-layer metrics instead of the end-to-end ones.
+round kind's plain reference runs the same three rounds on the batches the
+sampler gave the program; ``check.py`` decides ``correct``. ``--trace 1``
+records the window with the profiler and reports the per-layer metrics
+instead of the end-to-end ones.
 
 The last line of stdout is the result as JSON. Without a TPU, or with fewer
 chips than the cell asks for, the run exits non-zero and prints no result.
@@ -39,6 +56,7 @@ import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
+import types  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
@@ -50,6 +68,8 @@ sys.path[:] = [p for p in sys.path if os.path.abspath(p or ".") != HERE]
 # that every run of a cell after the first finds its programs.
 CACHE_DIR = os.path.join(HERE, ".jax_cache")
 CHECK_ROUNDS = 3
+# What every round kind's reference module defines.
+REFERENCE_API = ("frozen", "init", "run_round", "leaf_change_norms")
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
@@ -64,15 +84,44 @@ def _load_json(path: str) -> dict:
         return json.load(f)
 
 
-def load_module(kind: str, name: str):
-    """``<HERE>/<kind>/<name>.py`` as a module."""
-    path = os.path.join(HERE, kind, f"{name}.py")
+def load_module(kind: str, name: str, here: str = HERE):
+    """``<here>/<kind>/<name>.py`` as a module."""
+    path = os.path.join(here, kind, f"{name}.py")
     if not os.path.isfile(path):
         raise FileNotFoundError(f"no {kind} file for {name!r}: {path}")
     spec = importlib.util.spec_from_file_location(f"_bench_{kind}_{name}", path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
+
+
+def round_kind(t: dict, here: str = HERE) -> types.SimpleNamespace:
+    """The round kind of traffic mix ``t`` (see the module docstring): its
+    ``build``, its ``reference`` module, its ``variants`` and its
+    ``faults``, those of ``faults.py`` among them."""
+    from benchmarks.chip.faults import FAULTS as SHARED_FAULTS
+
+    module = load_module("rounds", t["round"], here)
+    where = module.__file__
+    name = getattr(module, "REFERENCE", "reference")
+    if not os.path.isfile(os.path.join(here, f"{name}.py")):
+        raise FileNotFoundError(f"{where}: REFERENCE {name!r} is no file "
+                                f"{name}.py in {here}")
+    ref = load_module("", name, here)
+    missing = [f for f in REFERENCE_API if not callable(getattr(ref, f, None))]
+    own = getattr(module, "FAULTS", {})
+    variants = getattr(module, "VARIANTS", {})
+    clash = (set(own) & set(SHARED_FAULTS)) | (
+        set(variants) & {"sound", "control", *SHARED_FAULTS, *own})
+    if missing:
+        raise ValueError(f"{where}: its reference {ref.__file__} lacks "
+                         f"{missing}")
+    if clash:
+        raise ValueError(f"{where}: FAULTS or VARIANTS take the names "
+                         f"{sorted(clash)}, which faults.py or control.py use")
+    return types.SimpleNamespace(build=module.build, reference=ref,
+                                 variants=variants,
+                                 faults={**SHARED_FAULTS, **own})
 
 
 def load_cell(name: str, root: str = ROOT) -> dict:
@@ -160,14 +209,13 @@ def tokens_per_round(t: dict) -> int:
     return t["cohort"] * t["local_steps"] * t["batch"] * t["seq"]
 
 
-def check_rounds(step, rnd, batches, words, params, sstate):
+def check_rounds(step, rnd, ref, batches, words, params, sstate):
     """The first rounds through ``step`` from the seed's weights, one on
     each of ``batches``: (params, server state, readings), the readings
-    being the rounds' losses and the per-leaf norms of the change of the
-    parameters after the first round and after the last."""
+    being the rounds' losses and the per-leaf norms (``ref``'s
+    ``leaf_change_norms``) of the change of the parameters after the first
+    round and after the last."""
     import jax
-
-    from benchmarks.chip import reference
 
     out = {"losses": []}
     for r, batch in enumerate(batches):
@@ -177,25 +225,23 @@ def check_rounds(step, rnd, batches, words, params, sstate):
         if r in (0, CHECK_ROUNDS - 1):
             p0, _ = rnd.init(words)
             out[f"change{r + 1}"] = _flat_floats(
-                reference.leaf_change_norms(params, p0))
+                ref.leaf_change_norms(params, p0))
             del p0
     return params, sstate, out
 
 
-def reference_rounds(c, t, batches, words, quant=None) -> dict:
-    """The same readings of the plain reference's rounds on the same
-    batches, on one chip."""
-    from benchmarks.chip import reference
-
-    cf = reference.frozen(c)
+def reference_rounds(ref, c, t, batches, words, quant=None) -> dict:
+    """The same readings of the plain reference ``ref``'s rounds on the
+    same batches, on one chip."""
+    cf = ref.frozen(c)
     out = {"losses": []}
-    p = reference.init(cf, words)
+    p = ref.init(cf, words)
     for r, batch in enumerate(batches):
-        p, loss = reference.run_round(c, t, p, batch, quant)
+        p, loss = ref.run_round(c, t, p, batch, quant)
         out["losses"].append(loss)
         if r in (0, CHECK_ROUNDS - 1):
             out[f"change{r + 1}"] = _flat_floats(
-                reference.leaf_change_norms(p, reference.init(cf, words)))
+                ref.leaf_change_norms(p, ref.init(cf, words)))
     return out
 
 
@@ -206,11 +252,12 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, devices,
     faults with it)."""
     import jax
 
-    from benchmarks.chip import check, flops
+    from benchmarks.chip import check, flops, legs
     from benchmarks.chip import trace as trace_lib, weights
 
     c, t = cell["config"], cell["traffic"]
-    rnd = load_module("rounds", t["round"]).build(c, t, devices)
+    kind = round_kind(t)
+    rnd = kind.build(c, t, devices)
     clock = CompileClock()
     words = weights.seed_array(seed)
     sample = sampler(t, c["vocab_size"], seed)
@@ -226,8 +273,8 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, devices,
     # The first rounds go through the timed executable and feed; the output
     # check reads their losses and parameter changes, and the reference
     # later runs on the same batches.
-    params, sstate, prog = check_rounds(step, rnd, batches, words, params,
-                                        sstate)
+    params, sstate, prog = check_rounds(step, rnd, kind.reference, batches,
+                                        words, params, sstate)
     marks["checked"] = time.perf_counter() - T_START
     # One more round after the check freed its copy of the start weights, so
     # that the window opens on the memory layout the rounds keep.
@@ -278,8 +325,8 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, devices,
     tokens_per_s = rounds * tokens_per_round(t) / window_s
 
     stats = [d.memory_stats() or {} for d in rnd.devices]
-    kind = rnd.devices[0].device_kind
-    device = {"platform": rnd.devices[0].platform, "kind": kind,
+    device_kind = rnd.devices[0].device_kind
+    device = {"platform": rnd.devices[0].platform, "kind": device_kind,
               "count": len(jax.devices()),
               "memory_peak_bytes": max(s.get("peak_bytes_in_use", 0)
                                        for s in stats)}
@@ -292,7 +339,8 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, devices,
         lo, hi = tr.window()
         ctx = {"trace": tr, "lo": lo, "hi": hi, "rounds": rounds,
                "tokens_per_s": tokens_per_s, "chips": len(rnd.devices),
-               "peak": flops.peaks(kind), "config": c, "traffic": t}
+               "peak": flops.peaks(device_kind), "config": c, "traffic": t,
+               "op_names": legs.op_names(compiled.as_text())}
         for m in cell["per_layer"]:
             value = load_module("metrics", m["name"]).read(ctx)
             if value is not None:
@@ -324,7 +372,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, devices,
     del params, sstate, metrics, out, step, compiled
     gc.collect()
     t_ref = time.perf_counter()
-    ref = reference_rounds(c, t, batches, words)
+    ref = reference_rounds(kind.reference, c, t, batches, words)
     log(f"reference {time.perf_counter() - t_ref:.2f} s; losses program "
         f"{prog['losses']} reference {ref['losses']}")
 

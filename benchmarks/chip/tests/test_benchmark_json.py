@@ -3,6 +3,7 @@ files by name."""
 
 from __future__ import annotations
 
+import ast
 import json
 import os
 import re
@@ -73,6 +74,24 @@ def test_every_workload_resolves(bench):
         ends = {m["name"] for m in cell["end_to_end"]}
         assert "setup_s" in ends and len(ends) >= 2
         assert cell["per_layer"], w["name"]
+
+
+def test_every_workload_resolves_its_round_kind(bench):
+    """A reference with the functions the harness calls and no import of
+    the program, and the names of the rows control.py writes distinct."""
+    for w in bench["workloads"]:
+        kind = run.round_kind(run.load_cell(w["name"])["traffic"])
+        for f in run.REFERENCE_API:
+            assert callable(getattr(kind.reference, f)), (w["name"], f)
+        with open(kind.reference.__file__) as src:
+            tree = ast.parse(src.read())
+        imported = [a.name for n in ast.walk(tree)
+                    if isinstance(n, ast.Import) for a in n.names]
+        imported += [n.module for n in ast.walk(tree)
+                     if isinstance(n, ast.ImportFrom) and n.module]
+        assert not [m for m in imported if m.split(".")[0] == "repro"]
+        rows = ["sound", "control", *kind.variants, *kind.faults]
+        assert len(set(rows)) == len(rows), (w["name"], rows)
 
 
 def test_every_per_layer_metric_has_a_reader(bench):

@@ -16,16 +16,18 @@ import pytest
 
 from tiny import workloads
 
+from benchmarks.chip import run
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 SEED = 2**33 + 77
 
+
 def _cases():
-    """Each cell sound, and with each fault a flat round on one chip can
-    have."""
+    """Each cell sound, and with each fault its round kind can have."""
     out = []
-    for name, _ in workloads():
+    for name, traffic in workloads():
         out.append((name, "none", True))
-        for fault in ("unchanged", "half_batch"):
+        for fault in run.round_kind(traffic).faults:
             out.append((name, fault, False))
     return out
 

@@ -4,13 +4,17 @@ intervals and on a stretch of a trace recorded on the chip."""
 
 from __future__ import annotations
 
+import gc
+
+import jax
 import pytest
 
 from tiny import files, tiny_cell
-from benchmarks.chip import legs, run, trace
+from benchmarks.chip import flops, legs, run, trace, weights
 from benchmarks.chip.trace import Op, Trace
 from test_trace import _recorded
 
+PEAK = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
 # op_names from the lm_1b round compiled on the CPU (full remat).
 MAP = "jit(round_fn)/drjax.map[clients]/vmap()/while/body/closed_call"
 LAYER = f"{MAP}/client_step/transpose(jvp())/while/body/closed_call/checkpoint"
@@ -92,12 +96,71 @@ def test_instruction_of_an_op_event():
     assert legs.instruction("copy-done.56") == "copy-done.56"
 
 
-def test_the_tiny_round_compiled_here_names_every_leg():
+@pytest.fixture(scope="module")
+def traced_run():
+    """A tiny traced run of the cell, with a stand-in trace of one op (a
+    CPU's trace has no TPU ops): the cell, the text of the executable it
+    timed, and the ``op_names`` it handed the readers."""
     cell = tiny_cell("lm_1b.local_sgd.c2h4")
-    ctx = {"config": cell["config"], "traffic": cell["traffic"], "chips": 1}
-    found = {legs.leg(n) for n in legs.op_names(legs.compiled_text(ctx))
-             .values()}
+    timed, handed = [], []
+    readings = legs.readings
+
+    def record(ctx):
+        handed.append(ctx["op_names"])
+        return readings(ctx)
+
+    stand_in = Trace(ops={0: [Op("%x.1 = f", 1, 2)]},
+                     spans=[Op("sample", 0, 1), Op("wait", 2, 3)])
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(trace, "find_xplane", lambda _: None)
+        mp.setattr(trace, "load", lambda _: stand_in)
+        mp.setattr(flops, "peaks", lambda _: PEAK)
+        mp.setattr(legs, "readings", record)
+        try:
+            run.run_cell(cell, 2**33 + 5, 0.1, True, jax.devices()[:1],
+                         break_step=lambda step: timed.append(step) or step)
+        finally:
+            gc.unfreeze()
+    return cell, timed[0].as_text(), handed[0]
+
+
+def _compiled_again(cell) -> str:
+    """The cell's round built and compiled a second time, from shapes, as
+    the readers did after the window before the run handed them the timed
+    executable's names."""
+    c, t = cell["config"], cell["traffic"]
+    rnd = run.round_kind(t).build(c, t, jax.devices()[:cell["chips"]])
+    params, sstate = jax.eval_shape(rnd.init, weights.seed_array(0))
+    batch = rnd.place(run.round_batch(run.sampler(t, c["vocab_size"], 0),
+                                      t, 0))
+    return rnd.step.lower(params, sstate, batch).compile().as_text()
+
+
+def test_the_tiny_round_compiled_here_names_every_leg(traced_run):
+    _, text, _ = traced_run
+    found = {legs.leg(n) for n in legs.op_names(text).values()}
     assert set(legs.METRICS) <= found
+
+
+def test_readers_get_the_timed_executables_op_names(traced_run):
+    """A traced run hands the readers the op_names of the executable it
+    timed, and the legs read from them equal those read from the round
+    compiled again."""
+    cell, text, handed = traced_run
+    again = legs.op_names(_compiled_again(cell))
+    assert handed == legs.op_names(text) == again
+    ops = [Op(f"%{n} = f", 2 * i, 2 * i + 1) for i, n in enumerate(handed)]
+    spans = [Op("sample", 0, 1), Op("wait", 2 * len(ops), 2 * len(ops) + 1)]
+    tr = Trace(ops={0: ops}, spans=spans)
+    lo, hi = tr.window()
+
+    def read(op_names):
+        return legs.readings({"trace": tr, "lo": lo, "hi": hi, "rounds": 1,
+                              "op_names": op_names})
+
+    got = read(handed)
+    assert got == read(again)
+    assert all(got[m] > 0 for m in legs.METRICS.values()), got
 
 
 # Two rounds in a window [0, 100), sampled from 10 and from 55, their ops
@@ -128,7 +191,7 @@ def _ctx(ops_by_device, names, rounds=2):
     cell = files("lm_1b", "local_sgd.c2h4")
     return {"trace": tr, "lo": lo, "hi": hi, "rounds": rounds,
             "tokens_per_s": 1.0, "chips": len(ops_by_device),
-            "peak": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
+            "peak": PEAK,
             "config": cell["config"], "traffic": cell["traffic"],
             "op_names": names}
 
