@@ -60,7 +60,6 @@ from repro.kernels import ops  # noqa: E402
 from repro.launch import steps as steps_lib  # noqa: E402
 from repro.launch import train as train_lib  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
-from repro.launch.mesh import mesh_for_placements, placement_axes_for  # noqa: E402
 from repro.models import registry  # noqa: E402
 
 # The round's shape on one chip: lm_350m's 470M bf16 parameters with this
@@ -68,7 +67,7 @@ from repro.models import registry  # noqa: E402
 SHAPE = ("--cohort", "4", "--local-steps", "4", "--batch", "4",
          "--seq", "512")
 ROUNDS = 3
-PODS, CLIENTS_PER_POD = 2, 2
+PODS = 2
 # Layers of the hier-int8 rounds. At all 24 the round needs 15.04 GB of
 # temporaries and 0.94 GB of arguments, over a v5e's 15.75 GB: its four
 # clients' f32 deltas (1.9 GB each) are live next to their flat-packed copy.
@@ -337,34 +336,23 @@ def phase_plan(args, cfg, first, clock):
     return rep
 
 
-def hier_round(cfg, args, *, mesh=None):
-    """The int8 pod-hierarchical round and its client leg, as a user builds
-    them. The fused kernel is insisted on: a fallback to the generic
-    composition raises instead of passing unseen."""
-    loss_fn = functools.partial(registry.loss_fn, cfg)
-    client_opt = optim.sgd(args.client_lr)
-    server_opt = optim.fedavg_momentum(1.0)
-    rcfg = rounds_lib.LocalSGDConfig(
-        partition_size=CLIENTS_PER_POD, num_pods=PODS,
-        num_local_steps=args.local_steps, grad_clip=1.0, compression="int8",
-        fused_reduce=True, mesh=mesh,
-        partition_axes=placement_axes_for(mesh) if mesh is not None else None,
-    )
-    round_fn = rounds_lib.make_hierarchical_local_sgd_round(
-        loss_fn, client_opt, server_opt, rcfg
-    )
+def hier_round(cfg, args, devices):
+    """The int8 pod-hierarchical round as ``launch.train --pods`` builds it
+    on ``devices`` (a ``(pod, data)`` mesh of them, or one device): the
+    jitted, donated round, its layout, its server optimizer and its client
+    leg without compression. The fused kernel is insisted on: a fallback to
+    the generic composition raises instead of passing unseen."""
+    args = argparse.Namespace(**{**vars(args), "pods": PODS,
+                                 "compression": "int8"})
+    layout = train_lib.round_layout(args, devices)
+    step, server_opt = train_lib.build_round_fn(cfg, args, layout.mesh)
     client_update = rounds_lib._make_client_update(
-        loss_fn, client_opt, dataclasses.replace(rcfg, compression=None)
+        functools.partial(registry.loss_fn, cfg), optim.sgd(args.client_lr),
+        rounds_lib.LocalSGDConfig(partition_size=args.cohort // PODS,
+                                  num_local_steps=args.local_steps,
+                                  grad_clip=1.0),
     )
-    return round_fn, client_update, server_opt
-
-
-def pod_batch(args, cfg, round_idx):
-    """Round data regrouped (pods, clients per pod, steps, batch, seq)."""
-    return jax.tree_util.tree_map(
-        lambda x: x.reshape((PODS, CLIENTS_PER_POD) + x.shape[1:]),
-        round_batch(args, cfg, round_idx),
-    )
+    return step, layout, server_opt, client_update
 
 
 @functools.partial(jax.jit, static_argnames=("client_update", "interpret"))
@@ -396,22 +384,25 @@ def phase_hier_int8(args, cfg, clock, *, kernel_marker="tpu_custom_call",
     check(os.environ.get("REPRO_NO_FUSED_REDUCE", "") in ("", "0"),
           "REPRO_NO_FUSED_REDUCE is set: the fused kernel would not run")
     mark = clock.mark()
-    round_fn, client_update, server_opt = hier_round(cfg, args)
-    params, sstate = init_state(args, cfg, server_opt)
+    step, layout, server_opt, client_update = hier_round(
+        cfg, args, jax.devices()[:1])
+    params, sstate = layout.state(init_state(args, cfg, server_opt))
+
+    def pod_batch(round_idx):
+        return layout.batch(round_batch(args, cfg, round_idx))
 
     kernel_steps = float(_kernel_vs_oracle(
-        params, pod_batch(args, cfg, 0), client_update, interpret
+        params, pod_batch(0), client_update, interpret
     ))
 
     round_mark = clock.mark()
-    step = jax.jit(round_fn, donate_argnums=(0, 1))
-    compiled = step.lower(params, sstate, pod_batch(args, cfg, 0)).compile()
+    compiled = step.lower(params, sstate, pod_batch(0)).compile()
     round_compile_s, _ = clock.since(round_mark)
     kernel = kernel_marker is None or kernel_marker in compiled.as_text()
 
     losses, round_s = [], []
     for r in range(ROUNDS):
-        batch = pod_batch(args, cfg, r)
+        batch = pod_batch(r)
         t0 = time.perf_counter()
         params, sstate, metrics = jax.block_until_ready(
             compiled(params, sstate, batch)
@@ -420,7 +411,7 @@ def phase_hier_int8(args, cfg, clock, *, kernel_marker="tpu_custom_call",
         losses.append(float(metrics["loss"]))
     compile_s, hits = clock.since(mark)
     rep = report(
-        "hier-int8", pods=PODS, clients_per_pod=CLIENTS_PER_POD,
+        "hier-int8", pods=PODS, clients_per_pod=args.cohort // PODS,
         layers=cfg.num_layers, kernel_in_hlo=kernel_marker,
         kernel_vs_oracle_int8_steps=kernel_steps, compile_s=compile_s,
         round_compile_s=round_compile_s, compile_cache_hits=hits,
@@ -535,32 +526,25 @@ def phase_sharded_round(args, cfg, clock, devices):
                            mesh={"data": len(devices), "model": 1})
 
 
-def _hier_once(cfg, args, mesh, clock):
-    """One hier-int8 round on ``mesh`` (one device if None): the run, the
-    memory of its devices and whether the kernel is in its HLO."""
-    round_fn, _, server_opt = hier_round(cfg, args, mesh=mesh)
-    params, sstate = init_state(args, cfg, server_opt)
-    batch = jax.device_put(pod_batch(args, cfg, 0))
-    if mesh is not None:
-        rep = compat.replicated_sharding(mesh)
-        params, sstate = jax.device_put((params, sstate), rep)
-        batch = jax.device_put(
-            batch, compat.named_sharding(mesh, ("pod", "data"))
-        )
-    step = jax.jit(round_fn, donate_argnums=(0, 1))
+def _hier_once(cfg, args, devices, clock):
+    """One hier-int8 round on ``devices``: the run, the memory of each
+    device, the text of its executable and its mesh's shape (None on one
+    device)."""
+    step, layout, server_opt, _ = hier_round(cfg, args, devices)
+    params, sstate = layout.state(init_state(args, cfg, server_opt))
+    batch = layout.batch(round_batch(args, cfg, 0))
     run, compiled = _timed_round(clock, step, params, sstate, batch)
-    devs = mesh.devices.flat if mesh is not None else jax.devices()[:1]
-    return run, {d.id: memory(d) for d in devs}, compiled.as_text()
+    shape = None if layout.mesh is None else dict(layout.mesh.shape)
+    return (run, {d.id: memory(d) for d in devices}, compiled.as_text(),
+            shape)
 
 
 def phase_hier_sharded(args, cfg, clock, devices, kernel_marker):
     mark = clock.mark()
-    mesh = mesh_for_placements({"pods": PODS, "clients": CLIENTS_PER_POD},
-                               devices=devices)
-    run4, mem4, hlo4 = _hier_once(cfg, args, mesh, clock)
-    run1, _, hlo1 = _hier_once(cfg, args, None, clock)
+    run4, mem4, hlo4, mesh = _hier_once(cfg, args, devices, clock)
+    run1, _, hlo1, _ = _hier_once(cfg, args, devices[:1], clock)
     rep = _compare_rounds("hier-int8-sharded", clock, mark, run4, run1, mem4,
-                          mesh=dict(mesh.shape), layers=cfg.num_layers,
+                          mesh=mesh, layers=cfg.num_layers,
                           kernel_in_hlo=kernel_marker)
     check(kernel_marker is None
           or (kernel_marker in hlo4 and kernel_marker in hlo1),

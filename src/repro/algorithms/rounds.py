@@ -125,7 +125,7 @@ def _make_client_update(loss_fn: Callable, client_opt: Optimizer,
         if not as_delta:
             return params_new, jnp.mean(losses)
         with jax.named_scope("client_delta"):
-            delta = _tree_sub(params_new, params0)
+            delta = _tree_sub(_as_stored(params_new), params0)
             if cfg.compression == "int8":
                 delta = compression.int8_roundtrip(delta)
             elif cfg.compression == "topk":
@@ -373,3 +373,20 @@ def make_fedsgd_round(
         return new_params, new_server_state, {"loss": mean_loss}
 
     return round_fn
+
+
+def _as_stored(tree):
+    """Each leaf in float32, rounded as its storage dtype rounds it.
+
+    XLA may keep a value that is converted to a narrower float and back at
+    the wider precision (``xla_allow_excess_precision``, on by default):
+    on a TPU a client's bf16 parameters, converted back to f32 for the
+    delta inside one fusion, come out unrounded. ``reduce_precision`` makes
+    the rounding an op of its own, which no simplification removes."""
+    def leaf(x):
+        info = jnp.finfo(x.dtype)
+        return jax.lax.reduce_precision(x.astype(jnp.float32),
+                                        exponent_bits=info.nexp,
+                                        mantissa_bits=info.nmant)
+
+    return jax.tree_util.tree_map(leaf, tree)

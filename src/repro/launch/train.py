@@ -13,6 +13,16 @@ CPU-scale example (reduced config, a few hundred rounds):
 On a real cluster, run unmodified under `jax.distributed` with
 ``--mesh single|multi`` (the production meshes from launch/mesh.py).
 
+``--pods P`` (P > 1) trains DrJAX's nested round instead of the flat one:
+``--cohort`` clients in P pods, a reduce inside each pod, then the mean
+across pods, with ``--compression int8`` on the fused reduce+compress
+kernel. Over several devices the round runs on a ``(pod P, data n/P)``
+mesh of them; state is replicated and each round's batch sharded over the
+mesh (:func:`round_layout`). On a host with four chips:
+
+    python -m repro.launch.train --arch lm_350m --pods 2 --cohort 8 \
+        --local-steps 1 --batch 4 --compression int8
+
 Profiling a real run: ``--profile-dir DIR`` records rounds with the JAX
 profiler into ``DIR`` (an ``.xplane.pb`` under ``DIR/plugins/profile/``),
 ``--profile-rounds START:END`` the rounds START..END-1 only (default: all).
@@ -29,20 +39,27 @@ each round carry the program's scopes (``client_step``, ``drjax.<op>[...]``,
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import functools
 import json
 import logging
 import time
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import optim
-from repro.algorithms.rounds import LocalSGDConfig, make_local_sgd_round
+from repro import compat, optim
+from repro.algorithms.rounds import (
+    LocalSGDConfig,
+    make_hierarchical_local_sgd_round,
+    make_local_sgd_round,
+)
 from repro.checkpoint import CheckpointManager
 from repro.data.grouped import CohortSampler, GroupedCorpus
 from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import mesh_for_placements, placement_axes_for
 from repro.models import registry
 from repro.runtime.failure import FailureInjector, run_with_recovery
 from repro.runtime.stragglers import StragglerSimulator, straggler_mask
@@ -50,7 +67,80 @@ from repro.runtime.stragglers import StragglerSimulator, straggler_mask
 logger = logging.getLogger(__name__)
 
 
-def build_round_fn(cfg, args):
+@dataclasses.dataclass(frozen=True)
+class RoundLayout:
+    """Where a round's state and batches live.
+
+    ``mesh``: the round's mesh, or None where it runs on one device;
+    ``state(tree)``: parameters or server state onto the round's devices,
+    replicated; ``batch(host)``: a sampler batch of shape ``(cohort, steps,
+    batch, seq)`` as the round takes it."""
+
+    mesh: Optional[jax.sharding.Mesh]
+    state: Callable
+    batch: Callable
+
+
+def _as_is(tree):
+    return tree
+
+
+def _clients_per_pod(args) -> int:
+    if args.pods < 1 or args.cohort % args.pods:
+        raise ValueError(f"--pods {args.pods} does not divide --cohort "
+                         f"{args.cohort}")
+    return args.cohort // args.pods
+
+
+def round_layout(args, devices=None) -> RoundLayout:
+    """The layout of the round ``args`` asks for, on ``devices`` (default:
+    every device).
+
+    The flat round (``--pods 1``) takes its state and batches as they come.
+    With ``--pods P`` the batch is regrouped to ``(P, cohort / P, steps,
+    batch, seq)``; over n > 1 devices the round runs on a ``(pod P, data
+    n / P)`` mesh, state replicated and the batch sharded over both axes,
+    so each device holds ``cohort / n`` clients."""
+    pods = args.pods
+    if pods == 1:
+        return RoundLayout(None, _as_is, _as_is)
+    per_pod = _clients_per_pod(args)
+    devices = list(jax.devices() if devices is None else devices)
+
+    def regroup(host):
+        return jax.tree_util.tree_map(
+            lambda x: x.reshape((pods, per_pod) + x.shape[1:]), host)
+
+    if len(devices) == 1:
+        one = jax.sharding.SingleDeviceSharding(devices[0])
+        return RoundLayout(None, lambda tree: jax.device_put(tree, one),
+                           lambda host: jax.device_put(regroup(host), one))
+    n = len(devices)
+    if n % pods or per_pod % (n // pods):
+        raise ValueError(
+            f"--pods {pods} with --cohort {args.cohort} cannot run on {n} "
+            f"devices: a (pod {pods}, data {n // pods}) mesh needs the "
+            f"device count a multiple of the pods and the {per_pod} clients "
+            "of a pod a multiple of the devices of a pod")
+    mesh = mesh_for_placements({"pods": pods, "clients": n // pods},
+                               devices=devices)
+    axes = placement_axes_for(mesh)
+    replicated = compat.replicated_sharding(mesh)
+    sharded = compat.named_sharding(mesh, (axes["pods"], axes["clients"]))
+    return RoundLayout(mesh, lambda tree: jax.device_put(tree, replicated),
+                       lambda host: jax.device_put(regroup(host), sharded))
+
+
+def build_round_fn(cfg, args, mesh=None):
+    """The round ``args`` asks for, jitted with the parameters and the
+    server state donated, and its server optimizer: ``(round_fn,
+    server_opt)``.
+
+    ``--pods 1`` builds the flat round. ``--pods P`` builds the nested
+    round over P pods of ``cohort / P`` clients, on ``mesh`` (the
+    :func:`round_layout` mesh; None: one device); with ``--compression
+    int8`` the pod partials take the fused reduce+compress kernel, which is
+    insisted on (a fallback to the generic composition raises)."""
     loss_fn = functools.partial(registry.loss_fn, cfg)
     client_opt = (
         optim.adamw(args.client_lr) if args.algorithm == "diloco"
@@ -68,7 +158,18 @@ def build_round_fn(cfg, args):
         compression=args.compression,
         straggler_mask=args.stragglers,
     )
-    round_fn = make_local_sgd_round(loss_fn, client_opt, server_opt, round_cfg)
+    if args.pods == 1:
+        round_fn = make_local_sgd_round(loss_fn, client_opt, server_opt,
+                                        round_cfg)
+    else:
+        round_cfg = dataclasses.replace(
+            round_cfg, partition_size=_clients_per_pod(args),
+            num_pods=args.pods, mesh=mesh,
+            partition_axes=placement_axes_for(mesh),
+            fused_reduce=True if args.compression == "int8" else None,
+        )
+        round_fn = make_hierarchical_local_sgd_round(
+            loss_fn, client_opt, server_opt, round_cfg)
     # Donate the carried state (params, server_state): the round loop below
     # rebinds both every round, so the executable updates them in place.
     return jax.jit(round_fn, donate_argnums=(0, 1)), server_opt
@@ -119,6 +220,9 @@ def parse_args(argv=None) -> argparse.Namespace:
     ap.add_argument("--client-lr", type=float, default=0.05)
     ap.add_argument("--compression", default=None,
                     choices=(None, "int8", "topk"))
+    ap.add_argument("--pods", type=int, default=1,
+                    help="train the nested round over this many pods of "
+                         "cohort / pods clients (1: the flat round)")
     ap.add_argument("--stragglers", action="store_true")
     ap.add_argument("--straggler-deadline-pct", type=float, default=90.0)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
@@ -158,8 +262,10 @@ def train(args, *, on_round=None) -> dict:
         args.batch = min(args.batch, 4)
 
     params = registry.init_params(jax.random.PRNGKey(args.seed), cfg)
-    round_fn, server_opt = build_round_fn(cfg, args)
+    layout = round_layout(args)
+    round_fn, server_opt = build_round_fn(cfg, args, layout.mesh)
     server_state = server_opt.init(params)
+    params, server_state = layout.state((params, server_state))
 
     corpus = GroupedCorpus(vocab_size=cfg.vocab_size)
     sampler = CohortSampler(corpus, cohort_size=args.cohort)
@@ -190,7 +296,8 @@ def train(args, *, on_round=None) -> dict:
             data = sampler.round_batch(
                 round_idx, args.local_steps, args.batch, args.seq
             )
-            batch = {"tokens": data["tokens"], "labels": data["labels"]}
+            batch = layout.batch(
+                {"tokens": data["tokens"], "labels": data["labels"]})
         t0 = time.perf_counter()
         with jax.profiler.TraceAnnotation("dispatch"):
             if strag is not None:
@@ -200,7 +307,8 @@ def train(args, *, on_round=None) -> dict:
                 )
                 mask = straggler_mask(durations, deadline,
                                       min_finishers=max(args.cohort // 2, 1))
-                out = round_fn(params, server_state, batch, mask)
+                out = round_fn(params, server_state, batch,
+                               layout.batch(mask))
             else:
                 out = round_fn(params, server_state, batch)
         with jax.profiler.TraceAnnotation("wait"):

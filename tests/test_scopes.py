@@ -139,28 +139,26 @@ def test_hierarchical_int8_round_names_both_reduce_levels(device_pool):
         import chip_smoke
         from repro import compat
         from repro.launch import train as train_lib
-        from repro.launch.mesh import mesh_for_placements
         from repro.models import registry
 
         args = train_lib.parse_args(
-            ["--arch", "lm_350m", "--reduced", "--local-steps", "1",
-             "--batch", "2", "--seq", "16"])
+            ["--arch", "lm_350m", "--reduced", "--cohort", "4",
+             "--local-steps", "1", "--batch", "2", "--seq", "16"])
         cfg = registry.get_config("lm_350m").reduced()
-        mesh = mesh_for_placements(
-            {{"pods": chip_smoke.PODS, "clients": chip_smoke.CLIENTS_PER_POD}},
-            devices=jax.devices()[:4])
-        round_fn, _, server_opt = chip_smoke.hier_round(cfg, args, mesh=mesh)
+        step, layout, server_opt, _ = chip_smoke.hier_round(
+            cfg, args, jax.devices()[:4])
+        mesh = layout.mesh
         rep = compat.replicated_sharding(mesh)
         described = lambda t, sh: jax.tree_util.tree_map(
             lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh), t)
         params = jax.eval_shape(
             lambda: registry.init_params(jax.random.PRNGKey(0), cfg))
         sstate = jax.eval_shape(server_opt.init, params)
-        shape = (chip_smoke.PODS, chip_smoke.CLIENTS_PER_POD, 1, 2, 16)
+        shape = (chip_smoke.PODS, 2, 1, 2, 16)
         data = compat.named_sharding(mesh, ("pod", "data"))
         batch = {{k: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=data)
                  for k in ("tokens", "labels")}}
-        text = jax.jit(round_fn).lower(
+        text = step.lower(
             described(params, rep), described(sstate, rep), batch
         ).compile().as_text()
         print(json.dumps(sorted(set(re.findall(r'op_name="([^"]*)"', text)))))
@@ -170,6 +168,52 @@ def test_hierarchical_int8_round_names_both_reduce_levels(device_pool):
             or _has(names, "drjax.compress[clients]"))
     assert _has(names, "drjax.reduce_mean[pods]")
     assert _has(names, "client_step") and _has(names, "server_update")
+
+
+# Opcodes of the instructions that move data between devices.
+COLLECTIVE = re.compile(
+    r"= [^=]*? (all-reduce|all-gather|reduce-scatter|collective-permute|"
+    r"all-to-all)(-start|-done)?\(")
+
+
+def test_every_collective_of_the_nested_round_names_its_primitive(
+        device_pool):
+    """The nested int8 round of ``launch.train --pods 2`` on a (pod 2,
+    data 2) mesh of the pool's virtual devices: every collective the
+    compiler put in, and every op of the fused reduce+compress (here the
+    jnp oracle's fusion), binds under a ``drjax.`` scope, where the leg
+    readers find them."""
+    lines = device_pool.run(f"""
+        import json, re
+        import jax, jax.numpy as jnp
+        from repro.launch import train as train_lib
+        from repro.models import registry
+
+        args = train_lib.parse_args(
+            ["--arch", "lm_350m", "--pods", "2", "--cohort", "8",
+             "--local-steps", "1", "--batch", "2", "--seq", "16",
+             "--compression", "int8"])
+        cfg = registry.get_config("lm_350m").reduced()
+        devices = jax.devices()[:4]
+        layout = train_lib.round_layout(args, devices)
+        step, server_opt = train_lib.build_round_fn(cfg, args, layout.mesh)
+        params = registry.init_params(jax.random.PRNGKey(0), cfg)
+        params, sstate = layout.state((params, server_opt.init(params)))
+        shape = (8, 1, 2, 16)
+        batch = layout.batch({{k: jnp.zeros(shape, jnp.int32)
+                              for k in ("tokens", "labels")}})
+        text = step.lower(params, sstate, batch).compile().as_text()
+        print(json.dumps([l for l in text.splitlines() if " = " in l]))
+    """)
+    collectives = [line for line in lines if COLLECTIVE.search(line)]
+    # A reducer's parameters carry the op_name of the reduce they serve,
+    # without the enclosing scopes; they are no op of their own.
+    kernel = [line for line in lines if "reduce_compress_roundtrip" in line
+              and " parameter(" not in line]
+    assert len(collectives) >= 2 and kernel
+    for line in collectives + kernel:
+        (op_name,) = OP_NAME.findall(line)
+        assert "drjax." in op_name, line
 
 
 def test_train_profiles_the_rounds_it_is_asked_for(tmp_path):

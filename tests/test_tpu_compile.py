@@ -31,7 +31,7 @@ OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
@@ -47,9 +47,14 @@ def one_chip():
     was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield jax.sharding.SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was_on)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return jax.sharding.SingleDeviceSharding(topo.devices[0])
 
 
 def _compile_hlo(fn, sharding, *shapes):
@@ -288,3 +293,65 @@ def test_flat_round_aggregate_bytes(flat_round):
     rest = sum(v for s, v in moved.items() if s != "drjax.broadcast")
     assert moved["drjax.broadcast"] > 0
     assert rest <= 1.05 * (8 * n_params + 8 * n_state), (moved, n_params)
+
+
+# Opcodes of the instructions that move data between devices.
+_COLLECTIVE = re.compile(
+    r"= [^=]*? (all-reduce|all-gather|reduce-scatter|collective-permute|"
+    r"all-to-all)(-start|-done)?\(")
+
+
+def test_nested_round_runs_the_kernel_on_every_chip(topo, monkeypatch):
+    """``launch.train --pods 2 --compression int8`` on a (pod 2, data 2)
+    mesh of the four described chips, at reduced widths: the one program
+    every chip runs holds the Mosaic ``reduce_compress_roundtrip`` call,
+    and it and every collective bind under a ``drjax.`` scope, where the
+    benchmark's readers find them. Each client's delta rounds its new
+    parameters to bf16 with an explicit ``reduce-precision``: without it
+    the TPU compiler keeps them at f32 (excess precision), and the pod
+    partials are no longer those of bf16 clients."""
+    from repro.launch import train as train_lib
+    from repro.models import registry
+
+    # The kernel dispatch asks for the backend, which here is the CPU.
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    jax.clear_caches()
+    cfg = registry.get_config("lm_350m").reduced(
+        dtype="bfloat16", d_model=256, num_heads=2, num_kv_heads=2,
+        head_dim=128, d_ff=512, vocab_size=512)
+    args = train_lib.parse_args(
+        ["--arch", "lm_350m", "--pods", "2", "--cohort", "8",
+         "--local-steps", "1", "--batch", "2", "--seq", "128",
+         "--compression", "int8"])
+    devices = list(topo.devices)
+    mesh = train_lib.round_layout(args, devices).mesh
+    step, server_opt = train_lib.build_round_fn(cfg, args, mesh)
+    replicated = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+    sharded = jax.sharding.NamedSharding(
+        mesh, jax.sharding.PartitionSpec(*mesh.axis_names))
+
+    def placed(tree, sharding):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=sharding), tree)
+
+    params = jax.eval_shape(
+        lambda: registry.init_params(jax.random.PRNGKey(0), cfg))
+    state = jax.eval_shape(server_opt.init, params)
+    batch = {k: jax.ShapeDtypeStruct((2, 4, 1, 2, 128), jnp.int32,
+                                     sharding=sharded)
+             for k in ("tokens", "labels")}
+    hlo = step.lower(placed(params, replicated), placed(state, replicated),
+                     batch).compile().as_text()
+    jax.clear_caches()
+    _assert_named_kernel(hlo, "reduce_compress_roundtrip")
+    lines = hlo.splitlines()
+    kernel = [line for line in lines if "tpu_custom_call" in line]
+    collectives = [line for line in lines if _COLLECTIVE.search(line)]
+    assert len(collectives) >= 2
+    for line in kernel + collectives:
+        (op_name,) = OP_NAME.findall(line)
+        assert "drjax." in op_name, line
+    rounding = [line for line in lines if " reduce-precision(" in line
+                and "client_delta" in line]
+    assert rounding and all("mantissa_bits=7" in line for line in rounding)
