@@ -98,11 +98,14 @@ from repro.kernels import reduce_compress as rc, ref
 
 x = jax.random.normal(jax.random.PRNGKey(0), (8, 4, 256), jnp.float32)
 q, s = rc.reduce_compress(x, interpret=True)
-qr, sr = ref.reduce_compress_ref(x)
+qr, sr = jax.jit(ref.reduce_compress_ref)(x)
 assert bool(jnp.all(q == qr)) and bool(jnp.all(s == sr)), \
     "fused reduce_compress kernel diverged from its jnp oracle"
-back = rc.dequant_accumulate(q[None], s[None], interpret=True)
-br = ref.dequant_accumulate_ref(q[None], s[None])
+qs = jnp.stack([q, -q])                     # two pods, (P, R, C)
+ss = jnp.concatenate([s, 2 * s])            # their (P, R) lane-dense scales
+back = jax.jit(lambda q, s: rc.dequant_accumulate(q, s, interpret=True))(
+    qs, ss)
+br = jax.jit(ref.dequant_accumulate_ref)(qs, ss)
 assert bool(jnp.all(back == br)), \
     "dequant_accumulate kernel diverged from its jnp oracle"
 print("fused-vs-oracle smoke check: OK")

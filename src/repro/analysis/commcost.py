@@ -15,7 +15,13 @@ derives, from the eqn's operand/output avals and its placement params:
   output as int8-on-the-wire, so the next comm stage over that value
   counts ``1 byte/value + one f32 scale per PACK_COLS(=256)-block`` —
   exactly the packed wire format ``repro.compression`` ships — instead of
-  the f32 nbytes. (The *unfused* roundtrip materializes f32 in the IR, so
+  the f32 nbytes. On a mesh the executable sends that format: the reduce
+  after the fused one is tagged ``wire="int8"`` and exchanges the int8
+  payload and its scales (``core.primitives._int8_wire_mean``). The count
+  is one copy per pod; every device of a pod holds the pod's partial and
+  sends it, so a pod of ``d`` devices puts ``d`` times the count on the
+  wire. (The
+  *unfused* roundtrip materializes f32 in the IR, so
   the IR-derived cost is honestly f32 there: compression that is invisible
   in the IR is invisible to a static pass.)
 
@@ -293,7 +299,7 @@ def cross_validate(
     Every plain (uncompressed) Reduce eqn is jitted standalone; the XLA
     cost model's operand bytes of parameter 0 plus its output bytes must
     match the modeled ``endpoints * payload + output nbytes`` within
-    ``tol``. Compressed reduces are excluded — their
+    ``tol``. Compressed and ``wire``-tagged reduces are excluded — their
     lowering contains quantization machinery whose memory accounting is not
     a wire model (they are pinned against the packed wire format math in
     tests instead). ``model_scale`` multiplies the modeled side; anything
@@ -313,7 +319,8 @@ def cross_validate(
     for name, stage, _owner in plan.named_stages():
         if not isinstance(stage, Reduce):
             continue
-        if stage.eqn.params.get("compress") is not None:
+        if (stage.eqn.params.get("compress") is not None
+                or stage.eqn.params.get("wire") is not None):
             continue
         eqn = stage.eqn
         aval = eqn.invars[0].aval

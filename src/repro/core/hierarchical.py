@@ -108,10 +108,13 @@ def _staged_reduce(tree, ctx, compress_fn, use_fused: Optional[bool]):
     Fast path: flat-pack the tree (one ``(*groups, R, 256)`` buffer per
     dtype), bind ``reduce_mean@innermost`` tagged ``compress="int8"`` — a
     single eqn whose execution is the one-pass Pallas reduce+compress kernel
-    on TPU (fused jnp oracle elsewhere) — then the plain outer reduces, and
-    unpack. The program still stages as placement-tagged REDUCEs, so
-    ``build_plan``/``to_beam`` see the same communication structure as the
-    generic composition.
+    on TPU (fused jnp oracle elsewhere) — then the outer reduces, and
+    unpack. The first outer reduce is tagged ``wire="int8"``: where its
+    level is sharded over mesh axes and the buffer is f32, the pod partials
+    cross it as int8 values and scales, and ``dequant_accumulate`` takes
+    their mean on receipt (``primitives._int8_wire_mean``). The program
+    still stages as placement-tagged REDUCEs, so ``build_plan``/``to_beam``
+    see the same communication structure as the generic composition.
 
     Scopes: the fused path's pack, inner reduce and unpack bind under
     ``drjax.reduce_compress[<inner>]``, the generic path's ``compress_fn``
@@ -129,8 +132,14 @@ def _staged_reduce(tree, ctx, compress_fn, use_fused: Optional[bool]):
             with api.scope("reduce_compress", inner):
                 v = prims.bind_reduce_mean(buf, placement=inner,
                                            compress="int8")
+            # The next level's operand is the roundtrip's output, on the
+            # int8 grid, and may cross its wire as such; the levels after
+            # it reduce a mean.
+            bind = prims._bind_roundtrip_mean
             for name in reversed(ctx.names[:-1]):
-                v = api.reduce_mean(v, placement=name)
+                with api.scope("reduce_mean", name):
+                    v = bind(v, placement=name)
+                bind = prims.bind_reduce_mean
             outs[key] = v
         with api.scope("reduce_compress", inner):
             return compression.flat_unpack(outs, spec, lead_ndim=0)

@@ -36,6 +36,7 @@ primitives here operate on single arrays and are mapped over pytrees by
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -193,6 +194,12 @@ batching.primitive_batchers[broadcast_p] = _broadcast_batch
 # ---------------------------------------------------------------------------
 
 
+def _spec_entry(pl: placement_lib.Placement):
+    """A placement's entry in a PartitionSpec: its mesh axis, or axes."""
+    axes = pl.axes_tuple()
+    return (axes if len(axes) > 1 else axes[0]) if axes else None
+
+
 def _fused_compress_reduce(x, i, name: str, compress: str, qaxis: int,
                            pctx: placement_lib.PlacementContext):
     """Execute a ``compress``-tagged reduction: the fused single-pass
@@ -215,11 +222,7 @@ def _fused_compress_reduce(x, i, name: str, compress: str, qaxis: int,
     if pctx.mesh is None or not pctx.use_sharding_annotations:
         return kernel_ops.reduce_compress_roundtrip(x, axis=i, qaxis=qaxis)
 
-    def entry(pl):
-        axes = pl.axes_tuple()
-        return (axes if len(axes) > 1 else axes[0]) if axes else None
-
-    lead = [entry(pl) for pl in pctx.placements[: i + 1]]
+    lead = [_spec_entry(pl) for pl in pctx.placements[: i + 1]]
     reduced = pctx.placements[i].axes_tuple()
     groups = x.shape[i]
 
@@ -231,6 +234,62 @@ def _fused_compress_reduce(x, i, name: str, compress: str, qaxis: int,
             jnp.expand_dims(part * (1.0 / groups), i), axis=i, qaxis=qaxis
         )
         return back.astype(xs.dtype)
+
+    rest = [None] * (x.ndim - i - 1)
+    return compat.shard_map(
+        per_shard, mesh=pctx.mesh,
+        in_specs=(P(*lead, *rest),), out_specs=P(*lead[:i], *rest),
+        check=False,
+    )(x)
+
+
+def _wire_engages(pctx: placement_lib.PlacementContext,
+                  pl: placement_lib.Placement, dtype) -> bool:
+    """Does a ``wire="int8"`` tag change what a reduce_mean sends? Only on a
+    mesh with sharding annotations, at a placement that names mesh axes, on
+    an f32 operand: a bf16 cast of the roundtrip is off the int8 grid, and
+    without sharded groups nothing crosses a wire."""
+    return (pctx.mesh is not None and pctx.use_sharding_annotations
+            and bool(pl.axes_tuple()) and np.dtype(dtype) == np.float32)
+
+
+def _int8_wire_mean(x, i, pctx: placement_lib.PlacementContext):
+    """Execute a ``wire="int8"``-tagged reduce_mean: each group's rows along
+    the last axis are already ``q * s`` (the output of the int8 roundtrip),
+    so the groups cross the placement's mesh axes as the int8 payload and
+    one f32 scale a row, not as f32 rows.
+
+    Per shard, inside a shard_map (GSPMD cannot partition a Mosaic kernel):
+    the inner leg's quantizer (``reduce_compress`` over one group) gives the
+    rows' ``q`` back exactly and their scales to an ulp or two; ``q`` and
+    the scales, lane-dense (an ``(R, 1)`` f32 array takes 128 lanes a row
+    in HBM), reach every device of the placement's axes; and
+    ``dequant_accumulate`` dequantizes and takes the mean over the groups
+    in one pass, in the groups' order on every device.
+
+    The payload travels as an all-to-all of one copy per device, which is
+    an all-gather's exchange: XLA runs an all-to-all synchronously, where
+    it would schedule an all-gather as an asynchronous pair and reorder the
+    rest of the program around it (on TPU that reordering packs the round's
+    buffers worse, at the same peak of live bytes)."""
+    from repro.kernels import ops as kernel_ops  # lazy: keep core import-light
+
+    lead = [_spec_entry(pl) for pl in pctx.placements[: i + 1]]
+    devices = math.prod(pctx.mesh.shape[a]
+                        for a in pctx.placements[i].axes_tuple())
+    cols = x.shape[-1]
+
+    def gather(v):
+        copies = jnp.broadcast_to(v, (devices,) + v.shape)
+        return jax.lax.all_to_all(copies.reshape((-1,) + v.shape[1:]),
+                                  lead[i], 0, 0, tiled=True)
+
+    def per_shard(xs):
+        groups = jnp.moveaxis(xs, i, 0)
+        rows = groups.reshape(groups.shape[0], 1, -1, cols)
+        q, s = jax.vmap(kernel_ops.reduce_compress)(rows)
+        mean = kernel_ops.dequant_accumulate(gather(q), gather(s[:, 0]))
+        return mean.reshape(groups.shape[1:])
 
     rest = [None] * (x.ndim - i - 1)
     return compat.shard_map(
@@ -274,11 +333,13 @@ def _make_reduction(name: str, reduce_fn):
     p = Primitive(f"drjax_{name}")
 
     def impl(x, *, pctx: placement_lib.PlacementContext, placement=None,
-             compress=None, qaxis=-1, dtype=None):
+             compress=None, qaxis=-1, dtype=None, wire=None):
         pl, i = _resolve(pctx, placement)
         _check_kind(pl, name, "replicas")  # eager binds skip abstract
         if compress is not None:
             out = _fused_compress_reduce(x, i, name, compress, qaxis, pctx)
+        elif wire is not None:
+            out = _int8_wire_mean(x, i, pctx)
         else:
             out = reduce_fn(x, pl, i, dtype)
         if i == 0:
@@ -286,7 +347,7 @@ def _make_reduction(name: str, reduce_fn):
         return sharding_lib.constrain_partitioned(out, pctx, depth=i)
 
     def abstract(x, *, pctx, placement=None, compress=None, qaxis=-1,
-                 dtype=None):
+                 dtype=None, wire=None):
         pl, i = _resolve(pctx, placement)
         _check_kind(pl, name, "replicas")
         _check_operand_depth(x, pctx, i + 1, name)
@@ -298,9 +359,13 @@ def _make_reduction(name: str, reduce_fn):
     mlir.register_lowering(p, mlir.lower_fun(impl, multiple_results=False))
 
     def batch(args, dims, *, pctx, placement=None, compress=None, qaxis=-1,
-              dtype=None):
+              dtype=None, wire=None):
         (x,), (d,) = args, dims
         extra = {} if dtype is None else {"dtype": dtype}
+        if wire is not None and d is batching.not_mapped:
+            # A mapped axis lands last (below), where the int8 rows were:
+            # the plain mean then runs, which has the tag's values.
+            extra["wire"] = wire
         if compress is not None:
             # The batch axis lands at the end (below), so a from-the-end
             # quantization axis shifts one step deeper; a from-the-front one
@@ -335,12 +400,13 @@ reduce_max_p = _make_reduction(
 def _linear_reduction_jvp(p):
     def jvp(primals, tangents, *, pctx, placement=None, dtype=None,
             **fused):
-        # ``fused`` carries compress/qaxis on the int8 fast-path eqn. The
-        # primal keeps them (fused execution); the tangent drops them: the
-        # roundtrip is straight-through under MapReduce AD, so d(fused
-        # reduce_mean@p) == d(reduce_mean@p) and grad matches the unfused
-        # composition exactly. An accumulation ``dtype`` is the output's
-        # dtype, so the tangent keeps it.
+        # ``fused`` carries compress/qaxis on the int8 fast-path eqn, or
+        # the execution-only ``wire`` tag. The primal keeps them (fused
+        # execution); the tangent drops them: the roundtrip is straight-
+        # through under MapReduce AD, so d(fused reduce_mean@p) ==
+        # d(reduce_mean@p) and grad matches the unfused composition
+        # exactly. An accumulation ``dtype`` is the output's dtype, so the
+        # tangent keeps it.
         (x,), (t,) = primals, tangents
         acc = {} if dtype is None else {"dtype": dtype}
         out = p.bind(x, pctx=pctx, placement=placement, **acc, **fused)
@@ -365,8 +431,9 @@ def _reduce_sum_transpose(ct, x, *, pctx, placement=None, **fused):
 
 
 def _reduce_mean_transpose(ct, x, *, pctx, placement=None, **fused):
-    # d(reduce_mean@p)^T = broadcast@p / size(p). A compress-tagged eqn
-    # transposes identically: the int8 roundtrip is straight-through. A mean
+    # d(reduce_mean@p)^T = broadcast@p / size(p). A compress- or wire-tagged
+    # eqn transposes identically: the int8 roundtrip is straight-through,
+    # and the wire changes only the encoding. A mean
     # that accumulated in another dtype hands back its operand's.
     if isinstance(ct, ad.Zero):
         return (ad.Zero(x.aval),)
@@ -541,6 +608,21 @@ def bind_reduce_mean(x, placement: Optional[str] = None, *,
     if compress is not None:
         extra.update(compress=compress, qaxis=qaxis)
     return reduce_mean_p.bind(x, **extra, **_bind_params(placement))
+
+
+def _bind_roundtrip_mean(x, placement: str):
+    """The plain mean of ``x``, tagged ``wire="int8"`` where the tag changes
+    what crosses the wire (:func:`_wire_engages`; :func:`_int8_wire_mean`).
+
+    Only ``hierarchical._staged_reduce`` binds it: ``x`` has to be the
+    output of ``bind_reduce_mean(..., compress="int8")`` at ``qaxis=-1``,
+    every row along its last axis ``q * s``. Nothing here can check that,
+    and any other operand would be quantized on the wire."""
+    params = _bind_params(placement)
+    ctx = params["pctx"]
+    wired = _wire_engages(ctx, ctx.get(placement), jnp.result_type(x))
+    return reduce_mean_p.bind(x, **({"wire": "int8"} if wired else {}),
+                              **params)
 
 
 def bind_reduce_max(x, placement: Optional[str] = None):

@@ -81,17 +81,36 @@ def dequantize(q, scales, *, dtype=None, row_block=256, interpret=None):
 # (pass ``interpret=True`` off-TPU), ``backend="jnp"`` forces the oracle.
 
 
-@functools.partial(jax.jit, static_argnames=("row_block", "interpret"))
-def reduce_compress(x, *, row_block=None, interpret=None):
-    """(G, R, C) -> ((R, C) int8, (R, 1) f32): fused partial mean + quantize."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
+def _fused_backend(backend):
+    """The fused family's dispatch: ``backend`` or, by default, the Mosaic
+    kernel on TPU and the jnp oracle elsewhere."""
+    if backend is None:
+        return "pallas" if _on_tpu() else "jnp"
+    if backend not in ("pallas", "jnp"):
+        raise ValueError(f"unknown backend {backend!r}")
+    return backend
+
+
+@functools.partial(
+    jax.jit, static_argnames=("row_block", "backend", "interpret")
+)
+def reduce_compress(x, *, row_block=None, backend=None, interpret=False):
+    """(G, R, C) -> ((R, C) int8, (1, R) f32): fused partial mean +
+    quantize, the scales lane-dense."""
+    if _fused_backend(backend) == "jnp":
+        return _ref.reduce_compress_ref(x)
     return _rc.reduce_compress(x, row_block=row_block, interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("row_block", "interpret"))
-def dequant_accumulate(q, scales, *, row_block=None, interpret=None):
-    """((P, R, C) int8, (P, R, 1)) -> (R, C): fused dequantize + pod mean."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
+@functools.partial(
+    jax.jit, static_argnames=("row_block", "backend", "interpret")
+)
+def dequant_accumulate(q, scales, *, row_block=None, backend=None,
+                       interpret=False):
+    """((P, R, C) int8, (P, R) f32) -> (R, C): fused dequantize + pod
+    mean."""
+    if _fused_backend(backend) == "jnp":
+        return _ref.dequant_accumulate_ref(q, scales)
     return _rc.dequant_accumulate(
         q, scales, row_block=row_block, interpret=interpret
     )
@@ -186,11 +205,7 @@ def reduce_compress_roundtrip(x, *, axis=0, qaxis=-1, row_block=None,
     if part_ndim < 1:
         raise ValueError("reduce_compress_roundtrip needs a non-group axis")
     qaxis = qaxis % part_ndim
-    if backend is None:
-        backend = "pallas" if _on_tpu() else "jnp"
-    if backend == "jnp":
+    if _fused_backend(backend) == "jnp":
         return _reduce_compress_roundtrip_jnp(x, axis, qaxis)
-    if backend != "pallas":
-        raise ValueError(f"unknown backend {backend!r}")
     return _reduce_compress_roundtrip_pallas(x, axis, qaxis, row_block,
                                              interpret)

@@ -75,25 +75,34 @@ def dequantize_ref(q, scales, dtype=jnp.float32):
     return (q.astype(jnp.float32) * scales).astype(dtype)
 
 
+def _partial_mean_ref(x):
+    """The mean over the leading group axis, accumulated in f32."""
+    return jnp.sum(x.astype(jnp.float32), axis=0) * (1.0 / x.shape[0])
+
+
 def reduce_compress_ref(x):
     """Fused partial mean + int8 quantize, op-for-op the kernel's math.
 
-    (G, R, C) -> ((R, C) int8, (R, 1) f32 scales). The mean accumulates in
+    (G, R, C) -> ((R, C) int8, (1, R) f32 scales). The mean accumulates in
     f32 over the leading group axis; scales are per row (one lane-contiguous
-    block of C values).
+    block of C values), laid out lane-dense as the kernel writes them.
     """
-    part = jnp.sum(x.astype(jnp.float32), axis=0) * (1.0 / x.shape[0])
-    return quantize_ref(part)
+    q, s = quantize_ref(_partial_mean_ref(x))
+    return q, s.reshape(1, -1)
 
 
 def reduce_compress_roundtrip_ref(x):
-    """(G, R, C) -> (back x.dtype, q int8, s f32): mean + quant + dequant."""
-    q, s = reduce_compress_ref(x)
+    """(G, R, C) -> (back x.dtype, q int8, (R, 1) s f32): mean + quant +
+    dequant."""
+    q, s = quantize_ref(_partial_mean_ref(x))
     back = dequantize_ref(q, s, x.dtype)
     return back, q, s
 
 
 def dequant_accumulate_ref(q, scales):
-    """Fused dequantize + mean over pods: ((P, R, C), (P, R, 1)) -> (R, C)."""
-    back = q.astype(jnp.float32) * scales
-    return jnp.sum(back, axis=0) * (1.0 / q.shape[0])
+    """Fused dequantize + mean over pods: ((P, R, C), (P, R)) -> (R, C).
+    The pods accumulate one at a time, in their order, as in the kernel."""
+    acc = q[0].astype(jnp.float32) * scales[0][:, None]
+    for k in range(1, q.shape[0]):
+        acc = acc + q[k].astype(jnp.float32) * scales[k][:, None]
+    return acc * (1.0 / q.shape[0])

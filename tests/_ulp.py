@@ -19,3 +19,16 @@ def assert_within_ulp(actual, desired, maxulp=1):
         np.testing.assert_array_max_ulp(a, b, maxulp=maxulp)
     else:
         np.testing.assert_array_equal(a, b)
+
+
+def assert_within_ulp_of(actual, desired, scale, maxulp=1):
+    """``actual`` within ``maxulp`` units in the last place of ``scale``
+    (elementwise, broadcast) of ``desired``. For a sum whose terms are
+    larger than the result: an ulp of a term that cancels is many ulps of
+    the result, so the ulps are counted at the terms' magnitude."""
+    a, b = np.asarray(actual), np.asarray(desired)
+    assert a.shape == b.shape and a.dtype == b.dtype, (a.shape, b.shape)
+    unit = np.spacing(np.abs(np.asarray(scale, a.dtype)))
+    gap = np.abs(a.astype(np.float64) - b.astype(np.float64))
+    worst = float(np.max(gap / unit)) if a.size else 0.0
+    assert worst <= maxulp, f"{worst} ulps of the scale apart (> {maxulp})"

@@ -36,15 +36,19 @@ class TestKernelsVsOracle:
     def test_reduce_compress_bitwise(self):
         x = jax.random.normal(jax.random.PRNGKey(0), (16, 10, 256))
         q, s = rc.reduce_compress(x, interpret=True)
-        qr, sr = ref.reduce_compress_ref(x)
-        assert q.dtype == jnp.int8 and s.shape == (10, 1)
+        # The interpreted kernel runs compiled; so does the oracle, as XLA
+        # may round the partial mean apart from an op-by-op evaluation.
+        qr, sr = jax.jit(ref.reduce_compress_ref)(x)
+        assert q.dtype == jnp.int8 and s.shape == (1, 10)
         np.testing.assert_array_equal(np.asarray(q), np.asarray(qr))
         np.testing.assert_array_equal(np.asarray(s), np.asarray(sr))
 
     def test_reduce_compress_row_padding_bitwise(self):
-        x = jax.random.normal(jax.random.PRNGKey(1), (8, 7, 128))
-        q, s = rc.reduce_compress(x, row_block=4, interpret=True)
-        qr, sr = ref.reduce_compress_ref(x)
+        # Lane-dense scales block rows by 128 lanes: 300 rows leave a
+        # partial last block.
+        x = jax.random.normal(jax.random.PRNGKey(1), (8, 300, 128))
+        q, s = rc.reduce_compress(x, row_block=128, interpret=True)
+        qr, sr = jax.jit(ref.reduce_compress_ref)(x)
         np.testing.assert_array_equal(np.asarray(q), np.asarray(qr))
         np.testing.assert_array_equal(np.asarray(s), np.asarray(sr))
 
@@ -59,12 +63,34 @@ class TestKernelsVsOracle:
         k = jax.random.PRNGKey(3)
         x = jax.random.normal(k, (4, 8, 9, 128))  # (P, G, R, C)
         q, s = jax.vmap(lambda p: rc.reduce_compress(p, interpret=True))(x)
-        out = rc.dequant_accumulate(q, s, interpret=True)
+        out = rc.dequant_accumulate(q, s[:, 0], interpret=True)
         # The interpreted kernel runs compiled, where XLA fuses the dequant
-        # multiply into the pod sum; the oracle is compiled too, so both
-        # take the same fused path and stay bitwise equal.
-        outr = jax.jit(ref.dequant_accumulate_ref)(q, s)
+        # multiply into the pod sum; the oracle is compiled too and adds
+        # the pods in the kernel's order, so both stay bitwise equal.
+        outr = jax.jit(ref.dequant_accumulate_ref)(q, s[:, 0])
         np.testing.assert_array_equal(np.asarray(out), np.asarray(outr))
+
+    def test_lane_dense_scales(self):
+        """The cross-pod leg's layout: ``reduce_compress`` writes its scales
+        as ``(1, R)``, bitwise the ``(R, 1)`` column of the roundtrip
+        kernel, and ``dequant_accumulate`` reads them as ``(P, R)``, over a
+        partial last block of 128 rows, bitwise its oracle."""
+        x = jax.random.normal(jax.random.PRNGKey(9), (3, 1, 300, 256))
+        x = x * jnp.exp(jax.random.normal(jax.random.PRNGKey(10),
+                                           (3, 1, 300, 1)))
+        q, s = jax.vmap(lambda p: rc.reduce_compress(
+            p, row_block=128, interpret=True))(x)
+        _, q_rt, s_rt = jax.vmap(
+            lambda p: rc.reduce_compress_roundtrip(p, interpret=True))(x)
+        assert s.shape == (3, 1, 300)
+        np.testing.assert_array_equal(np.asarray(q), np.asarray(q_rt))
+        np.testing.assert_array_equal(np.asarray(s[:, 0]),
+                                      np.asarray(s_rt[..., 0]))
+        out = rc.dequant_accumulate(q, s[:, 0], row_block=128,
+                                    interpret=True)
+        np.testing.assert_array_equal(
+            np.asarray(out),
+            np.asarray(jax.jit(ref.dequant_accumulate_ref)(q, s[:, 0])))
 
     def test_pair_equals_roundtrip_then_mean(self):
         """reduce_compress → dequant_accumulate (the backend's two-kernel
@@ -72,6 +98,7 @@ class TestKernelsVsOracle:
         partials followed by the plain cross-pod mean."""
         x = jax.random.normal(jax.random.PRNGKey(4), (3, 5, 4, 256))
         q, s = jax.vmap(lambda p: rc.reduce_compress(p, interpret=True))(x)
+        s = s[:, 0]                                 # (P, R), lane-dense
         pair = rc.dequant_accumulate(q, s, interpret=True)
         backs, q_rt, s_rt = jax.vmap(
             lambda p: rc.reduce_compress_roundtrip(p, interpret=True)
@@ -79,9 +106,9 @@ class TestKernelsVsOracle:
         # Both kernels put the same payload on the wire, and the roundtrip
         # partial is that payload dequantized.
         np.testing.assert_array_equal(np.asarray(q), np.asarray(q_rt))
-        np.testing.assert_array_equal(np.asarray(s), np.asarray(s_rt))
+        np.testing.assert_array_equal(np.asarray(s), np.asarray(s_rt[..., 0]))
         np.testing.assert_array_equal(
-            np.asarray(backs), np.asarray(q, np.float32) * np.asarray(s)
+            np.asarray(backs), np.asarray(q, np.float32) * np.asarray(s_rt)
         )
         # The pod mean of those partials, with the dequantize fused into the
         # sum as in the kernel (a mean of the materialized partials rounds

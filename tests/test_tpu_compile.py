@@ -22,6 +22,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.kernels import ops
@@ -78,7 +79,7 @@ def _assert_named_kernel(hlo, name):
 @pytest.mark.parametrize("groups", [8, 64])
 def test_reduce_compress(one_chip, groups):
     hlo = _compile_hlo(
-        lambda x: ops.reduce_compress(x, interpret=False),
+        lambda x: ops.reduce_compress(x, backend="pallas", interpret=False),
         one_chip, ((groups, ROWS, LANES), jnp.float32),
     )
     _assert_named_kernel(hlo, "reduce_compress")
@@ -98,10 +99,34 @@ def test_reduce_compress_roundtrip(one_chip, groups):
 @pytest.mark.parametrize("pods", [8, 64])
 def test_dequant_accumulate(one_chip, pods):
     hlo = _compile_hlo(
-        lambda q, s: ops.dequant_accumulate(q, s, interpret=False),
+        lambda q, s: ops.dequant_accumulate(q, s, backend="pallas",
+                                             interpret=False),
         one_chip,
         ((pods, ROWS, LANES), jnp.int8),
-        ((pods, ROWS, 1), jnp.float32),
+        ((pods, ROWS), jnp.float32),
+    )
+    _assert_named_kernel(hlo, "dequant_accumulate")
+
+
+# The cross-pod leg's pair keeps its scales lane-dense, so a block holds a
+# multiple of 128 rows: at a row count that is no multiple of 128 the last
+# block is partial.
+@pytest.mark.parametrize("groups", [1, 8])
+def test_reduce_compress_partial_block(one_chip, groups):
+    hlo = _compile_hlo(
+        lambda x: ops.reduce_compress(x, backend="pallas"),
+        one_chip, ((groups, ROWS + 100, LANES), jnp.float32),
+    )
+    _assert_named_kernel(hlo, "reduce_compress")
+
+
+@pytest.mark.parametrize("pods", [2, 8, 64])
+def test_dequant_accumulate_partial_block(one_chip, pods):
+    hlo = _compile_hlo(
+        lambda q, s: ops.dequant_accumulate(q, s, backend="pallas"),
+        one_chip,
+        ((pods, ROWS + 100, LANES), jnp.int8),
+        ((pods, ROWS + 100), jnp.float32),
     )
     _assert_named_kernel(hlo, "dequant_accumulate")
 
@@ -301,6 +326,78 @@ _COLLECTIVE = re.compile(
     r"all-to-all)(-start|-done)?\(")
 
 
+_GROUPS = re.compile(r"replica_groups=(\{\{[\d,{}]*\}\}|"
+                     r"\[[\d,]+\]<=\[[\d,]+\](?:T\([\d,]+\))?)")
+
+
+def _kernel_names(hlo: str) -> dict:
+    """Each Mosaic kernel's name (``vmap(name)`` counts as ``name``) and
+    the ``op_name`` of each of its custom calls."""
+    out = {}
+    for line in hlo.splitlines():
+        if "tpu_custom_call" in line:
+            (op_name,) = OP_NAME.findall(line)
+            name = op_name.split("/")[-2]
+            name = name[5:-1] if name.startswith("vmap(") else name
+            out.setdefault(name, []).append(op_name)
+    return out
+
+
+def _replica_groups(text: str) -> frozenset:
+    """HLO ``replica_groups``, in either the explicit or the iota form, as
+    a set of groups of device ids."""
+    if text.startswith("{"):
+        return frozenset(frozenset(int(d) for d in g.split(","))
+                         for g in re.findall(r"\{([\d,]+)\}", text))
+    shape, dims, perm = re.match(
+        r"\[([\d,]+)\]<=\[([\d,]+)\](?:T\(([\d,]+)\))?", text).groups()
+    ids = np.arange(np.prod([int(d) for d in dims.split(",")]))
+    ids = ids.reshape([int(d) for d in dims.split(",")])
+    if perm:
+        ids = ids.transpose([int(d) for d in perm.split(",")])
+    ids = ids.reshape([int(d) for d in shape.split(",")])
+    return frozenset(frozenset(row) for row in ids.tolist())
+
+
+def _mesh_groups(mesh, axis: str) -> frozenset:
+    """The device groups (ids in the mesh's order) that ``axis`` joins."""
+    ids = np.moveaxis(np.arange(mesh.devices.size).reshape(mesh.devices.shape),
+                      mesh.axis_names.index(axis), -1)
+    return frozenset(frozenset(row) for row in
+                     ids.reshape(-1, ids.shape[-1]).tolist())
+
+
+# Bytes one member of a group of n sends, per byte of the op's result.
+_SENT = {"all-gather": lambda n: (n - 1) / n,
+         "all-to-all": lambda n: (n - 1) / n,
+         "all-reduce": lambda n: 2 * (n - 1) / n,
+         "reduce-scatter": lambda n: n - 1,
+         "collective-permute": lambda n: 1}
+
+
+def _collectives(hlo: str) -> list:
+    """The compiled program's collectives, one entry for each array of a
+    (combined) result: opcode, dtype and dims, replica groups, and the
+    bytes each member of a group sends."""
+    out = []
+    for line in hlo.splitlines():
+        m = _COLLECTIVE.search(line)
+        if not m or m.group(2) == "-done":
+            continue
+        shape = line.split(" = ", 1)[1].split(f" {m.group(1)}", 1)[0]
+        arrays = _ARRAY.findall(shape)
+        if m.group(2):  # an async start's result is the last array
+            arrays = arrays[-1:]
+        groups = _replica_groups(_GROUPS.search(line).group(1))
+        n = len(next(iter(groups)))
+        for dtype, dims in arrays:
+            dims = [int(d) for d in filter(None, dims.split(","))]
+            size = int(np.prod(dims)) * _ITEMSIZE[dtype]
+            out.append(dict(op=m.group(1), dtype=dtype, dims=dims,
+                            groups=groups, sent=_SENT[m.group(1)](n) * size))
+    return out
+
+
 def test_nested_round_runs_the_kernel_on_every_chip(topo, monkeypatch):
     """``launch.train --pods 2 --compression int8`` on a (pod 2, data 2)
     mesh of the four described chips, at reduced widths: the one program
@@ -344,7 +441,8 @@ def test_nested_round_runs_the_kernel_on_every_chip(topo, monkeypatch):
     hlo = step.lower(placed(params, replicated), placed(state, replicated),
                      batch).compile().as_text()
     jax.clear_caches()
-    _assert_named_kernel(hlo, "reduce_compress_roundtrip")
+    kernels = _kernel_names(hlo)
+    assert kernels["reduce_compress_roundtrip"], kernels
     lines = hlo.splitlines()
     kernel = [line for line in lines if "tpu_custom_call" in line]
     collectives = [line for line in lines if _COLLECTIVE.search(line)]
@@ -355,3 +453,60 @@ def test_nested_round_runs_the_kernel_on_every_chip(topo, monkeypatch):
     rounding = [line for line in lines if " reduce-precision(" in line
                 and "client_delta" in line]
     assert rounding and all("mantissa_bits=7" in line for line in rounding)
+    # The cross-pod leg: the pod partials leave each pod as int8 values and
+    # lane-dense f32 scales, requantized and dequantized by the kernel pair
+    # under the outer reduce's scope; only the intra-pod sum moves f32 rows.
+    assert set(kernels) == {"reduce_compress_roundtrip", "reduce_compress",
+                            "dequant_accumulate"}, kernels
+    for name in ("reduce_compress", "dequant_accumulate"):
+        assert all("drjax.reduce_mean[pods]" in op for op in kernels[name])
+    pods, intra = _mesh_groups(mesh, "pod"), _mesh_groups(mesh, "data")
+    ops_ = _collectives(hlo)
+    (partial,) = [c for c in ops_ if c["groups"] == intra
+                  and c["dtype"] == "f32" and c["dims"][-1:] == [256]]
+    rows = partial["dims"][-2]
+    across = [c for c in ops_ if c["groups"] == pods]
+    assert not [c for c in across
+                if c["dtype"] == "f32" and c["dims"][-1:] == [256]], across
+    assert [c for c in across if c["dtype"] == "s8"
+            and c["dims"][-2:] == [rows, 256]], across
+    assert [c for c in across if c["dtype"] == "f32"
+            and c["dims"][-1:] == [rows]], across
+    sent = sum(c["sent"] for c in across)
+    assert sent <= 0.26 * rows * 256 * 4, (sent, rows)
+
+
+def test_nested_int8_reduce_sends_what_the_analyzer_counts(topo, monkeypatch):
+    """``plan.comm_cost()`` counts the nested int8 reduce's cross-pod (DCN)
+    stage at one byte a value and one f32 scale a row of 256, each pod's
+    partial once. Compiled for a (pod 2, data 2) mesh of the described
+    chips, every device of a pod holds the pod's partial and sends it: the
+    two cross-pod groups (one a data index) send twice that together, to
+    5 %."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro import compat
+    from repro import core as drjax
+    from repro.compression import int8_roundtrip
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    jax.clear_caches()
+    mesh = compat.make_mesh((2, 2), ("pod", "data"), devices=topo.devices)
+    sizes = {"pods": 2, "clients": 4}
+
+    @drjax.program(placements=sizes, mesh=mesh,
+                   partition_axes={"pods": "pod", "clients": "data"})
+    def f(xs):
+        return drjax.hierarchical_reduce_mean(xs, compress_fn=int8_roundtrip)
+
+    xs = jax.ShapeDtypeStruct(
+        (2, 4, ROWS * LANES), jnp.float32,
+        sharding=NamedSharding(mesh, PartitionSpec("pod", "data")))
+    dcn = drjax.build_plan(jax.make_jaxpr(f)(xs), sizes).comm_cost().dcn_bytes
+    hlo = jax.jit(f).lower(xs).compile().as_text()
+    jax.clear_caches()
+    pods = _mesh_groups(mesh, "pod")
+    sent = sum(len(g) for g in pods) * sum(
+        c["sent"] for c in _collectives(hlo) if c["groups"] == pods)
+    assert sent == pytest.approx(mesh.shape["data"] * dcn, rel=0.05), (
+        sent, dcn)
