@@ -2,8 +2,8 @@
 
 Runs :func:`repro.runtime.chaos.run_chaos_soak` (device failures + pod
 dropout/regrowth + straggler deadlines + torn/corrupt checkpoints +
-concurrent serve bursts with a scheduler fault) and records the soak's
-production metrics as a per-PR trajectory in ``BENCH_chaos.json``:
+concurrent serve bursts with a scheduler fault) and prints the soak's
+report as JSON:
 
 * ``client_retraces`` / ``oracle_extra_traces`` — must stay 0 (the
   zero-retrace elasticity invariant);
@@ -22,10 +22,10 @@ production metrics as a per-PR trajectory in ``BENCH_chaos.json``:
   on the same devices (the co-location contention column).
 
 ``--smoke`` is the CI shape: ~20 rounds with 1 device failure, 1 elastic
-event, straggler deadlines every round and a checkpoint fault (no BENCH
-write). ``--physical`` runs the physical-mesh soak; it needs 8 host
-CPU devices and re-execs itself on them (``JAX_PLATFORMS=cpu``, ``XLA_FLAGS``)
-unless its environment already provides them. Invoked via ``benchmarks.run`` (key ``chaos``) or directly:
+event, straggler deadlines every round and a checkpoint fault.
+``--physical`` runs the physical-mesh soak; it needs 8 host CPU devices and
+re-execs itself on them (``JAX_PLATFORMS=cpu``, ``XLA_FLAGS``) unless its
+environment already provides them. Run it as:
 
     PYTHONPATH=src python -m benchmarks.chaos [--smoke] [--physical]
 """
@@ -40,12 +40,9 @@ import subprocess
 import sys
 import time
 
-from repro.launch import bench_log
 from repro.runtime.chaos import ChaosConfig, run_chaos_soak
 
 from ._util import _REPO, cpu_devices_env
-
-OUT_PATH = bench_log.bench_path("chaos")
 
 #: devices the physical soak needs (4 pods x 2 clients)
 PHYSICAL_DEVICES = 8
@@ -116,68 +113,11 @@ def _on_cpu_pool() -> bool:
             and int(m.group(1)) >= PHYSICAL_DEVICES)
 
 
-def _physical_point_subprocess() -> dict:
-    """Run the physical soak in a fresh 8-device process, return its point.
-
-    The host device count is locked at JAX's first init, so the aggregator
-    (whose process typically has 1 device) gets the physical point from a
-    subprocess — the same pattern as benchmarks/hier_sharded.py."""
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.chaos",
-         "--smoke", "--physical", "--json"],
-        capture_output=True, text=True, cwd=_REPO, timeout=900,
-        env=cpu_devices_env(PHYSICAL_DEVICES),
-    )
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"physical chaos soak failed:\n{proc.stdout[-2000:]}\n"
-            f"{proc.stderr[-2000:]}"
-        )
-    return json.loads(proc.stdout.strip().splitlines()[-1])
-
-
-def run():
-    t0 = time.time()
-    point = bench()
-    point["bench_wall_s"] = round(time.time() - t0, 1)
-    phys = _physical_point_subprocess()
-    bench_log.merge_entry(
-        {"chaos": point, "chaos_physical": phys}, name="chaos"
-    )
-    per_round_us = 1e6 * point["bench_wall_s"] / max(point["rounds"], 1)
-    return [
-        {
-            "name": "chaos_soak",
-            "us_per_call": f"{per_round_us:.0f}",
-            "derived": (
-                f"bitwise={point['oracle_bitwise_equal']}; "
-                f"retraces={point['client_retraces']}; "
-                f"failures={point['device_failures']}; "
-                f"fallbacks={point['fallback_restores']}; "
-                f"straggler_speedup={point['straggler']['speedup']}; "
-                f"serve_p99_contended={point['serve_p99_contended']}"
-            ),
-        },
-        {
-            "name": "chaos_soak_physical",
-            "us_per_call": "-",
-            "derived": (
-                f"bitwise={phys['oracle_bitwise_equal']}; "
-                f"reshards={phys['reshards']}; "
-                f"mesh_migrate_ms={phys['mesh_migrate_ms']}; "
-                f"meshes={phys['meshes_seen']}; "
-                f"kills={phys['mid_write_kills_survived']}/"
-                f"{phys['mid_write_kills_injected']}"
-            ),
-        },
-    ]
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="~20-round CI soak (1 failure, 1 elastic event, "
-                         "stragglers, 1 ckpt fault); no BENCH write")
+                         "stragglers, 1 ckpt fault)")
     ap.add_argument("--physical", action="store_true",
                     help="physical-mesh soak (real (pod, data) mesh, live "
                          f"resharding); re-execs on {PHYSICAL_DEVICES} CPU "
@@ -196,11 +136,6 @@ def main():
     t0 = time.time()
     point = bench(smoke=args.smoke, seed=args.seed, physical=args.physical)
     point["bench_wall_s"] = round(time.time() - t0, 1)
-    if not args.smoke:
-        key = "chaos_physical" if args.physical else "chaos"
-        bench_log.merge_entry({key: point}, name="chaos")
-        if not args.json:
-            print(f"wrote {OUT_PATH}")
     if args.json:
         print(json.dumps(point))
     else:

@@ -17,9 +17,8 @@ Both schedulers are warmed on a bucket-covering trace first (a prompt of
 measured run asserts the steady-state invariant: ZERO new traces under
 arbitrary traffic (``prefill_traces`` / ``decode_traces`` flat).
 
-``BENCH_serve.json`` is a per-PR trajectory via the generalized
-``bench_log`` (one entry per git SHA). Invoked via ``benchmarks.run``
-(key ``serve``) or directly:
+The times are the host's wall clock on whatever backend runs the
+schedulers; the report is printed as JSON. Run it as:
 
     PYTHONPATH=src python -m benchmarks.serve [--smoke]
 """
@@ -33,7 +32,6 @@ from typing import List, Optional
 import jax
 import numpy as np
 
-from repro.launch import bench_log
 from repro.launch.serve import (
     ContinuousBatchingScheduler,
     Request,
@@ -41,7 +39,6 @@ from repro.launch.serve import (
 )
 from repro.models import registry
 
-OUT_PATH = bench_log.bench_path("serve")
 ARCH = "stablelm_3b"
 
 
@@ -199,32 +196,6 @@ def bench(n: int = 24, slots: int = 4, chunk: int = 8, seed: int = 0,
     return point
 
 
-def run():
-    point = bench()
-    bench_log.merge_entry({"serve": point}, name="serve")
-    us_c = 1e6 / point["continuous"]["tokens_per_s"]
-    us_s = 1e6 / point["static"]["tokens_per_s"]
-    return [
-        {
-            "name": "serve_continuous",
-            "us_per_call": f"{us_c:.1f}",
-            "derived": (
-                f"ttft_p99={point['continuous']['ttft_p99_s']}s; "
-                f"traces p/d={point['prefill_traces']}/"
-                f"{point['decode_traces']} flat"
-            ),
-        },
-        {
-            "name": "serve_static_wave",
-            "us_per_call": f"{us_s:.1f}",
-            "derived": (
-                f"ttft_p99={point['static']['ttft_p99_s']}s; "
-                f"cont/static tokens/s={point['tokens_per_s_ratio']}"
-            ),
-        },
-    ]
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
@@ -237,9 +208,6 @@ def main():
     t0 = time.time()
     point = bench(n=n, rate=args.rate, smoke=args.smoke)
     point["bench_wall_s"] = round(time.time() - t0, 1)
-    if not args.smoke:
-        bench_log.merge_entry({"serve": point}, name="serve")
-        print(f"wrote {OUT_PATH}")
     import json
 
     print(json.dumps(point, indent=2))

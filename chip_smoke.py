@@ -381,8 +381,6 @@ def phase_hier_int8(args, cfg, clock, *, kernel_marker="tpu_custom_call",
                     interpret=False):
     """``kernel_marker``: the text the compiled round's HLO must hold to
     show that the Mosaic kernel, not the jnp oracle, runs in the round."""
-    check(os.environ.get("REPRO_NO_FUSED_REDUCE", "") in ("", "0"),
-          "REPRO_NO_FUSED_REDUCE is set: the fused kernel would not run")
     mark = clock.mark()
     step, layout, server_opt, client_update = hier_round(
         cfg, args, jax.devices()[:1])

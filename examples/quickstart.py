@@ -170,7 +170,7 @@ def compressed_hier_mean(tree):
 @drjax.program(placements={"pods": 2, "clients": 4})
 def reference_hier_mean(tree):
     # use_fused=False forces the generic reduce -> quantize -> dequantize
-    # composition (also reachable globally via REPRO_NO_FUSED_REDUCE=1).
+    # composition.
     return drjax.hierarchical_reduce_mean(
         tree, compress_fn=int8_roundtrip, use_fused=False
     )

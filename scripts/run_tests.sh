@@ -114,7 +114,7 @@ PY
 # Serve-runtime smoke: continuous batching vs static waves on a reduced
 # config — asserts flat trace counts after bucket warmup and token-identical
 # outputs across schedulers (perf-ordering assertions are skipped in smoke
-# mode; the full comparison runs via benchmarks.run / benchmarks.serve).
+# mode; the full comparison runs via python -m benchmarks.serve).
 python -m benchmarks.serve --smoke > /dev/null
 echo "serve continuous-batching smoke check: OK"
 

@@ -1,8 +1,8 @@
 """Asynchronous (one-round-stale) local SGD — compute/communication overlap.
 
 Synchronous rounds serialize: [local steps] → [reduce] → [server update] →
-[broadcast]. At pod scale the reduce+broadcast leg can rival the compute leg
-(see EXPERIMENTS.md §Roofline, lm_8b). The async variant overlaps them with
+[broadcast]. At pod scale the reduce+broadcast leg can rival the compute
+leg. The async variant overlaps them with
 one round of staleness (the standard pipelined-DiLoCo trick):
 
     round r:   clients train on params_{r-1} while the server is still

@@ -62,8 +62,7 @@ class PipelineConfig:
 
 def pipeline_bubble_fraction(num_stages: int, num_microbatches: int) -> float:
     """Idle fraction of stage-ticks in the fill/drain schedule:
-    ``(S - 1) / (M + S - 1)`` — the figure of merit ``benchmarks/pipeline``
-    tracks (more microbatches -> smaller bubble)."""
+    ``(S - 1) / (M + S - 1)`` (more microbatches -> smaller bubble)."""
     s, m = num_stages, num_microbatches
     if s < 1 or m < 1:
         raise ValueError("need num_stages >= 1 and num_microbatches >= 1")

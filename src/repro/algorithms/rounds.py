@@ -27,7 +27,7 @@ Distribution: the partition axis shards over (pod, data); everything inside
 parallelism composes (paper: "shard computations over data partitions,
 model, and within-data partitions simultaneously").
 
-Options beyond the paper's baseline (all recorded in EXPERIMENTS.md §Perf):
+Options beyond the paper's baseline:
  * straggler masks (over-provisioned cohorts, masked reduction);
  * delta compression (int8 with error-feedback) before the reduction;
  * weighted (FedAvg) and self-tuned (learned-weight) reductions.

@@ -27,7 +27,6 @@ reshape is pure local compute at the pod boundary.
 from __future__ import annotations
 
 import math
-import os
 from typing import Callable, Optional
 
 import jax
@@ -40,11 +39,6 @@ from . import placement as placement_lib
 from . import primitives as prims
 
 _SUPER = "pods"
-
-# Kill switch for the fused reduce+compress fast path (ROADMAP conventions):
-# set REPRO_NO_FUSED_REDUCE=1 to force the generic two-primitive composition
-# even for recognized compressors. An explicit ``use_fused=True`` overrides.
-_NO_FUSED_ENV = "REPRO_NO_FUSED_REDUCE"
 
 
 def _axes_if_divisible(axes, groups: int, mesh):
@@ -75,9 +69,8 @@ def _fusable(tree, ctx, compress_fn, use_fused: Optional[bool]) -> bool:
     The fast path engages when the compressor is *recognized* — it carries
     the ``drjax_fused_compress = "int8"`` tag (``compression.int8_roundtrip``
     does) — and every leaf is a floating array carrying the stack's group
-    axes. ``use_fused=False`` (or ``REPRO_NO_FUSED_REDUCE=1``) forces the
-    generic two-primitive composition; ``use_fused=True`` insists and raises
-    if the compressor cannot be fused.
+    axes. ``use_fused=False`` forces the generic two-primitive composition;
+    ``use_fused=True`` insists and raises if the compressor cannot be fused.
     """
     tag = getattr(compress_fn, "drjax_fused_compress", None)
     if use_fused is False:
@@ -88,8 +81,6 @@ def _fusable(tree, ctx, compress_fn, use_fused: Optional[bool]) -> bool:
                 "use_fused=True requires a fusable compress_fn (one tagged "
                 f"drjax_fused_compress='int8'); got {compress_fn!r}"
             )
-        return False
-    if use_fused is None and os.environ.get(_NO_FUSED_ENV, "") not in ("", "0"):
         return False
     leaves = jax.tree_util.tree_leaves(tree)
     depth = ctx.depth

@@ -1,10 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-Backend dispatch: on TPU the Mosaic kernels run natively; elsewhere
-``interpret=True`` executes the kernel bodies in Python (correctness path,
-used by tests) and the model code defaults to the XLA blocked implementations
-(``repro.models.attention.blocked_attention`` etc.) which share the same
-algorithm.
+Backend dispatch: on TPU the Mosaic kernels run natively. Elsewhere the
+int8 ``quantize``/``dequantize`` pair runs its kernel bodies in Python
+(``interpret=True``), and the fused reduce+compress family runs its jnp
+oracle (see below).
 """
 
 from __future__ import annotations
@@ -15,42 +14,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import flash_attention as _fa
 from . import quantize as _quant
 from . import reduce_compress as _rc
 from . import ref as _ref
-from . import rglru_scan as _lru
-from . import wkv6 as _wkv
 
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
-
-
-@functools.partial(
-    jax.jit, static_argnames=("causal", "window", "q_block", "kv_block", "interpret")
-)
-def flash_attention(q, k, v, *, causal=True, window=0, q_block=512,
-                    kv_block=512, interpret=None):
-    interpret = (not _on_tpu()) if interpret is None else interpret
-    return _fa.flash_attention(
-        q, k, v, causal=causal, window=window, q_block=q_block,
-        kv_block=kv_block, interpret=interpret,
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("chunk", "width_block", "interpret"))
-def lru_scan(a, b, *, chunk=256, width_block=512, interpret=None):
-    interpret = (not _on_tpu()) if interpret is None else interpret
-    return _lru.lru_scan(
-        a, b, chunk=chunk, width_block=width_block, interpret=interpret
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def wkv6(r, k, v, logw, u, *, chunk=64, interpret=None):
-    interpret = (not _on_tpu()) if interpret is None else interpret
-    return _wkv.wkv6(r, k, v, logw, u, chunk=chunk, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("row_block", "interpret"))

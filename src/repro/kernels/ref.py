@@ -1,4 +1,5 @@
-"""Pure-jnp oracles for every Pallas kernel (allclose targets in tests)."""
+"""Pure-jnp oracles (allclose targets in tests): for every Pallas kernel,
+and for the models' own attention, RG-LRU scan and WKV6 code."""
 
 from __future__ import annotations
 
@@ -9,7 +10,8 @@ import jax.numpy as jnp
 
 
 def flash_attention_ref(q, k, v, *, causal=True, window=0):
-    """Naive full-materialization GQA attention. Same contract as the kernel."""
+    """Naive full-materialization GQA attention. Same contract as
+    ``models.attention.blocked_attention``."""
     b, sq, hq, hd = q.shape
     skv, hkv = k.shape[1], k.shape[2]
     g = hq // hkv

@@ -1,1 +1,1 @@
-"""Launch: production mesh, dry-run, training and serving drivers."""
+"""Launch: production mesh, training and serving entry points."""

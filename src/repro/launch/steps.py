@@ -81,8 +81,7 @@ def strategy_rules(cfg, fsdp: bool):
     over (pod, data). Right for >=8B models where TP amortizes.
     ``dp``: the model axis is repurposed as extra data parallelism — batch
     shards over (pod, data, model), model dims replicate. Right for small
-    models where per-layer TP all-reduces would dominate (see EXPERIMENTS.md
-    §Perf: tp->dp moves small-model cells from collective- to compute-bound).
+    models where per-layer TP all-reduces would dominate.
     """
     rules = dict(fsdp_rules(fsdp))
     if cfg.mesh_strategy == "dp":
@@ -220,8 +219,8 @@ def make_drjax_round_step(
             return inner(params, server_state, round_data)
 
     if jit_donated:
-        # The round-loop donation discipline (same as dryrun's jit of this
-        # step): params + server_state are carried state and update in place.
+        # The round-loop donation discipline: params + server_state are
+        # carried state and update in place.
         round_step = jax.jit(round_step, donate_argnums=(0, 1))
 
     p_axes = registry.param_axes(cfg)

@@ -1,11 +1,10 @@
 """GQA attention: blocked (flash-style, XLA), naive, decode-with-cache.
 
 The *blocked* path is the production default: an online-softmax scan over KV
-blocks that never materializes the (Sq, Skv) score matrix — the same
-algorithmic shape as the Pallas flash kernel in ``repro.kernels``, expressed
-in XLA so it lowers on any backend (the dry-run runs on CPU host devices
-where Mosaic cannot lower). HLO matmul FLOPs are identical to the kernel's;
-the kernel additionally keeps tiles in VMEM.
+blocks that never materializes the (Sq, Skv) score matrix, expressed in XLA
+so it lowers on any backend. ``flash_attention_xla`` is the same forward
+with a blockwise backward of its own; ``tests/test_kernels.py`` checks both
+against the naive oracle ``repro.kernels.ref.flash_attention_ref``.
 
 Supports:
  * grouped-query attention (Hq = G * Hkv),
@@ -268,7 +267,7 @@ def _blocked_attention_tri(
 ):
     """Causal block-skipping schedule: scan lower-triangular (i, j) pairs only.
 
-    Beyond-paper perf optimization (see EXPERIMENTS.md §Perf): for causal
+    Beyond-paper perf optimization: for causal
     attention with Sq == Skv this computes nq(nq+1)/2 block pairs instead of
     nq·nk, halving attention FLOPs at long sequence length.
     """

@@ -13,8 +13,8 @@ RG-LRU recurrence (diagonal, data-dependent decay):
     h_t = a_t ⊙ h_{t-1} + sqrt(1 - a_t²) ⊙ (i_t ⊙ x_t)
 
 Training/prefill uses ``jax.lax.associative_scan`` (log-depth, TPU-friendly);
-decode is a single fused step. A Pallas TPU kernel for the scan lives in
-``repro.kernels.rglru_scan``.
+decode is a single fused step. ``repro.kernels.ref.lru_scan_ref`` is the
+sequential oracle of ``lru_scan``.
 """
 
 from __future__ import annotations

@@ -8,14 +8,13 @@ Per head (head_dim = N) the time-mix recurrence over state S ∈ R^{N×N}:
 
 with data-dependent decay  w_t = exp(-exp(w0 + LoRA_w(x̃_t))) ∈ (0,1)^N.
 
-Three execution paths:
+Two execution paths, checked against ``repro.kernels.ref.wkv6_ref``:
  * ``sequential_wkv`` — plain lax.scan, the oracle (and the decode step);
  * ``chunked_wkv`` — TPU-native chunkwise-parallel form: the per-pair decay
    factorizes as exp(lcw_{i-1} - lcw_j) = (r_i e^{lcw_{i-1}})·(k_j e^{-lcw_j}),
    turning intra-chunk interaction into plain matmuls (MXU-friendly) while the
    state S carries across chunks — this is the hardware adaptation of the
-   paper's CUDA kernel;
- * a Pallas TPU kernel (``repro.kernels.wkv6``) with the same chunked scheme.
+   paper's CUDA kernel.
 """
 
 from __future__ import annotations

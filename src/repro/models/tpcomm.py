@@ -1,7 +1,7 @@
 """Quantized tensor-parallel collectives (beyond-paper optimization).
 
-The dominant roofline term for the large train/prefill cells is the per-layer
-tensor-parallel activation reduction: each TP block ends with partial sums
+For large models under tensor parallelism, the per-layer tensor-parallel
+activation reduction is a large cost: each TP block ends with partial sums
 that GSPMD reduces in bf16 (ring all-reduce ≈ 2·(m-1)/m · bytes on the wire).
 
 ``int8_matmul_reduce`` replaces that reduction for a TP matmul's output with:
@@ -17,9 +17,8 @@ all-reduce → ≈ 3.9× fewer bytes at m=16. Cost: m× dequant-add flops
 (error ≤ absmax/254 per row per shard; validated in tests, cosine > 0.999).
 
 Implemented with shard_map (via ``repro.compat``, which picks the right API
-across JAX versions) so the collective is explicit in the lowered HLO — the
-dry-run's collective parser sees ``all-gather`` ops with ``s8`` operands,
-which is the measurement used in EXPERIMENTS.md §Perf.
+across JAX versions) so the collective is explicit in the lowered HLO, as
+``all-gather`` ops with ``s8`` operands.
 """
 
 from __future__ import annotations

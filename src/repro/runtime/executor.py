@@ -27,11 +27,10 @@ This module provides that compiled path:
   skeleton and the captured const values).
 
 * donation plumbing: ``compile_plan(plan, donate_argnums=...)`` donates the
-  carried arguments (params / server state in a round plan), matching the
-  ``donate_argnums`` discipline of ``launch/dryrun.py``. The donation rule
-  for this repo: **any jitted hot loop donates its carried state**; inputs
-  that are re-used across calls (model params at serve time, eval batches)
-  are never donated.
+  carried arguments (params / server state in a round plan). The donation
+  rule for this repo: **any jitted hot loop donates its carried state**;
+  inputs that are re-used across calls (model params at serve time, eval
+  batches) are never donated.
 
 * per-stage sharding constraints: with ``mesh=``/``placement_axes=``, the
   output of every BROADCAST/REDUCE stage is pinned to its placement-stack
@@ -92,8 +91,8 @@ class TraceCounter:
 
     ``jit`` only calls the underlying Python callable when tracing, so a
     plain side-effecting counter measures exactly the retrace count — the
-    no-retrace invariants in ``tests/test_executor.py`` and
-    ``benchmarks/executor.py`` are asserted with this.
+    no-retrace invariants in ``tests/test_executor.py`` are asserted with
+    this.
     """
 
     def __init__(self):

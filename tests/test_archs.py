@@ -1,10 +1,10 @@
 """Per-architecture smoke tests: reduced config of the same family, one
 forward/train step on CPU, asserting output shapes and no NaNs.
 
-The FULL configs are exercised only via the dry-run (ShapeDtypeStruct, no
-allocation) — see launch/dryrun.py and EXPERIMENTS.md §Dry-run.
+The FULL configs are checked by their widths and parameter counts only.
 """
 
+import dataclasses
 import functools
 
 import jax
@@ -93,6 +93,25 @@ def test_param_counts_in_expected_range():
     for arch, (lo, hi) in cases.items():
         n = registry.get_config(arch).param_count()
         assert lo <= n <= hi, f"{arch}: {n/1e9:.1f}B outside [{lo/1e9},{hi/1e9}]"
+
+
+def test_param_count_matches_init():
+    """The config's parameter formula counts what ``init_params`` makes."""
+    for arch in ("stablelm_3b", "phi35_moe", "rwkv6_3b"):
+        cfg = registry.get_config(arch).reduced()
+        params = registry.init_params(jax.random.PRNGKey(0), cfg)
+        actual = sum(
+            int(np.prod(l.shape))
+            for l in jax.tree_util.tree_leaves(params)
+        )
+        # account for vocab padding to multiples of 512
+        padded = dataclasses.replace(
+            cfg, vocab_size=-(-cfg.vocab_size // 512) * 512
+        )
+        expected = padded.param_count()
+        assert abs(actual - expected) / expected < 0.25, (
+            f"{arch}: init {actual} vs formula {expected}"
+        )
 
 
 def test_active_params_moe():

@@ -12,8 +12,6 @@ Covers the acceptance criteria of the fused hierarchical reduction:
 * cross-placement ``map_fn`` fusion is bitwise-identical to the nested form.
 """
 
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -345,14 +343,6 @@ class TestFusedHierarchical:
 
         with pytest.raises(ValueError, match="use_fused=True"):
             f(jnp.ones((4, 8)))
-
-    def test_env_kill_switch(self, monkeypatch):
-        monkeypatch.setenv("REPRO_NO_FUSED_REDUCE", "1")
-        fused, _, _ = _programs(4, 2)
-        xs = jax.random.normal(jax.random.PRNGKey(4), (4, 16))
-        jxp = jax.make_jaxpr(fused)(xs)
-        # generic path: no compress-tagged reduce eqn in the trace
-        assert "compress" not in str(jxp)
 
     def test_fused_eqn_in_trace(self):
         fused, _, _ = _programs(4, 2)

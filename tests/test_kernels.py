@@ -1,8 +1,11 @@
-"""Per-kernel allclose sweeps: Pallas (interpret=True) vs pure-jnp oracles.
+"""Allclose sweeps against the pure-jnp oracles in ``repro.kernels.ref``.
 
-Each kernel is swept over shapes and dtypes per the deliverable requirement.
-``interpret=True`` executes the kernel bodies (BlockSpec tiling included) on
-CPU; on TPU the same kernels lower through Mosaic.
+The int8 quantize pair runs as a Pallas kernel in interpret mode
+(``interpret=True`` executes the kernel body, BlockSpec tiling included, on
+the CPU; on a TPU the same kernel lowers through Mosaic). Attention, the
+RG-LRU scan and WKV6 run as the models run them: the XLA code in
+``repro.models`` (blocked attention, ``lru_scan``, ``chunked_wkv``), swept
+over shapes, block sizes and dtypes against the sequential oracles.
 """
 
 import jax
@@ -12,6 +15,19 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.kernels import ops, ref
+from repro.models import attention, rglru, rwkv
+
+# Jitted, as the models run them (eager dispatch of a scan is slow).
+blocked_attention = jax.jit(
+    attention.blocked_attention,
+    static_argnames=("causal", "window", "q_block", "kv_block"),
+)
+flash_attention_xla = jax.jit(
+    attention.flash_attention_xla, static_argnums=(3, 4, 5, 6)
+)
+lru_scan = jax.jit(rglru.lru_scan)
+chunked_wkv = jax.jit(rwkv.chunked_wkv, static_argnames=("chunk",))
+sequential_wkv = jax.jit(rwkv.sequential_wkv)
 
 
 def _tol(dtype):
@@ -36,9 +52,7 @@ class TestFlashAttention:
         q = jax.random.normal(ks[0], (b, s, hq, hd)).astype(dtype)
         k = jax.random.normal(ks[1], (b, s, hkv, hd)).astype(dtype)
         v = jax.random.normal(ks[2], (b, s, hkv, hd)).astype(dtype)
-        out = ops.flash_attention(
-            q, k, v, causal=True, q_block=qb, kv_block=kb, interpret=True
-        )
+        out = blocked_attention(q, k, v, causal=True, q_block=qb, kv_block=kb)
         r = ref.flash_attention_ref(q, k, v, causal=True)
         np.testing.assert_allclose(
             np.asarray(out, np.float32), np.asarray(r, np.float32), **_tol(dtype)
@@ -50,9 +64,8 @@ class TestFlashAttention:
         q = jax.random.normal(ks[0], (2, 64, 4, 16))
         k = jax.random.normal(ks[1], (2, 64, 2, 16))
         v = jax.random.normal(ks[2], (2, 64, 2, 16))
-        out = ops.flash_attention(
-            q, k, v, causal=True, window=window, q_block=16, kv_block=16,
-            interpret=True,
+        out = blocked_attention(
+            q, k, v, causal=True, window=window, q_block=16, kv_block=16
         )
         r = ref.flash_attention_ref(q, k, v, causal=True, window=window)
         np.testing.assert_allclose(out, r, rtol=2e-5, atol=2e-5)
@@ -62,37 +75,35 @@ class TestFlashAttention:
         q = jax.random.normal(ks[0], (1, 24, 4, 32))
         k = jax.random.normal(ks[1], (1, 56, 2, 32))  # Skv != Sq
         v = jax.random.normal(ks[2], (1, 56, 2, 32))
-        out = ops.flash_attention(
-            q, k, v, causal=False, q_block=8, kv_block=16, interpret=True
-        )
+        out = blocked_attention(q, k, v, causal=False, q_block=8, kv_block=16)
         r = ref.flash_attention_ref(q, k, v, causal=False)
         np.testing.assert_allclose(out, r, rtol=2e-5, atol=2e-5)
 
     def test_matches_xla_blocked_path(self):
-        """Kernel and the XLA blocked implementation agree (same algorithm)."""
-        from repro.models.attention import blocked_attention
-
+        """The models' default attention (``flash_attention_xla``, with its
+        own backward) and the blocked scan agree: the same algorithm."""
         ks = jax.random.split(jax.random.PRNGKey(3), 3)
         q = jax.random.normal(ks[0], (2, 48, 4, 16))
         k = jax.random.normal(ks[1], (2, 48, 2, 16))
         v = jax.random.normal(ks[2], (2, 48, 2, 16))
-        a = ops.flash_attention(q, k, v, causal=True, q_block=16, kv_block=16,
-                                interpret=True)
+        a = flash_attention_xla(q, k, v, True, 0, 16, 16)
         b = blocked_attention(q, k, v, causal=True, q_block=16, kv_block=16)
         np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
 
 
+def _lru_inputs(key, shape, dtype=jnp.float32):
+    ks = jax.random.split(key, 2)
+    a = jax.nn.sigmoid(jax.random.normal(ks[0], shape)).astype(dtype)
+    x = jax.random.normal(ks[1], shape).astype(dtype)
+    return a, x
+
+
 class TestLruScan:
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-    @pytest.mark.parametrize(
-        "b,s,w,chunk,wb",
-        [(1, 16, 8, 8, 8), (2, 40, 24, 16, 8), (2, 100, 32, 32, 32)],
-    )
-    def test_vs_ref(self, b, s, w, chunk, wb, dtype):
-        ks = jax.random.split(jax.random.PRNGKey(0), 2)
-        a = jax.nn.sigmoid(jax.random.normal(ks[0], (b, s, w))).astype(dtype)
-        x = jax.random.normal(ks[1], (b, s, w)).astype(dtype)
-        out = ops.lru_scan(a, x, chunk=chunk, width_block=wb, interpret=True)
+    @pytest.mark.parametrize("b,s,w", [(1, 16, 8), (2, 40, 24), (2, 100, 32)])
+    def test_vs_ref(self, b, s, w, dtype):
+        a, x = _lru_inputs(jax.random.PRNGKey(0), (b, s, w), dtype)
+        out = lru_scan(a, x)
         r = ref.lru_scan_ref(a, x)
         np.testing.assert_allclose(
             np.asarray(out, np.float32), np.asarray(r, np.float32), **_tol(dtype)
@@ -104,23 +115,41 @@ class TestLruScan:
     )
     @settings(max_examples=10, deadline=None)
     def test_property_random_shapes(self, s, w):
-        ks = jax.random.split(jax.random.PRNGKey(s * 131 + w), 2)
-        a = jax.nn.sigmoid(jax.random.normal(ks[0], (1, s, w)))
-        x = jax.random.normal(ks[1], (1, s, w))
-        out = ops.lru_scan(a, x, chunk=8, width_block=8, interpret=True)
+        a, x = _lru_inputs(jax.random.PRNGKey(s * 131 + w), (1, s, w))
+        out = lru_scan(a, x)
         r = ref.lru_scan_ref(a, x)
         np.testing.assert_allclose(out, r, rtol=1e-5, atol=1e-5)
 
     @pytest.mark.parametrize("s,w", [(2, 1), (13, 5), (33, 16)])
     def test_random_shapes_smoke(self, s, w):
         """Deterministic slice of the shape property (no hypothesis needed):
-        ragged sequence lengths and widths that don't divide the blocks."""
-        ks = jax.random.split(jax.random.PRNGKey(s * 131 + w), 2)
-        a = jax.nn.sigmoid(jax.random.normal(ks[0], (1, s, w)))
-        x = jax.random.normal(ks[1], (1, s, w))
-        out = ops.lru_scan(a, x, chunk=8, width_block=8, interpret=True)
+        ragged sequence lengths and odd widths."""
+        a, x = _lru_inputs(jax.random.PRNGKey(s * 131 + w), (1, s, w))
+        out = lru_scan(a, x)
         r = ref.lru_scan_ref(a, x)
         np.testing.assert_allclose(out, r, rtol=1e-5, atol=1e-5)
+
+    def test_initial_state(self):
+        """``h0`` carries a chunk's state into the next (chunked prefill):
+        the oracle sees it as one extra leading step whose input is h0."""
+        a, x = _lru_inputs(jax.random.PRNGKey(7), (2, 24, 8))
+        h0 = jax.random.normal(jax.random.PRNGKey(8), (2, 8))
+        out = lru_scan(a, x, h0=h0)
+        r = ref.lru_scan_ref(
+            jnp.concatenate([jnp.ones_like(a[:, :1]), a], axis=1),
+            jnp.concatenate([h0[:, None], x], axis=1),
+        )[:, 1:]
+        np.testing.assert_allclose(out, r, rtol=1e-5, atol=1e-5)
+
+
+def _wkv_inputs(key, b, s, h, n):
+    ks = jax.random.split(key, 5)
+    r_ = jax.random.normal(ks[0], (b, s, h, n))
+    k_ = jax.random.normal(ks[1], (b, s, h, n))
+    v_ = jax.random.normal(ks[2], (b, s, h, n))
+    lw = -jnp.exp(jax.random.normal(ks[3], (b, s, h, n)) * 0.5)
+    u = jax.random.normal(ks[4], (h, n)) * 0.1
+    return r_, k_, v_, lw, u
 
 
 class TestWkv6:
@@ -128,29 +157,19 @@ class TestWkv6:
         "b,s,h,n,chunk", [(1, 16, 1, 8, 8), (2, 48, 2, 8, 16), (1, 50, 3, 16, 16)]
     )
     def test_vs_ref(self, b, s, h, n, chunk):
-        ks = jax.random.split(jax.random.PRNGKey(0), 5)
-        r_ = jax.random.normal(ks[0], (b, s, h, n))
-        k_ = jax.random.normal(ks[1], (b, s, h, n))
-        v_ = jax.random.normal(ks[2], (b, s, h, n))
-        lw = -jnp.exp(jax.random.normal(ks[3], (b, s, h, n)) * 0.5)
-        u = jax.random.normal(ks[4], (h, n)) * 0.1
-        out = ops.wkv6(r_, k_, v_, lw, u, chunk=chunk, interpret=True)
-        oracle = ref.wkv6_ref(r_, k_, v_, lw, u)
+        args = _wkv_inputs(jax.random.PRNGKey(0), b, s, h, n)
+        out, _ = chunked_wkv(*args, chunk=chunk)
+        oracle = ref.wkv6_ref(*args)
         np.testing.assert_allclose(out, oracle, rtol=1e-4, atol=1e-4)
 
     def test_matches_model_chunked_path(self):
-        from repro.models.rwkv import chunked_wkv
-
-        ks = jax.random.split(jax.random.PRNGKey(1), 5)
-        b, s, h, n = 2, 32, 2, 8
-        r_ = jax.random.normal(ks[0], (b, s, h, n))
-        k_ = jax.random.normal(ks[1], (b, s, h, n))
-        v_ = jax.random.normal(ks[2], (b, s, h, n))
-        lw = -jnp.exp(jax.random.normal(ks[3], (b, s, h, n)) * 0.5)
-        u = jax.random.normal(ks[4], (h, n)) * 0.1
-        a = ops.wkv6(r_, k_, v_, lw, u, chunk=8, interpret=True)
-        bx, _ = chunked_wkv(r_, k_, v_, lw, u, chunk=8)
+        """The chunked (training) and sequential (decode) paths agree on
+        the outputs and on the state they hand on."""
+        args = _wkv_inputs(jax.random.PRNGKey(1), 2, 32, 2, 8)
+        a, sa = sequential_wkv(*args)
+        bx, sb = chunked_wkv(*args, chunk=8)
         np.testing.assert_allclose(a, bx, rtol=1e-4, atol=1e-4)
+        np.testing.assert_allclose(sa, sb, rtol=1e-4, atol=1e-4)
 
 
 class TestQuantize:
