@@ -22,10 +22,20 @@ Under a genuinely nested ``@drjax.program(placements={"pods": P,
 placement API, the (n, ...) value is regrouped to (P, n/P, ...) and the same
 two primitives bind inside a derived two-level stack — the one remaining
 reshape is pure local compute at the pod boundary.
+
+With a recognized int8 compressor the fast path packs the tree into one
+buffer and reduces it in one fused pass (:func:`_staged_reduce`). Where a
+device holds several of the innermost level's groups (m groups over d
+devices of a mesh, f32 leaves), it first sums its own m/d groups, each
+scaled by d/m: a MapReduce combiner, local and linear, so the pack copies
+one group a device instead of m/d, and the inner reduce binds at a derived
+level of d groups with the same name and mesh axes. Without a mesh, with
+one group a device, or with bf16 leaves, the path is the plain one.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import math
 from typing import Callable, Optional
 
@@ -93,10 +103,54 @@ def _fusable(tree, ctx, compress_fn, use_fused: Optional[bool]) -> bool:
     return bool(leaves)
 
 
+def _combine(tree, ctx):
+    """Sum each device's co-resident groups of the innermost level before
+    the fused path packs them: the tree and a context whose innermost level
+    keeps its name and axes and holds one group a device; both unchanged
+    where the combine does not engage.
+
+    It engages on a mesh with sharding annotations, where the innermost
+    level names mesh axes of ``d`` devices, its ``m`` groups are a multiple
+    of them with ``m/d > 1``, and every leaf is f32: a bf16 buffer summed
+    in f32 would be another value, and its partial would cross the
+    cross-pod wire as int8, where a bf16 partial crosses as itself
+    (``primitives._wire_engages``). Shard ``j`` holds groups ``j*m/d`` to
+    ``(j+1)*m/d - 1``, so the sum moves nothing between devices. Each group
+    is scaled by ``d/m`` before the sum, where XLA fuses the scale into the
+    sum and the groups' producer; a scale after the sum is a pass of its
+    own. The mean over the ``d`` sums is the mean over the ``m`` groups."""
+    inner = ctx.innermost
+    axes = inner.axes_tuple()
+    if ctx.mesh is None or not ctx.use_sharding_annotations or not axes:
+        return tree, ctx
+    d = math.prod(ctx.mesh.shape[a] for a in axes)
+    per = inner.size // d
+    if inner.size % d or per == 1 or any(
+            jnp.result_type(leaf) != jnp.float32
+            for leaf in jax.tree_util.tree_leaves(tree)):
+        return tree, ctx
+    i = ctx.depth - 1
+
+    def combine(x):
+        groups = x.reshape(x.shape[:i] + (d, per) + x.shape[i + 1:])
+        return jnp.sum(groups * (1.0 / per), axis=i + 1)
+
+    derived = dataclasses.replace(
+        ctx, placements=ctx.placements[:-1] + (
+            dataclasses.replace(inner, size=d),))
+    return jax.tree_util.tree_map(combine, tree), derived
+
+
 def _staged_reduce(tree, ctx, compress_fn, use_fused: Optional[bool]):
     """Bind the two-stage reduction under the ambient (nested) context.
 
-    Fast path: flat-pack the tree (one ``(*groups, R, 256)`` buffer per
+    Fast path: where each device holds several of the innermost level's
+    groups (a mesh, f32 leaves), first sum them on the device
+    (:func:`_combine`, a MapReduce combiner): the pack then copies one
+    group a device instead of every group, and the inner reduce binds at a
+    derived innermost level of one group a device, with the same name and
+    axes.
+    Then flat-pack the tree (one ``(*groups, R, 256)`` buffer per
     dtype), bind ``reduce_mean@innermost`` tagged ``compress="int8"`` — a
     single eqn whose execution is the one-pass Pallas reduce+compress kernel
     on TPU (fused jnp oracle elsewhere) — then the outer reduces, and
@@ -107,20 +161,22 @@ def _staged_reduce(tree, ctx, compress_fn, use_fused: Optional[bool]):
     still stages as placement-tagged REDUCEs, so ``build_plan``/``to_beam``
     see the same communication structure as the generic composition.
 
-    Scopes: the fused path's pack, inner reduce and unpack bind under
-    ``drjax.reduce_compress[<inner>]``, the generic path's ``compress_fn``
-    under ``drjax.compress[<inner>]``; the outer levels are
+    Scopes: the fused path's combine, pack, inner reduce and unpack bind
+    under ``drjax.reduce_compress[<inner>]``, the generic path's
+    ``compress_fn`` under ``drjax.compress[<inner>]``; the outer levels are
     ``drjax.reduce_mean[<level>]``.
     """
     inner = ctx.names[-1]
     if _fusable(tree, ctx, compress_fn, use_fused):
         with api.scope("reduce_compress", inner):
+            tree, ctx = _combine(tree, ctx)
             bufs, spec = compression.flat_pack(
                 tree, lead_ndim=ctx.depth, cols=compression.PACK_COLS
             )
         outs = {}
         for key, buf in bufs.items():
-            with api.scope("reduce_compress", inner):
+            with api.scope("reduce_compress", inner), \
+                    placement_lib.placement_context(ctx):
                 v = prims.bind_reduce_mean(buf, placement=inner,
                                            compress="int8")
             # The next level's operand is the roundtrip's output, on the

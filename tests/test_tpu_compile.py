@@ -474,6 +474,28 @@ def test_nested_round_runs_the_kernel_on_every_chip(topo, monkeypatch):
             and c["dims"][-1:] == [rows]], across
     sent = sum(c["sent"] for c in across)
     assert sent <= 0.26 * rows * 256 * 4, (sent, rows)
+    # Each chip sums its own two clients before the pack: the pack's
+    # concatenate holds one group, and no f32 buffer of the packed rows
+    # holds more (as one that packed both clients, then reduced them, would).
+    packs = [op for op in _entry_ops(hlo).values()
+             if op["opcode"] == "concatenate"
+             and "drjax.reduce_compress[clients]" in op["op_name"]]
+    assert [_ARRAY.findall(op["shape"]) for op in packs] == [
+        [("f32", f"1,1,{rows},256")]], [op["line"][:200] for op in packs]
+    grouped = {dims for dtype, dims in _ARRAY.findall(hlo)
+               if dtype == "f32" and dims.endswith(f",{rows},256")
+               and np.prod([int(d) for d in dims.split(",")[:-2]]) > 1}
+    assert not grouped, grouped
+    # The collectives are those a pack of every client would need: the
+    # intra-pod partial and the loss inside the pods, the int8 values, the
+    # scales and the loss across them.
+    assert sorted((c["op"], c["dtype"], c["dims"], c["groups"] == pods)
+                  for c in ops_) == sorted([
+        ("all-reduce", "f32", [1, rows, 256], False),
+        ("all-reduce", "f32", [1], False),
+        ("all-to-all", "s8", [2, rows, 256], True),
+        ("all-to-all", "f32", [2, 1, rows], True),
+        ("all-reduce", "f32", [], True)]), ops_
 
 
 def test_nested_int8_reduce_sends_what_the_analyzer_counts(topo, monkeypatch):
